@@ -8,18 +8,6 @@ reference's plan/execute API shape, testcase semantics, benchmark timer and
 evaluation tooling.
 """
 
-# jax version shim: the framework (and its tests) call ``jax.shard_map``,
-# which jax only exports at top level from 0.5; on older runtimes alias the
-# experimental implementation so every call site keeps working. Installed
-# here because importing ANY package submodule runs this first.
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):  # jax < 0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _jax.shard_map = _shard_map
-del _jax
-
 from .params import (
     AUTO,
     CommMethod,

@@ -359,7 +359,7 @@ def check_contract(contract: Contract, census: Dict[str, int],
             if not _cmp(rule.cmp, got, rule.value):
                 out.append(ContractViolation(contract.name, rule, got))
         elif rule.kind == "forbid":
-            if rule.op in compiled_txt:
+            if hloscan.contains_op(compiled_txt, rule.op):
                 out.append(ContractViolation(contract.name, rule,
                                              f"{rule.op!r} present"))
         elif rule.kind == "payload":

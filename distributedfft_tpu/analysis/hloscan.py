@@ -59,6 +59,11 @@ _HLO_SHAPE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
 _MLIR_SHAPE = re.compile(r"tensor<([0-9x]*)x?((?:complex<)?[a-z][a-z0-9]*>?)>")
 _METADATA = re.compile(r",?\s*metadata=\{[^{}]*\}")
 _MODULE_NAME = re.compile(r"^HloModule\s+\S+", re.MULTILINE)
+# Source-location tables the compiler prints after the module header
+# (one numbered row per line, ended by a blank line).
+_LOCATION_TABLES = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*",
+    re.MULTILINE)
 
 _DTYPE_BYTES: Dict[str, int] = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "s32": 4, "u32": 4,
@@ -163,11 +168,18 @@ def collective_census(hlo: Any) -> Dict[str, int]:
     return out
 
 
+def contains_op(txt: str, token: str) -> bool:
+    """Whether a module's op graph mentions ``token`` — the text with its
+    metadata and source-location tables stripped, so a file or function
+    name never counts."""
+    return token in strip_metadata(txt)
+
+
 def contains_bf16(txt: str) -> bool:
-    """Whether a module text mentions bf16 anywhere — the structural pin
-    behind the native wire's bit-identity (a native-wire program is
-    bf16-FREE, not merely numerically indistinguishable)."""
-    return "bf16" in txt
+    """Whether a module's op graph mentions bf16 anywhere — the
+    structural pin behind the native wire's bit-identity (a native-wire
+    program is bf16-FREE, not merely numerically indistinguishable)."""
+    return contains_op(txt, "bf16")
 
 
 # ---------------------------------------------------------------------------
@@ -278,10 +290,10 @@ def staged_exchange_total(plan: Any, direction: str = "forward",
 # ---------------------------------------------------------------------------
 
 def strip_metadata(txt: str) -> str:
-    """Compiled module text with op ``metadata={...}`` (source file/line)
-    and the module name dropped — the op graph, stable across pure
-    refactors that only move code."""
-    txt = _METADATA.sub("", txt)
+    """Compiled module text with op ``metadata={...}`` (source file/line),
+    the source-location tables and the module name dropped — the op
+    graph, stable across pure refactors that only move code."""
+    txt = _LOCATION_TABLES.sub("", _METADATA.sub("", txt))
     return _MODULE_NAME.sub("HloModule _", txt)
 
 

@@ -8,12 +8,13 @@ O(log n). This module counts the MACs the backend ACTUALLY issues — by
 mirroring the dispatch logic of ``ops/mxu_fft.py`` (direct vs four-step vs
 radix-2, R2C/C2R real-matmul fast paths, XLA's 4-real-matmul complex dot
 decomposition) — and converts each measured row into achieved MXU TFLOPS
-and fraction of the v5e's effective peak.
+and fraction of the chip's effective peak.
 
-Peak model: one v5e chip peaks at 197 bf16 TFLOPS (public spec). The
+Peak model: ``PEAKS`` holds each chip's published peaks, keyed by the
+``device_kind`` JAX reports; a kind not in the table has no ideal. The
 backend's default precision is ``HIGH`` = 3-pass bf16 emulation of f32
-(``MXUSettings.precision`` docstring), so its effective peak is 197/3;
-``HIGHEST`` is 6-pass (197/6).
+(``MXUSettings.precision`` docstring), so its effective peak is a third
+of the bf16 peak; ``HIGHEST`` is 6-pass (a sixth).
 
 Reference anchor: the reference derives GPU efficiency from cuFFT's nominal
 flops only (``/root/reference/eval/complete/scalability.py``); a
@@ -34,20 +35,31 @@ from __future__ import annotations
 import math
 import os
 import re
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 from ..ops.bluestein import chirp_length, is_smooth
 from ..ops.mxu_fft import DIRECT_MAX, _R2_BASE, _split_for
 
-V5E_PEAK_BF16_TFLOPS = 197.0
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 16 GB of HBM at 819 GB/s.
+V5E_KIND = "TPU v5 lite"
+PEAKS: Dict[str, Dict[str, float]] = {
+    V5E_KIND: {"bf16_tflops": 197.0, "hbm_gbps": 819.0, "hbm_gb": 16.0},
+}
 
 # MXU passes per f32-emulating matmul at each lax.Precision.
 _PREC_PASSES = {"default": 1, "high": 3, "highest": 6}
 
 
-def effective_peak_tflops(precision: str = "high") -> float:
-    """v5e effective matmul peak for f32 data at the given precision."""
-    return V5E_PEAK_BF16_TFLOPS / _PREC_PASSES[precision]
+def effective_peak_tflops(kind: str, precision: str = "high"
+                          ) -> Optional[float]:
+    """Effective matmul peak of a ``kind`` chip for f32 data at the given
+    precision; None for a kind with no published peak in ``PEAKS``."""
+    peak = PEAKS.get(kind)
+    if peak is None:
+        return None
+    return peak["bf16_tflops"] / _PREC_PASSES[precision]
 
 
 # ---------------------------------------------------------------------------
@@ -208,13 +220,12 @@ def mxu_flops_batched2d(batch: int, m: int, direct_max: int = DIRECT_MAX,
 # model's 100%-of-effective-peak time a measured row achieved. The model
 # is the SAME per-plan expectation dfft-explain prints — the exact MXU MAC
 # count for the matmul-family backends, the nominal 2.5·N·log2 N flops for
-# everything else — against the v5e effective peak, divided by the mesh
-# size for distributed rows (per-chip share of the transform work; the
-# exchange is deliberately NOT in the denominator, so communication time
-# shows up as lost fraction — that is the seam this gate exists to track).
-# On a non-TPU backend (the CPU test mesh) the v5e peak makes the fraction
-# a tiny TRACKING number, not a utilization claim: it is comparable across
-# runs of the same host, which is all the CI regression gate needs.
+# everything else — against the effective peak of the ``kind`` the caller
+# names, divided by the mesh size for distributed rows (per-chip share of
+# the transform work; the exchange is deliberately NOT in the
+# denominator, so communication time shows up as lost fraction). A CPU
+# row scored against ``V5E_KIND`` is a TRACKING number the caller chose
+# explicitly, not a utilization claim.
 
 
 def _parse_size(shape):
@@ -255,22 +266,24 @@ def _backend_model(backend: str):
     return False, "high", False
 
 
-def ideal_time_ms(shape, backend: str, *, devices: int = 1,
+def ideal_time_ms(shape, backend: str, *, kind: str, devices: int = 1,
                   mode: str = "roundtrip",
                   direct_max: "Optional[int]" = None) -> Optional[float]:
     """The per-plan expectation: the time ``mode`` of this workload would
-    take at 100% of the v5e effective MXU peak — exact MACs (4mm bound)
-    for the matmul family, nominal FFT flops for other backends. None
-    when the shape is outside the model (non-cube/non-square-batched).
-    ``devices`` divides the work (per-chip share); ``direct_max``
-    overrides the plan threshold (the ``direct(N)`` bench plan note)."""
+    take at 100% of a ``kind`` chip's effective MXU peak — exact MACs
+    (4mm bound) for the matmul family, nominal FFT flops for other
+    backends. None when the shape is outside the model (non-cube/
+    non-square-batched) or the kind has no published peak. ``devices``
+    divides the work (per-chip share); ``direct_max`` overrides the plan
+    threshold (the ``direct(N)`` bench plan note)."""
     parsed = _parse_size(shape)
-    if parsed is None or devices < 1:
-        return None
-    kind, dims = parsed
     mxu, precision, r2 = _backend_model(backend)
+    peak = effective_peak_tflops(kind, precision)
+    if parsed is None or devices < 1 or peak is None:
+        return None
+    form, dims = parsed
     dmax = DIRECT_MAX if direct_max is None else int(direct_max)
-    if kind == "cube":
+    if form == "cube":
         n = dims
         if mxu:
             flops = mxu_flops_roundtrip_3d(n, dmax, radix2=r2)
@@ -286,7 +299,6 @@ def ideal_time_ms(shape, backend: str, *, devices: int = 1,
             flops = flops_batched2d(b, m, m)
     if mode != "roundtrip":  # forward / inverse / forward-chunked
         flops /= 2.0
-    peak = effective_peak_tflops(precision)
     return flops / (peak * 1e12) / float(devices) * 1e3
 
 
@@ -302,7 +314,7 @@ def _mesh_devices(mesh) -> int:
 
 
 def roofline_row(measured_ms: float, shape, backend: str, mesh=None, *,
-                 mode: str = "roundtrip",
+                 kind: str, mode: str = "roundtrip",
                  direct_max: "Optional[int]" = None) -> Optional[dict]:
     """The tracked roofline record for one measured row (what bench.py
     writes under BENCH_DETAILS.json's ``"roofline"`` block): the model's
@@ -311,8 +323,8 @@ def roofline_row(measured_ms: float, shape, backend: str, mesh=None, *,
     if not measured_ms or measured_ms <= 0:
         return None
     devices = _mesh_devices(mesh)
-    ideal = ideal_time_ms(shape, backend, devices=devices, mode=mode,
-                          direct_max=direct_max)
+    ideal = ideal_time_ms(shape, backend, kind=kind, devices=devices,
+                          mode=mode, direct_max=direct_max)
     if ideal is None:
         return None
     mxu, precision, _ = _backend_model(backend)
@@ -323,21 +335,22 @@ def roofline_row(measured_ms: float, shape, backend: str, mesh=None, *,
         "ideal_ms": float(f"{ideal:.4g}"),
         "roofline_fraction": float(f"{ideal / measured_ms:.4g}"),
         "model": (f"mxu-4mm@{precision}" if mxu else "nominal@high"),
+        "kind": kind,
         "mode": mode,
         "devices": devices,
     }
 
 
 def roofline_fraction(measured_ms: float, shape, backend: str,
-                      mesh=None, *, mode: str = "roundtrip",
+                      mesh=None, *, kind: str, mode: str = "roundtrip",
                       direct_max: "Optional[int]" = None
                       ) -> Optional[float]:
     """``ideal_time_ms / measured_ms`` — the honest, tracked fraction of
     the per-plan roofline a measurement achieved (ROADMAP item 3's gate:
     every perf PR must move this number, and the CI roofline job fails a
     >10% regression against the committed BENCH_DETAILS.json)."""
-    row = roofline_row(measured_ms, shape, backend, mesh, mode=mode,
-                       direct_max=direct_max)
+    row = roofline_row(measured_ms, shape, backend, mesh, kind=kind,
+                       mode=mode, direct_max=direct_max)
     return None if row is None else row["roofline_fraction"]
 
 
@@ -360,12 +373,8 @@ def tracked_fractions(path: Optional[str] = None) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Roofline table from the committed measurement CSV
+# Roofline table from a measurement CSV
 # ---------------------------------------------------------------------------
-
-_CSV = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "..",
-                    "eval", "benchmarks", "tpu_v5e",
-                    "single_chip_chain_timed.csv")
 
 # backend label -> (counts_on_mxu, precision, radix2). The all-real-planes
 # formulation issues the identical matmuls on split (re, im) planes, so it
@@ -416,9 +425,14 @@ def _parse_backend(label: str):
     return base, dmax
 
 
-def roofline_rows(csv_path: str = _CSV) -> list:
-    """Parse the measured CSV and return roofline dicts for every row
-    whose backend has an exact MXU MAC count."""
+def roofline_rows(csv_path: str, kind: str) -> list:
+    """Parse a measurement CSV (columns ``size``, ``transform``,
+    ``backend``, ``per_iter_ms``, ``gflops``) taken on ``kind`` chips and
+    return roofline dicts for every row whose backend has an exact MXU
+    MAC count. A kind with no published peak raises: a table without a
+    denominator says nothing."""
+    if kind not in PEAKS:
+        raise ValueError(f"no peak for {kind!r}; known: {sorted(PEAKS)}")
     out = []
     with open(csv_path) as f:
         header = f.readline().strip().split(",")
@@ -451,7 +465,7 @@ def roofline_rows(csv_path: str = _CSV) -> list:
                                          radix2=r2)
             else:
                 continue
-            peak = effective_peak_tflops(precision)
+            peak = effective_peak_tflops(kind, precision)
             t4 = f4 / (per_ms * 1e-3) / 1e12
             t3 = f3 / (per_ms * 1e-3) / 1e12
             out.append({
@@ -466,41 +480,17 @@ def roofline_rows(csv_path: str = _CSV) -> list:
     return out
 
 
-def _cube512_clause(rows) -> str:
-    """Utilization bounds for the headline 512^3 matmul@high row, quoted
-    FROM the rendered rows so the narrative can never contradict its own
-    table; empty when that row is absent."""
-    for r in rows:
-        if r["size"] == "512^3" and r["backend"] == "matmul@high":
-            return (f" (512^3 runs at {100 * r['util_3mm']:.0f}-"
-                    f"{100 * r['util_4mm']:.0f}% of effective peak)")
-    return ""
-
-
-def _nominal_drop_clause(rows) -> str:
-    """The 256^3 -> 512^3 nominal-GFLOPS drop, quoted FROM the rendered
-    rows for the same can't-contradict-the-table reason; falls back to
-    the sizeless statement when either row is absent."""
-    vals = {}
-    for r in rows:
-        if r["backend"] == "matmul@high" and r["size"] in ("256^3", "512^3"):
-            vals.setdefault(r["size"], r["nominal_gflops"])
-    if len(vals) == 2:
-        return (f"the 256^3 -> 512^3 nominal drop ({vals['256^3']:.1f} -> "
-                f"{vals['512^3']:.1f}) is")
-    return "the nominal fall with size is"
-
-
-def render_markdown(rows, path: Optional[str] = None) -> str:
+def render_markdown(rows, kind: str, path: Optional[str] = None) -> str:
+    eff = {p: effective_peak_tflops(kind, p) for p in ("high", "highest")}
     lines = [
-        "# MXU-utilization roofline (v5e single chip)",
+        f"# MXU-utilization roofline ({kind}, one chip)",
         "",
-        "Measured roundtrip rows from `single_chip_chain_timed.csv`, with",
-        "the MXU flops the matmul backend ACTUALLY executes (counted by",
-        "`evalkit/roofline.py`, mirroring `ops/mxu_fft.py` dispatch)",
-        "against the v5e's effective peak (197 bf16 TFLOPS; `HIGH` = 3-pass",
-        "bf16 f32 emulation -> 65.7 TFLOPS effective, `HIGHEST` = 6-pass",
-        "-> 32.8).",
+        "Measured roundtrip rows, with the MXU flops the matmul backend",
+        "ACTUALLY executes (counted by `evalkit/roofline.py`, mirroring",
+        "`ops/mxu_fft.py` dispatch) against the chip's effective peak",
+        f"({PEAKS[kind]['bf16_tflops']:g} bf16 TFLOPS; `HIGH` = 3-pass bf16",
+        f"f32 emulation -> {eff['high']:.1f} TFLOPS effective, `HIGHEST` =",
+        f"6-pass -> {eff['highest']:.1f}).",
         "",
         "| size | backend | ms/iter | nominal GFLOPS | MXU TFLOPS "
         "(3mm-4mm) | eff. peak | utilization (3mm-4mm) |",
@@ -517,28 +507,9 @@ def render_markdown(rows, path: Optional[str] = None) -> str:
         "",
         "The two bounds bracket XLA's complex-dot lowering: `4mm` = the",
         "textbook 4-real-matmul decomposition, `3mm` = the 3-multiplication",
-        "Karatsuba form. The 128^3 row EXCEEDING peak under 4mm proves the",
-        "actual lowering is cheaper than 4 matmuls, so the hardware truth",
-        "lies between the columns (R2C/C2R passes are exact in both — they",
-        "are explicit real-matmul pairs in `ops/mxu_fft.py`). For",
-        "`matmul-planes` the 4mm column is EXACT everywhere: `_rp_stage`",
-        "writes the 4 real einsums out explicitly, nothing is left to the",
-        "compiler's complex lowering.",
-        "",
-        "Reading: NOMINAL GFLOPS (2.5·N·log2 N — what a textbook FFT would",
-        "need) falls with size because the matmul backend spends O(n)",
-        "MACs/element per axis, while MXU utilization stays high — "
-        + _nominal_drop_clause(rows) + " the O(n)/O(log n)",
-        "flop-count ratio growing, not the chip idling"
-        + _cube512_clause(rows) + ". The outliers are the point of the",
-        "table: matmul-r2's low utilization shows its interleave relayout",
-        "starving the MXU (matching its measured net loss), and the",
-        "batched-2D rows' low single digits show the four-step swapaxes",
-        "relayouts are HBM-bound — the 2026-07-31 on-chip chunk sweep",
-        "(session_r5.jsonl) found per-plane lax.map slices (chunk size 1)",
-        "fastest, with larger fused slices monotonically slower (the",
-        "whole-stack fused program failed remote compile 2026-07-30 and",
-        "remains unmeasured).",
+        "Karatsuba form; the hardware truth lies between the columns (R2C/",
+        "C2R passes are exact in both — they are explicit real-matmul pairs",
+        "in `ops/mxu_fft.py`).",
     ]
     text = "\n".join(lines) + "\n"
     if path:
@@ -551,17 +522,16 @@ def main(argv=None) -> int:
     import argparse
     ap = argparse.ArgumentParser(
         "dfft-roofline", description="Render the MXU roofline table from "
-        "the committed single-chip measurement CSV.")
-    ap.add_argument("--csv", default=_CSV)
+        "a measurement CSV taken on one kind of chip.")
+    ap.add_argument("--csv", required=True)
+    ap.add_argument("--device-kind", required=True,
+                    help=f"the chips' jax device_kind, one of "
+                         f"{sorted(PEAKS)}")
     ap.add_argument("--out", default=None,
                     help="write markdown here (default: print)")
     a = ap.parse_args(argv)
-    if not os.path.exists(a.csv):
-        ap.error(f"measurement CSV not found: {a.csv} — the default path "
-                 "resolves inside a source checkout (eval/ is not "
-                 "packaged); pass --csv explicitly")
-    rows = roofline_rows(a.csv)
-    text = render_markdown(rows, a.out)
+    rows = roofline_rows(a.csv, a.device_kind)
+    text = render_markdown(rows, a.device_kind, a.out)
     if not a.out:
         print(text, end="")
     return 0

@@ -271,10 +271,9 @@ class DistFFTPlan:
         through the distributed spectral pipeline (all_to_all transposes
         included), an enclosing ``jax.jit``, etc. A capability the
         reference's hand-rolled MPI exchanges cannot express. The sharded
-        collectives differentiate cleanly; the local transform's vjp is
-        backend-dependent (``fft_backend="matmul"`` — pure einsum — is the
-        differentiable TPU-native choice; XLA's FFT op may lack a transpose
-        rule under shard_map). See tests/test_autodiff.py."""
+        collectives and the local transforms differentiate cleanly (the
+        xla backend's rfft carries its own tangent rule so that it
+        transposes under shard_map). See tests/test_autodiff.py."""
         raise NotImplementedError
 
     def inverse_fn(self) -> Callable[..., Any]:

@@ -449,10 +449,15 @@ def _roofline_lines(args, kind: str, backend: str) -> list:
                  "(2.5·N·log2 N per direction)")
     lines.append(f"  matmul-backend MXU flops: {mxu3 / 1e9:.2f}-"
                  f"{mxu4 / 1e9:.2f} GF (3mm-4mm complex-dot bracket)")
-    peak = rl.effective_peak_tflops("high")
+    import jax
+    dev_kind = jax.devices()[0].device_kind
+    peak = rl.effective_peak_tflops(dev_kind, "high")
+    if peak is None:
+        return lines + [f"  no peak for {dev_kind} (evalkit/roofline.py "
+                        "PEAKS): no ideal time"]
     ideal_ms = mxu4 / (peak * 1e12) * 1e3
-    lines.append(f"  v5e effective peak @high: {peak:.1f} TFLOPS -> ideal "
-                 f"matmul roundtrip >= {ideal_ms:.2f} ms "
+    lines.append(f"  {dev_kind} effective peak @high: {peak:.1f} TFLOPS -> "
+                 f"ideal matmul roundtrip >= {ideal_ms:.2f} ms "
                  "(100% MXU; backend here: " + backend + ")")
     # Predicted roofline_fraction (ISSUE 10 gate): the fraction a
     # measurement of this workload would score is ideal_ms/measured_ms;

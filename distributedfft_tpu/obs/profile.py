@@ -415,6 +415,14 @@ def find_trace_files(logdir: str) -> List[str]:
 
 # Lines that carry host python bookkeeping, not op executions.
 _SKIP_LINES = re.compile(r"^(python|launcher|\$)", re.IGNORECASE)
+# Host-runtime dispatch events on the CPU backend's thread-pool lines
+# (argument handling, output allocation, pool bookkeeping): not ops.
+_RUNTIME_EVENTS = re.compile(
+    r"^(PjRtCpuExecutable::|CommonPjRtClient::|ThreadpoolListener::"
+    r"|Handle inputs$)")
+# A TPU device plane carries a module-level line beside the op-level
+# one; counting both would charge every op twice.
+_DEVICE_OP_LINE = "XLA Ops"
 
 
 def _self_times(events: List[Dict[str, Any]]) -> List[Tuple[
@@ -422,8 +430,11 @@ def _self_times(events: List[Dict[str, Any]]) -> List[Tuple[
     """``(scope, self_time_ps)`` per event of ONE line: an event interval
     that contains other events is charged only for the time its children
     do not cover (flame-graph self time), so a ``call`` op wrapping a
-    fusion is not counted twice."""
-    evs = [e for e in events if e.get("dur_ps", 0) > 0]
+    fusion is not counted twice. An unscoped child of a scoped op (the
+    CPU runtime's ``Rendezvous`` inside an ``all-to-all``, its
+    ``end: <op>`` marker) is part of that op and inherits its scope."""
+    evs = [e for e in events if e.get("dur_ps", 0) > 0
+           and not _RUNTIME_EVENTS.match(e.get("name", ""))]
     evs.sort(key=lambda e: (e["offset_ps"], -e["dur_ps"]))
     out: List[Tuple[Optional[str], float]] = []
     stack: List[Dict[str, Any]] = []  # open ancestors, innermost last
@@ -438,6 +449,8 @@ def _self_times(events: List[Dict[str, Any]]) -> List[Tuple[
                         max(0.0, parent["dur_ps"] - covered)))
         if stack and end <= stack[-1]["offset_ps"] + stack[-1]["dur_ps"]:
             child_time[-1] += e["dur_ps"]
+            if not e.get("scope") and stack[-1].get("scope"):
+                e = dict(e, scope=stack[-1]["scope"])
         stack.append(e)
         child_time.append(0.0)
     while stack:
@@ -450,22 +463,34 @@ def _self_times(events: List[Dict[str, Any]]) -> List[Tuple[
 
 def aggregate_trace(planes: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Aggregate device time by scope over the op-execution lines.
-    Device planes (``/device:...``) win when present (TPU); host planes
+    Device planes (``/device:...``) win when present (TPU), read from
+    their op-level line (``XLA Ops``) where they have one; host planes
     otherwise (the CPU backend runs its ops on host thread-pool lines).
     Returns ``{"scopes": {scope: ms}, "unattributed_ms", "total_ms",
-    "planes": [names]}`` — python bookkeeping lines are skipped, nested
-    ops resolved by self time."""
+    "window_ms", "planes": [names]}`` — python bookkeeping lines are
+    skipped, nested ops resolved by self time. ``window_ms`` is the
+    longest first-to-last-event span of one chosen line: on a device,
+    the ops of one line can never add up to more."""
     device = [p for p in planes if p["name"].startswith("/device:")
               and any(ln["events"] for ln in p["lines"])]
     chosen = device or [p for p in planes
                         if any(ln["events"] for ln in p["lines"])]
     scopes: Dict[str, float] = {}
     unattributed = 0.0
+    window = 0.0
     for plane in chosen:
-        for line in plane["lines"]:
-            if _SKIP_LINES.match(line["name"] or ""):
-                continue
-            for scope, ps in _self_times(line["events"]):
+        lines = [ln for ln in plane["lines"]
+                 if not _SKIP_LINES.match(ln["name"] or "")]
+        if plane in device and any(ln["name"] == _DEVICE_OP_LINE
+                                   for ln in lines):
+            lines = [ln for ln in lines if ln["name"] == _DEVICE_OP_LINE]
+        for line in lines:
+            evs = [e for e in line["events"] if e.get("dur_ps", 0) > 0]
+            if evs:
+                window = max(window, max(e["offset_ps"] + e["dur_ps"]
+                                         for e in evs)
+                             - min(e["offset_ps"] for e in evs))
+            for scope, ps in _self_times(evs):
                 if scope:
                     scopes[scope] = scopes.get(scope, 0.0) + ps
                 else:
@@ -475,6 +500,7 @@ def aggregate_trace(planes: List[Dict[str, Any]]) -> Dict[str, Any]:
         "scopes": {k: round(v * to_ms, 6) for k, v in sorted(scopes.items())},
         "unattributed_ms": round(unattributed * to_ms, 6),
         "total_ms": round((sum(scopes.values()) + unattributed) * to_ms, 6),
+        "window_ms": round(window * to_ms, 6),
         "planes": [p["name"] for p in chosen],
     }
 
@@ -531,6 +557,7 @@ def capture_stage_profile(plan: Any, direction: str = "forward",
                    for k, v in agg["scopes"].items()},
         "unattributed_ms": round(agg["unattributed_ms"] / iters, 6),
         "total_ms": round(agg["total_ms"] / iters, 6),
+        "window_ms": round(agg["window_ms"] / iters, 6),
         "planes": agg["planes"],
     }
     agg["iters"] = iters
@@ -561,12 +588,14 @@ def node_scope_key(graph: Any, node: Any) -> Optional[str]:
     return None
 
 
-def _node_ideal_ms(graph: Any, node: Any, ranks: int) -> Optional[float]:
+def _node_ideal_ms(graph: Any, node: Any, ranks: int,
+                   kind: str) -> Optional[float]:
     """Nominal ideal time of one local-FFT stage: 2.5*N*log2(extent) per
-    transformed axis over the v5e effective peak, per-chip share on the
-    mesh (the roofline module's convention — communication deliberately
-    unmodeled, so exchange nodes have no ideal; their measured time IS
-    the roofline gap)."""
+    transformed axis over the effective peak of a ``kind`` chip, per-chip
+    share on the mesh (the roofline module's convention — communication
+    deliberately unmodeled, so exchange nodes have no ideal; their
+    measured time IS the roofline gap). None for a kind with no
+    published peak."""
     if node.kind != "local_fft" or not node.axes:
         return None
     from ..evalkit import roofline as rl
@@ -583,10 +612,10 @@ def _node_ideal_ms(graph: Any, node: Any, ranks: int) -> Optional[float]:
             flops += 2.5 * elems * math.log2(shape[a])
     if flops <= 0:
         return None
-    peak = rl.effective_peak_tflops("high") * 1e12 * max(1, ranks)
-    # Significant-digit rounding (the roofline_row convention): a tiny
-    # CPU tracking ideal must never collapse to 0.0.
-    return float(f"{flops / peak * 1e3:.4g}")
+    peak = rl.effective_peak_tflops(kind, "high")
+    if peak is None:
+        return None
+    return float(f"{flops / (peak * 1e12 * max(1, ranks)) * 1e3:.4g}")
 
 
 def stage_profile(plan: Any, direction: str = "forward", dims: int = 3,
@@ -610,6 +639,10 @@ def stage_profile(plan: Any, direction: str = "forward", dims: int = 3,
     mesh = getattr(plan, "mesh", None)
     if mesh is not None:
         ranks = math.prod(mesh.devices.shape)
+        kind = mesh.devices.flat[0].device_kind
+    else:
+        import jax
+        kind = jax.devices()[0].device_kind
     # Nodes sharing one scope key (two encodes under a dual-exchange p2p
     # pencil both land in "wire/encode") split that key's time evenly.
     keys: Dict[str, List[Any]] = {}
@@ -632,7 +665,7 @@ def stage_profile(plan: Any, direction: str = "forward", dims: int = 3,
             approx = nshare > 1
             consumed[k] = t
         ms = round(share, 6) if share is not None else 0.0
-        ideal = _node_ideal_ms(graph, n, ranks)
+        ideal = _node_ideal_ms(graph, n, ranks, kind)
         row: Dict[str, Any] = {
             "node": n.id, "kind": n.kind,
             "label": n.label or plangraph._node_brief(n),
@@ -661,6 +694,7 @@ def stage_profile(plan: Any, direction: str = "forward", dims: int = 3,
         "direction": direction,
         "iters": agg.get("iters", iters),
         "total_ms": round(total, 6),
+        "window_ms": agg.get("window_ms"),
         "attributed_ms": round(attributed, 6),
         "unattributed_ms": round(
             float(agg["unattributed_ms"]) + sum(other.values()), 6),

@@ -226,9 +226,8 @@ def irfft(x, n: int, axis: int, norm: FFTNorm = FFTNorm.NONE):
     if is_smooth(n):
         inorm = {FFTNorm.NONE: "forward", FFTNorm.ORTHO: "ortho",
                  FFTNorm.BACKWARD: "backward"}[norm]
-        y = jnp.moveaxis(x, axis, -1)
-        y = jnp.fft.irfft(y, n=n, norm=inorm)
-        return jnp.moveaxis(y, -1, axis)
+        from .fft import xla_irfft
+        return xla_irfft(x, n, axis, inorm)
     cdt = np.complex128 if _is_double(x.dtype) else np.complex64
     c = jnp.moveaxis(x.astype(cdt), axis, -1)
     c = _fit_axis(c, -1, n // 2 + 1)
@@ -278,7 +277,8 @@ def irfftn_3d(x, shape_3d: Tuple[int, int, int], norm: FFTNorm = FFTNorm.NONE):
     if all(is_smooth(n) for n in shape_3d[-3:]):
         inorm = {FFTNorm.NONE: "forward", FFTNorm.ORTHO: "ortho",
                  FFTNorm.BACKWARD: "backward"}[norm]
-        return jnp.fft.irfftn(x, s=shape_3d, axes=(-3, -2, -1), norm=inorm)
+        from .fft import irfftn_3d as xla_irfftn_3d
+        return xla_irfftn_3d(x, shape_3d, norm)
     c = ifft(_fit_axis(x, -3, shape_3d[-3]), axis=-3, norm=norm)
     c = ifft(_fit_axis(c, -2, shape_3d[-2]), axis=-2, norm=norm)
     return irfft(c, n=shape_3d[-1], axis=-1, norm=norm)

@@ -28,8 +28,13 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
+import math
 from typing import Sequence, Tuple
 
+import numpy as np
+
+import jax
 import jax.numpy as jnp
 
 from ..params import FFTNorm
@@ -122,7 +127,28 @@ def rfft(x, axis: int, norm: FFTNorm = FFTNorm.NONE, backend: str = "xla",
     if m is not None:
         with _settings_ctx(backend, settings):
             return m.rfft(x, axis=axis, norm=norm)
-    return jnp.fft.rfft(x, axis=axis, norm=_fwd_norm(norm))
+    return _xla_rfft(x, axis, _fwd_norm(norm))
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1, 2))
+def _xla_rfft(x, axis: int, norm: str):
+    """``jnp.fft.rfft`` whose tangent map is spelled as slice(fft(x)).
+    JAX's own transpose of rfft traces a naive rfft against an unsharded
+    dummy, so inside ``shard_map`` its output type lacks the cotangent's
+    varying mesh axes and the backward pass is refused; slice, fft and
+    the real-to-complex cast transpose with the types intact."""
+    return jnp.fft.rfft(x, axis=axis, norm=norm)
+
+
+@_xla_rfft.defjvp
+def _xla_rfft_jvp(axis, norm, primals, tangents):
+    x, = primals
+    t, = tangents
+    m = x.shape[axis] // 2 + 1
+    full = jnp.fft.fft(t.astype(jnp.result_type(t, 1j)), axis=axis,
+                       norm=norm)
+    return _xla_rfft(x, axis, norm), jax.lax.slice_in_dim(full, 0, m,
+                                                          axis=axis)
 
 
 def irfft(x, n: int, axis: int, norm: FFTNorm = FFTNorm.NONE,
@@ -133,7 +159,55 @@ def irfft(x, n: int, axis: int, norm: FFTNorm = FFTNorm.NONE,
     if m is not None:
         with _settings_ctx(backend, settings):
             return m.irfft(x, n=n, axis=axis, norm=norm)
-    return jnp.fft.irfft(x, n=n, axis=axis, norm=_inv_norm(norm))
+    return xla_irfft(x, n, axis, _inv_norm(norm))
+
+
+def _fit_last(x, k: int):
+    """Crop or zero-pad the last axis to ``k`` (numpy's irfft rule)."""
+    if x.shape[-1] > k:
+        return x[..., :k]
+    if x.shape[-1] < k:
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, k - x.shape[-1])])
+    return x
+
+
+def _reversed_last(x):
+    """``x[..., ::-1]`` as a gather. On a TPU v5e (JAX 0.9.0) a reverse
+    op reading the output of an FFT in the same program returns wrong
+    values at 256^3-class shapes; the gather does not (chip runs,
+    PR 21)."""
+    return jnp.take(x, np.arange(x.shape[-1] - 1, -1, -1), axis=-1)
+
+
+def xla_irfft(x, n: int, axis: int = -1, norm: str = "backward"):
+    """Inverse C2R along one axis, equal to ``jnp.fft.irfft``. XLA's own
+    IRFFT builds the Hermitian half with a reverse op, and on a TPU v5e
+    (JAX 0.9.0) that goes wrong after inverse FFTs along other axes in
+    the same program (``irfftn`` at 256^3; chip runs, PR 21). So the
+    real inverse of even length n is one complex inverse of length n/2:
+    with a = X[k], b = conj(X[n/2-k]), Z[k] = (a + b)/2 + i (a - b)
+    e^{2 pi i k/n}/2 holds the DFTs of the even and odd samples, and
+    ifft(Z) interleaves them. An odd n takes the Hermitian-extended
+    full-length inverse. Like numpy, the spectral axis is cropped or
+    zero-padded to n//2 + 1 and the imaginary parts of the DC and
+    Nyquist bins are ignored."""
+    x = jnp.moveaxis(x, axis, -1)
+    m = n // 2
+    x = _fit_last(x, m + 1)
+    scale = {"backward": 1.0, "forward": float(n),
+             "ortho": math.sqrt(n)}[norm]
+    if n % 2:
+        tail = jnp.conj(_reversed_last(x[..., 1:]))
+        y = jnp.real(jnp.fft.ifft(jnp.concatenate([x, tail], axis=-1)))
+        return jnp.moveaxis(y * scale, -1, axis)
+    k = np.arange(m + 1)
+    x = jnp.where((k == 0) | (k == m), jnp.real(x).astype(x.dtype), x)
+    a = x[..., :m]
+    b = jnp.conj(_reversed_last(x[..., 1:]))
+    w = jnp.exp(2j * np.pi * np.arange(m) / n).astype(x.dtype)
+    z = jnp.fft.ifft((a + b) + 1j * (a - b) * w) * (0.5 * scale)
+    y = jnp.stack([jnp.real(z), jnp.imag(z)], axis=-1)
+    return jnp.moveaxis(y.reshape(x.shape[:-1] + (n,)), -1, axis)
 
 
 def fft(x, axis: int, norm: FFTNorm = FFTNorm.NONE, backend: str = "xla",
@@ -193,4 +267,7 @@ def irfftn_3d(x, shape_3d: Tuple[int, int, int], norm: FFTNorm = FFTNorm.NONE,
     if m is not None:
         with _settings_ctx(backend, settings):
             return m.irfftn_3d(x, shape_3d=shape_3d, norm=norm)
-    return jnp.fft.irfftn(x, s=shape_3d, axes=(-3, -2, -1), norm=_inv_norm(norm))
+    inorm = _inv_norm(norm)
+    x = jnp.fft.ifft(x, n=shape_3d[0], axis=-3, norm=inorm)
+    x = jnp.fft.ifft(x, n=shape_3d[1], axis=-2, norm=inorm)
+    return xla_irfft(x, shape_3d[2], -1, inorm)

@@ -73,12 +73,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # pltpu imports fail on builds without TPU support compiled in
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from ..params import FFTNorm
 from . import mxu_fft as mx
@@ -105,10 +100,6 @@ def _prec():
 def _interpret() -> bool:
     """Compile on TPU; interpret elsewhere (the CPU test mesh)."""
     return jax.default_backend() != "tpu"
-
-
-def available() -> bool:
-    return _HAS_PLTPU
 
 
 # ---------------------------------------------------------------------------
@@ -222,36 +213,18 @@ def _f32_planes(F: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
             np.ascontiguousarray(F.imag.astype(np.float32)))
 
 
-def _sds(shape, dtype, vma):
-    """``ShapeDtypeStruct`` carrying the vma set where the runtime supports
-    it (jax >= 0.5); pre-vma runtimes take the bare struct — the set is
-    always empty there (see ``_vma``), so nothing is lost."""
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+def _spec(block, index_map):
+    """``pl.BlockSpec`` whose block indices stay int32 under
+    ``jax_enable_x64`` (Mosaic refuses the i64 constants it would
+    otherwise get)."""
+    return pl.BlockSpec(
+        block, lambda *g: tuple(jnp.int32(v) for v in index_map(*g)))
 
 
 def _vma(x) -> frozenset:
-    """The value's varying-across-mesh-axes set, or empty when the runtime
-    predates ``jax.typeof``/vma tracking (jax < 0.5, where shard_map has no
-    per-value vma and nothing needs lifting)."""
-    typeof = getattr(jax, "typeof", None)
-    return getattr(typeof(x), "vma", frozenset()) if typeof else frozenset()
-
-
-def _under_rewrite() -> bool:
-    """True inside shard_map's replication-checking rewrite on runtimes
-    predating vma tracking (jax < 0.5), where ``pallas_call`` has no
-    replication rule — the kernels' jnp-equivalent interpret fallback must
-    apply there. On vma runtimes ``_vma`` carries this signal instead."""
-    if hasattr(jax, "typeof"):
-        return False
-    try:
-        from jax._src import core as jcore
-        return type(jcore.trace_ctx.trace).__name__ in ("RewriteTrace",
-                                                        "ShardMapTrace")
-    except Exception:  # noqa: BLE001 — unknown internals: assume plain trace
-        return False
+    """The value's varying-across-mesh-axes set (empty outside
+    shard_map)."""
+    return jax.typeof(x).vma
 
 
 def _lift_vma(args, vma):
@@ -279,7 +252,7 @@ def _call_stage(x2, F_np: np.ndarray, twiddle: "Tuple[int, int, bool] | None"):
     k = F_np.shape[1]
     real_in = not jnp.issubdtype(x2.dtype, jnp.complexfloating)
 
-    if _interpret() and (_vma(x2) or _under_rewrite()):
+    if _interpret() and _vma(x2):
         # Pallas's HLO interpreter cannot yet thread shard_map's vma through
         # its internal grid loop carries; off-TPU, inside shard_map, compute
         # the stage with the equivalent jnp ops (the compiled Mosaic path on
@@ -304,14 +277,14 @@ def _call_stage(x2, F_np: np.ndarray, twiddle: "Tuple[int, int, bool] | None"):
     grid = (m_pad // tb,)
 
     fr, fi = _f32_planes(F_np)
-    row_spec = pl.BlockSpec((tb, n), lambda i: (i, 0))
-    const_spec = pl.BlockSpec((n, k), lambda i: (0, 0))
-    tw_spec = pl.BlockSpec((tb, k), lambda i: (0, 0))
-    out_spec = pl.BlockSpec((tb, k), lambda i: (i, 0))
+    row_spec = _spec((tb, n), lambda i: (i, 0))
+    const_spec = _spec((n, k), lambda i: (0, 0))
+    tw_spec = _spec((tb, k), lambda i: (0, 0))
+    out_spec = _spec((tb, k), lambda i: (i, 0))
     # Propagate the input's varying-across-mesh-axes set so the kernel works
     # under shard_map's vma checking (per-shard data varies over the mesh).
     vma = _vma(x2)
-    out_shape = [_sds((m_pad, k), jnp.float32, vma)] * 2
+    out_shape = [jax.ShapeDtypeStruct((m_pad, k), jnp.float32, vma=vma)] * 2
 
     flops_c = (2 if real_in else 4) * 2 * m_pad * n * k
     cost = pl.CostEstimate(flops=flops_c, transcendentals=0,
@@ -361,7 +334,7 @@ def _c2r_stage(c, n: int):
     CR, CI = mx._c2r_np(n, False)
     xr, xi = jnp.real(c2), jnp.imag(c2)
 
-    if _interpret() and (_vma(c2) or _under_rewrite()):
+    if _interpret() and _vma(c2):
         y2 = (jnp.matmul(xr, jnp.asarray(CR), precision=_prec())
               - jnp.matmul(xi, jnp.asarray(CI), precision=_prec()))
         return y2.reshape(lead + (n,))
@@ -372,16 +345,16 @@ def _c2r_stage(c, n: int):
         xr = jnp.pad(xr, [(0, m_pad - m), (0, 0)])
         xi = jnp.pad(xi, [(0, m_pad - m), (0, 0)])
     vma = _vma(c2)
-    row_spec = pl.BlockSpec((tb, n_in), lambda i: (i, 0))
-    const_spec = pl.BlockSpec((n_in, n), lambda i: (0, 0))
-    out_spec = pl.BlockSpec((tb, n), lambda i: (i, 0))
+    row_spec = _spec((tb, n_in), lambda i: (i, 0))
+    const_spec = _spec((n_in, n), lambda i: (0, 0))
+    out_spec = _spec((tb, n), lambda i: (i, 0))
     args = _lift_vma([xr, xi, jnp.asarray(CR), jnp.asarray(CI)], vma)
     y2 = pl.pallas_call(
         _c2r_kernel,
         grid=(m_pad // tb,),
         in_specs=[row_spec, row_spec, const_spec, const_spec],
         out_specs=out_spec,
-        out_shape=_sds((m_pad, n), jnp.float32, vma),
+        out_shape=jax.ShapeDtypeStruct((m_pad, n), jnp.float32, vma=vma),
         cost_estimate=pl.CostEstimate(
             flops=2 * 2 * m_pad * n_in * n, transcendentals=0,
             bytes_accessed=4 * (m_pad * (2 * n_in + n) + 2 * n_in * n)),
@@ -391,9 +364,9 @@ def _c2r_stage(c, n: int):
 
 
 def _use_fallback(x) -> bool:
-    """jnp-matmul fallback: no pltpu build, f64 data (kernel is f32-only;
-    f64 gates run via the matmul backend on CPU), or oversized axis."""
-    return (not _HAS_PLTPU) or mx._is_double(x.dtype)
+    """jnp-matmul fallback: f64 data (kernel is f32-only; f64 gates run
+    via the matmul backend on CPU)."""
+    return mx._is_double(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -512,7 +485,7 @@ def fused3d_applicable(shape3, dtype) -> bool:
     """The fused two-kernel path handles 3D arrays whose axes all take a
     single direct DFT matmul; larger axes go through the four-step
     per-axis path."""
-    return (_HAS_PLTPU and not mx._is_double(dtype)
+    return (not mx._is_double(dtype)
             and len(shape3) == 3
             and all(2 <= n <= mx.DIRECT_MAX for n in shape3))
 
@@ -543,12 +516,12 @@ def _x_transform(yr, yi, inverse: bool, vma):
     zr, zi = pl.pallas_call(
         _x_c2c_kernel,
         grid=(Kp // tk,),
-        in_specs=[pl.BlockSpec((X, tk, Zo), lambda i: (0, i, 0)),
-                  pl.BlockSpec((X, tk, Zo), lambda i: (0, i, 0)),
-                  pl.BlockSpec((X, X), lambda i: (0, 0)),
-                  pl.BlockSpec((X, X), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((X, tk, Zo), lambda i: (0, i, 0))] * 2,
-        out_shape=[_sds((X, Kp, Zo), jnp.float32, vma)] * 2,
+        in_specs=[_spec((X, tk, Zo), lambda i: (0, i, 0)),
+                  _spec((X, tk, Zo), lambda i: (0, i, 0)),
+                  _spec((X, X), lambda i: (0, 0)),
+                  _spec((X, X), lambda i: (0, 0))],
+        out_specs=[_spec((X, tk, Zo), lambda i: (0, i, 0))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((X, Kp, Zo), jnp.float32, vma=vma)] * 2,
         cost_estimate=pl.CostEstimate(
             flops=4 * X * X * Kp * Zo * 2, transcendentals=0,
             bytes_accessed=4 * X * Kp * Zo * 4),
@@ -577,13 +550,13 @@ def _rfftn3d_fused(x):
     yr, yi = pl.pallas_call(
         _zy_fwd_kernel,
         grid=(Xp // B,),
-        in_specs=[pl.BlockSpec((B, Y, Z), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((Z, Zo), lambda i: (0, 0)),
-                  pl.BlockSpec((Z, Zo), lambda i: (0, 0)),
-                  pl.BlockSpec((Y, Y), lambda i: (0, 0)),
-                  pl.BlockSpec((Y, Y), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((B, Y, Zo), lambda i: (i, 0, 0))] * 2,
-        out_shape=[_sds((Xp, Y, Zo), jnp.float32, vma)] * 2,
+        in_specs=[_spec((B, Y, Z), lambda i: (i, 0, 0)),
+                  _spec((Z, Zo), lambda i: (0, 0)),
+                  _spec((Z, Zo), lambda i: (0, 0)),
+                  _spec((Y, Y), lambda i: (0, 0)),
+                  _spec((Y, Y), lambda i: (0, 0))],
+        out_specs=[_spec((B, Y, Zo), lambda i: (i, 0, 0))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((Xp, Y, Zo), jnp.float32, vma=vma)] * 2,
         cost_estimate=pl.CostEstimate(
             flops=2 * Xp * Y * Z * Zo * 2 + 4 * Xp * Y * Y * Zo * 2,
             transcendentals=0,
@@ -620,14 +593,14 @@ def _irfftn3d_fused(c, shape_3d):
     y = pl.pallas_call(
         _yz_inv_kernel,
         grid=(Xp // B,),
-        in_specs=[pl.BlockSpec((B, Y, Zo), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((B, Y, Zo), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((Y, Y), lambda i: (0, 0)),
-                  pl.BlockSpec((Y, Y), lambda i: (0, 0)),
-                  pl.BlockSpec((Zo, Z), lambda i: (0, 0)),
-                  pl.BlockSpec((Zo, Z), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((B, Y, Z), lambda i: (i, 0, 0)),
-        out_shape=_sds((Xp, Y, Z), jnp.float32, vma),
+        in_specs=[_spec((B, Y, Zo), lambda i: (i, 0, 0)),
+                  _spec((B, Y, Zo), lambda i: (i, 0, 0)),
+                  _spec((Y, Y), lambda i: (0, 0)),
+                  _spec((Y, Y), lambda i: (0, 0)),
+                  _spec((Zo, Z), lambda i: (0, 0)),
+                  _spec((Zo, Z), lambda i: (0, 0))],
+        out_specs=_spec((B, Y, Z), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Xp, Y, Z), jnp.float32, vma=vma),
         scratch_shapes=[pltpu.VMEM((B, Y, Zo), jnp.float32)] * 2,
         cost_estimate=pl.CostEstimate(
             flops=4 * Xp * Y * Y * Zo * 2 + 2 * Xp * Y * Zo * Z * 2,
@@ -757,11 +730,11 @@ def _wire_planes_decode_jnp(y, dtype):
 
 
 def _wire_kernel_usable(x) -> bool:
-    """Whether the fused wire kernels can run on this value: a pltpu
-    build, f32-family data, and not the interpret-mode shard_map corner
-    (same contract as ``_call_stage``'s fallback)."""
-    return (_HAS_PLTPU and not mx._is_double(x.dtype)
-            and not (_interpret() and (_vma(x) or _under_rewrite())))
+    """Whether the fused wire kernels can run on this value: f32-family
+    data, and not the interpret-mode shard_map corner (same contract as
+    ``_call_stage``'s fallback)."""
+    return (not mx._is_double(x.dtype)
+            and not (_interpret() and _vma(x)))
 
 
 def _plane_pass(kern, planes, out_dtype):
@@ -775,14 +748,14 @@ def _plane_pass(kern, planes, out_dtype):
     if m_pad != m:
         p2 = [jnp.pad(p, [(0, m_pad - m), (0, 0)]) for p in p2]
     vma = _vma(planes[0])
-    spec = pl.BlockSpec((tb, n), lambda i: (i, 0))
+    spec = _spec((tb, n), lambda i: (i, 0))
     args = _lift_vma(p2, vma)
     yr, yi = pl.pallas_call(
         kern,
         grid=(m_pad // tb,),
         in_specs=[spec, spec],
         out_specs=[spec, spec],
-        out_shape=[_sds((m_pad, n), out_dtype, vma)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((m_pad, n), out_dtype, vma=vma)] * 2,
         interpret=_interpret(),
     )(*args)
     if m_pad != m:
@@ -867,15 +840,15 @@ def decode_fft_fused(y, dtype, axis: int, *, inverse: bool = False,
             pi2 = jnp.pad(pi2, [(0, m_pad - m), (0, 0)])
         fr, fi = _f32_planes(mx._dft_np(n, inverse, False))
         vma = _vma(y)
-        row_spec = pl.BlockSpec((tb, n), lambda i: (i, 0))
-        const_spec = pl.BlockSpec((n, n), lambda i: (0, 0))
+        row_spec = _spec((tb, n), lambda i: (i, 0))
+        const_spec = _spec((n, n), lambda i: (0, 0))
         args = _lift_vma([pr2, pi2, jnp.asarray(fr), jnp.asarray(fi)], vma)
         yr, yi = pl.pallas_call(
             _dec_cmatmul_kernel,
             grid=(m_pad // tb,),
             in_specs=[row_spec, row_spec, const_spec, const_spec],
             out_specs=[row_spec, row_spec],
-            out_shape=[_sds((m_pad, n), jnp.float32, vma)] * 2,
+            out_shape=[jax.ShapeDtypeStruct((m_pad, n), jnp.float32, vma=vma)] * 2,
             cost_estimate=pl.CostEstimate(
                 flops=4 * 2 * m_pad * n * n, transcendentals=0,
                 bytes_accessed=2 * m_pad * n * 2 + 4 * (m_pad + n) * n * 2),
@@ -946,8 +919,7 @@ def _fused3d_usable(x, shape3) -> bool:
     # path's jnp fallback applies; everywhere else the fused path rules at
     # direct sizes.
     return (fused3d_applicable(shape3, x.dtype)
-            and not (_interpret()
-                     and (_vma(x) or _under_rewrite())))
+            and not (_interpret() and _vma(x)))
 
 
 def rfftn_3d(x, norm: FFTNorm = FFTNorm.NONE):

@@ -13,6 +13,7 @@ one MPI sub-communicator).
 from __future__ import annotations
 
 import math
+import os
 import threading
 from typing import Optional, Sequence, Tuple
 
@@ -36,21 +37,29 @@ DEVICE_LOCK = threading.RLock()
 
 
 def force_cpu_devices(n: int) -> None:
-    """Select the CPU platform with ``n`` virtual devices, portably across
-    jax releases: ``jax_num_cpu_devices`` exists from jax 0.5; older
-    runtimes only honor ``XLA_FLAGS=--xla_force_host_platform_device_count``
-    (which must land before the CPU backend initializes, so call this
-    before the first device query)."""
-    import os
-
+    """Select the CPU platform with ``n`` virtual devices (call before
+    the first device query: the CPU backend reads the count once)."""
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:  # jax < 0.5
-        opt = "--xla_force_host_platform_device_count"
-        kept = [t for t in os.environ.get("XLA_FLAGS", "").split()
-                if not t.startswith(opt + "=")]
-        os.environ["XLA_FLAGS"] = " ".join(kept + [f"{opt}={n}"])
+    jax.config.update("jax_num_cpu_devices", n)
+
+
+# The checkout root: the package lives one level below it.
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+    ``$JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
+    left alone; otherwise the cache goes to ``<checkout>/.jax_cache``
+    (git-ignored). A fixed path matters: the path is part of the key, so
+    a cache that moves never hits. Call before the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _topology_mesh(shape: Tuple[int, ...]):

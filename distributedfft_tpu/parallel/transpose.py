@@ -156,16 +156,6 @@ def wire_nbytes(shape, dtype, wire: str = WIRE_NATIVE) -> int:
     return math.prod(int(s) for s in shape) * wire_itemsize(dtype, wire)
 
 
-def _axis_size(axis_name) -> int:
-    """Static size of a named mesh axis, portable across jax releases:
-    ``lax.axis_size`` only exists from jax 0.5; older runtimes constant-fold
-    ``psum(1, axis)`` to the same Python int inside shard_map."""
-    try:
-        return lax.axis_size(axis_name)
-    except AttributeError:  # jax < 0.5
-        return lax.psum(1, axis_name)
-
-
 def pad_axis_to(x, axis: int, target: int):
     """Zero-pad ``axis`` up to ``target`` extent (no-op when already there)."""
     cur = x.shape[axis]
@@ -394,7 +384,7 @@ def _ring_transpose_impl(x, axis_name: str, split_axis: int,
             f"got {depth} (use overlap=False for the serial ring)")
     if subblocks < 1:
         raise ValueError(f"subblocks must be >= 1, got {subblocks}")
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     wired = _wire_active(x, wire)
     if pipeline_fn is None:
         def pipeline_fn(b):
@@ -716,7 +706,7 @@ def _all_to_all_native(x, axis_name: str, split_axis: int, concat_axis: int,
     if not realigned:
         return lax.all_to_all(x, axis_name, split_axis=split_axis,
                               concat_axis=concat_axis, tiled=True)
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     s, c = split_axis, concat_axis
     shp = x.shape
     if shp[s] % p:

@@ -511,9 +511,10 @@ class Config:
     HIGHEST. ``mxu_direct_max`` is the direct-plan threshold: axes up to
     this length are one dense contraction, longer axes take the
     four-step factorization — on a v5e at 1024^3 the all-direct plan
-    (``mxu_direct_max=1024``) beat the default four-step 2.9x
-    (session_r5.jsonl 2026-07-31; ``autotune_local_fft`` races it
-    automatically past the default threshold).
+    (``mxu_direct_max=1024``) once beat the default four-step 2.9x (a
+    pre-PR-12 row, not measured on the current code;
+    ``autotune_local_fft`` races it automatically past the default
+    threshold).
     """
 
     comm_method: CommMethod = CommMethod.ALL2ALL

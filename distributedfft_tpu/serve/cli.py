@@ -436,8 +436,9 @@ def main(argv=None) -> int:
     if args.obs:
         obs.enable_console()
 
+    from ..parallel.mesh import force_cpu_devices, use_compile_cache
+    use_compile_cache()
     if args.emulate_devices:
-        from ..parallel.mesh import force_cpu_devices
         force_cpu_devices(args.emulate_devices)
 
     from .. import params as pm

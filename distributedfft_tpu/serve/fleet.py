@@ -288,6 +288,15 @@ def _stats_lite(core: Any, devices: Optional[int] = None
     return out
 
 
+def _host_chips() -> int:
+    """Accelerator chips attached to this host, counted from their
+    device nodes (``/dev/accel*``; ``/dev/vfio/<n>`` on v5e and later)
+    without loading libtpu — the parent must not claim a chip."""
+    import glob
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            + len(glob.glob("/dev/vfio/[0-9]*")))
+
+
 def _worker_main(conn: Any, spec: Dict[str, Any]) -> None:
     """Entry point of one spawned worker process (module-level so the
     spawn context can pickle it)."""
@@ -610,6 +619,7 @@ class Fleet:
                              "worker backend (worker_backend='server')")
         self._resident_spec = dict(resident) if resident else None
         self._resident_index = int(resident_index)
+        self._refuse_shared_chips(n_workers, worker_backend, worker_env)
         self._ctx = multiprocessing.get_context("spawn")
         self._lock = threading.Lock()
         self._workers: Dict[str, _Worker] = {}
@@ -658,6 +668,28 @@ class Fleet:
 
     def __exit__(self, *exc: Any) -> None:
         self.close(drain=True)
+
+    def _refuse_shared_chips(self, n_workers: int, worker_backend: str,
+                             worker_env: Optional[Dict[str, str]]) -> None:
+        """A worker with no emulated CPU mesh initializes JAX's default
+        backend, and on a host with chips each such process loads libtpu
+        and claims every chip: the second one fails or hangs. Refuse at
+        start-up rather than leave workers hanging."""
+        if worker_backend != "server":
+            return
+        platforms = (worker_env or {}).get(
+            "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+        if platforms.strip().lower().startswith("cpu"):
+            return
+        real = [i for i in range(n_workers) if not self._devices_for(i)]
+        chips = _host_chips()
+        if chips and len(real) > 1:
+            raise ValueError(
+                f"fleet: {len(real)} workers would each load libtpu on a "
+                f"host with {chips} chip(s), and a chip belongs to one "
+                "process — run one accelerator worker (size the others "
+                "with worker_devices/emulate_devices, which keeps them on "
+                "the CPU) or serve from a single in-process Server")
 
     def _take_index(self) -> int:
         with self._lock:

@@ -15,12 +15,14 @@ import pytest
 import jax
 
 # Must run before any backend initialization (conftest imports precede
-# tests; importing the package does not initialize a backend). The helper
-# owns the jax<0.5 XLA_FLAGS fallback for the CPU device count.
+# tests; importing the package does not initialize a backend).
 from distributedfft_tpu.parallel.mesh import force_cpu_devices  # noqa: E402
 
 force_cpu_devices(8)
 jax.config.update("jax_enable_x64", True)
+# In-process CLI tests place the persistent compile cache
+# (mesh.use_compile_cache); tests compile fresh and write no cache.
+jax.config.update("jax_enable_compilation_cache", False)
 
 # Build the native planner once so its tests run instead of skipping on a
 # fresh checkout; a missing/failed toolchain degrades back to skip. The

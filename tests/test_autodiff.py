@@ -26,14 +26,16 @@ def _roundtrip_loss(plan, w):
     return loss
 
 
-def test_grad_through_sharded_slab_roundtrip(devices, rng):
+@pytest.mark.parametrize("backend", ["matmul", "xla"])
+def test_grad_through_sharded_slab_roundtrip(devices, rng, backend):
     """grad of a weighted-sum loss through the 8-device slab forward +
     inverse (crosses the all_to_all transpose both ways). The
-    unnormalized roundtrip / N^3 is the identity, so dloss/dx = w."""
+    unnormalized roundtrip / N^3 is the identity, so dloss/dx = w.
+    The xla case crosses the rfft transpose inside shard_map."""
     g = dfft.GlobalSize(16, 16, 16)
     plan = dfft.SlabFFTPlan(g, dfft.SlabPartition(8),
                             dfft.Config(double_prec=True,
-                                        fft_backend="matmul"))
+                                        fft_backend=backend))
     w = rng.random(g.shape)
     got = np.asarray(jax.grad(_roundtrip_loss(plan, w))(rng.random(g.shape)))
     np.testing.assert_allclose(got, w, atol=1e-10)

@@ -669,3 +669,28 @@ def test_real_server_fleet_roundtrip():
             time.sleep(0.1)
         assert any(k.startswith("fleet.worker.queue_depth[")
                    for k in obs.metrics.snapshot()["gauges"])
+
+
+@pytest.mark.parametrize("kw, refused", [
+    (dict(n_workers=2), True),
+    (dict(n_workers=1), False),
+    (dict(n_workers=2, emulate_devices=2), False),
+    (dict(n_workers=2, worker_devices=[0, 2]), False),
+    (dict(n_workers=2, worker_env={"JAX_PLATFORMS": "cpu"}), False),
+])
+def test_fleet_refuses_workers_sharing_the_chips(monkeypatch, kw, refused):
+    """On a host with chips, more than one worker on the accelerator
+    backend is refused at start-up, before any worker is spawned."""
+    from distributedfft_tpu.serve import fleet as fleet_mod
+
+    def spawn(self, *args, **kwargs):
+        raise RuntimeError("spawned")
+
+    monkeypatch.setattr(fleet_mod, "_host_chips", lambda: 4)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(fleet_mod.Fleet, "_spawn", spawn)
+    with pytest.raises((ValueError, RuntimeError)) as ei:
+        fleet_mod.Fleet(**kw)
+    assert isinstance(ei.value, ValueError) == refused, ei.value
+    if refused:
+        assert "libtpu" in str(ei.value)

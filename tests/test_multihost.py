@@ -20,14 +20,6 @@ import jax
 import distributedfft_tpu as dfft
 from distributedfft_tpu.parallel import multihost as mh
 
-# jaxlib's CPU backend only gained multi-process collectives after 0.4.x
-# ("Multiprocess computations aren't implemented on the CPU backend"); the
-# two-process tests are a runtime capability, not a code path we can shim.
-_OLD_JAX = tuple(int(t) for t in jax.__version__.split(".")[:2]) < (0, 5)
-_two_proc = pytest.mark.skipif(
-    _OLD_JAX, reason="CPU multiprocess collectives need jax >= 0.5")
-
-
 def test_maybe_initialize_noop_single_process(monkeypatch):
     for var in (mh.ENV_COORD, mh.ENV_NPROCS, mh.ENV_PROCID,
                 "JAX_COORDINATOR_ADDRESS"):
@@ -126,7 +118,6 @@ def _run_two_procs(tmp_path, script_text):
     return outs
 
 
-@_two_proc
 def test_two_process_mesh_end_to_end(tmp_path):
     """Two controllers x 4 CPU devices: rendezvous, per-process input
     blocks, and the slab pipeline's all_to_all crossing processes."""
@@ -185,7 +176,6 @@ _TC1_ANALYTIC_WORKER = textwrap.dedent("""
 """)
 
 
-@_two_proc
 def test_two_process_tc1_analytic(tmp_path):
     """Validation at pod scale: tc1 with the device-built analytic truth
     runs under multi-controller (no coordinator-rank host array exists) —
@@ -196,7 +186,6 @@ def test_two_process_tc1_analytic(tmp_path):
         assert f"TC1 OK {i}" in out
 
 
-@_two_proc
 def test_two_process_timer_gathers_per_process_columns(tmp_path):
     """VERDICT r2 item 6: under multi-controller runs the Timer CSV must
     carry each process's OWN durations in its ranks' columns (the
@@ -228,7 +217,6 @@ _AUTOTUNE_WORKER = textwrap.dedent("""
 """)
 
 
-@_two_proc
 def test_two_process_comm_autotune_agreement(tmp_path):
     """The comm-strategy autotuner's multi-controller agreement step: both
     processes must run the same unconditional broadcast (a divergent

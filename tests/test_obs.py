@@ -308,7 +308,7 @@ def test_explain_obs_flag_prints_snapshot_and_event_log(tmp_path, capsys,
      "ZY_Then_X"),
 ])
 def test_obs_adds_zero_hlo_ops(tmp_path, devices, cfg_kw, sequence):
-    """Compiled HLO with observability ENABLED is byte-identical to
+    """Compiled HLO with observability ENABLED has the same op graph as
     DISABLED for every exchange rendering: spans are host-side intervals,
     never ops, so the disabled path (the default) is transitively pinned
     to the pre-obs programs."""
@@ -325,10 +325,8 @@ def test_obs_adds_zero_hlo_ops(tmp_path, devices, cfg_kw, sequence):
     off = compile_text()
     obs.enable(str(tmp_path / "obs"))
     on = compile_text()
-    assert on == off
-    # The metadata-stripped fingerprint (what dfft-verify's pins compare)
-    # agrees by construction.
-    assert hloscan.op_graph_fingerprint(on) == \
-        hloscan.op_graph_fingerprint(off)
+    # Source locations differ (the enabled path traces through other
+    # lines); the op graph — what dfft-verify's pins compare — does not.
+    assert hloscan.strip_metadata(on) == hloscan.strip_metadata(off)
     # And the enabled run really did trace (the comparison is not vacuous).
     assert obs.validate_events_dir(str(tmp_path / "obs")) > 0

@@ -15,10 +15,6 @@ from distributedfft_tpu.ops import fft as lf
 from distributedfft_tpu.ops import pallas_fft
 from distributedfft_tpu.params import FFTNorm
 
-pytestmark = pytest.mark.skipif(not pallas_fft.available(),
-                                reason="jax build lacks pallas TPU support")
-
-
 def _rel(a, b):
     return np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30)
 

@@ -208,3 +208,41 @@ def test_stage_profile_attributes_every_declared_node(family, devices):
     assert prof["attributed_ms"] >= 0.85 * prof["total_ms"], prof
     assert prof["exchange_ms"] > 0 and prof["compute_ms"] > 0
     assert prof["total_ms"] > 0 and prof["iters"] == 2
+
+
+def test_profile_stages_failure_propagates(monkeypatch):
+    """``--profile-stages`` is a requested output: a capture failure
+    propagates (the CLI exits non-zero) instead of printing a note."""
+    import argparse
+
+    from distributedfft_tpu.cli import common
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("no trace")
+
+    monkeypatch.setattr(profile, "stage_profile", broken)
+    with pytest.raises(RuntimeError, match="no trace"):
+        common.print_stage_profile(None, argparse.Namespace(
+            profile_stages=True))
+    common.print_stage_profile(None, argparse.Namespace(
+        profile_stages=False))  # not asked for: nothing runs
+
+
+def test_aggregate_reads_device_op_line_and_inherits_scope():
+    """A TPU device plane carries module- and op-level lines: only the op
+    line counts. An unscoped child of a scoped op (a runtime marker) is
+    charged to that op; ``window_ms`` bounds the per-line op sum."""
+    def ev(name, scope, off, dur):
+        return {"name": name, "scope": scope, "offset_ps": off * 10**9,
+                "dur_ps": dur * 10**9}
+
+    planes = [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Modules", "events": [ev("jit_fwd", None, 0, 10)]},
+        {"name": "XLA Ops", "events": [
+            ev("fusion.1", "slab/local_fft:1", 0, 6),
+            ev("end: fusion.1", None, 5, 1),
+            ev("copy.2", None, 7, 2)]}]}]
+    agg = profile.aggregate_trace(planes)
+    assert agg["scopes"] == {"slab/local_fft:1": 6.0}
+    assert agg["unattributed_ms"] == 2.0
+    assert agg["total_ms"] == 8.0 and agg["window_ms"] == 9.0

@@ -378,10 +378,9 @@ def test_hlo_byte_identical_when_off(name, kw, seq, devices, monkeypatch):
     assert gtxt != before  # the guarded+injected build is not vacuous
     monkeypatch.delenv(inject.ENV_VAR)
     after = text()
-    assert after == before
-    # The metadata-stripped op-graph fingerprint — the byte-identity
-    # currency dfft-verify's pins and the Plan-IR migration net use —
-    # agrees with the full-text comparison.
+    # The op-graph fingerprint — the byte-identity currency dfft-verify's
+    # pins use; source-location tables may differ (the guarded build
+    # traced through other lines), the op graph may not.
     assert hloscan.op_graph_fingerprint(after) == \
         hloscan.op_graph_fingerprint(before)
 

@@ -274,10 +274,11 @@ def test_hlo_ring_pipelines_fft_between_permutes(devices):
     sync = dfft.SlabFFTPlan(g, pm.SlabPartition(8), dfft.Config(),
                             sequence="Z_Then_YX")
     ring_txt = hloscan.lower_plan(ring, "forward").as_text()
-    sync_txt = hloscan.lower_plan(sync, "forward").as_text()
-    n_ring = len(re.findall(r"\.fft", ring_txt))  # stablehlo.fft / mhlo.fft
-    n_sync = len(re.findall(r"\.fft", sync_txt))
     assert len(re.findall(r"collective_permute", ring_txt)) >= 7
+    # Count compiled FFT ops: the staged module may share one private
+    # function body between identical per-block calls.
+    n_ring = len(re.findall(r" fft\(", hloscan.compiled_text(ring, "forward")))
+    n_sync = len(re.findall(r" fft\(", hloscan.compiled_text(sync, "forward")))
     assert n_ring >= n_sync + 7  # one extra per non-local peer block
 
 

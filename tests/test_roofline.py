@@ -1,14 +1,49 @@
 """MXU roofline model (evalkit/roofline.py): the MAC counts must mirror
-ops/mxu_fft.py dispatch exactly, and the table generator must translate
-the committed CSV without inventing or dropping rows."""
+ops/mxu_fft.py dispatch exactly, peaks come only from the per-kind table,
+and the table generator must translate a measurement CSV without
+inventing or dropping rows."""
 
-import os
+import pytest
 
 from distributedfft_tpu.evalkit import roofline as rl
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CSV = os.path.join(REPO, "eval", "benchmarks", "tpu_v5e",
-                   "single_chip_chain_timed.csv")
+V5E = rl.V5E_KIND
+
+# (size, backend label, direct_max the label implies, 3mm utilization)
+_ROWS = [
+    ("128^3", "matmul@high", None, 0.9),
+    ("256^3", "matmul@high", None, 0.8),
+    ("512^3", "matmul@high", None, 0.7),
+    ("512^3", "matmul@high four-step(16x32)", 32, 0.5),
+    ("1024^3", "matmul@high direct(1024)", 1024, 0.6),
+    ("256^3", "matmul-r2@high", None, 0.3),
+    ("2048^2x64", "matmul@high", None, 0.05),
+    ("4096^2x64", "matmul@high ck=1", None, 0.04),
+]
+
+
+@pytest.fixture
+def csv_path(tmp_path):
+    """A synthetic measurement CSV in the committed schema: each
+    matmul-family roundtrip row timed at a chosen 3mm utilization of the
+    v5e effective peak, plus rows the table must skip (xla, forward)."""
+    lines = ["size,transform,backend,per_iter_ms,gflops"]
+    for size, label, dmax, util in _ROWS:
+        base, _ = rl._parse_backend(label)
+        prec, r2 = rl._BACKENDS[base]
+        d = rl.DIRECT_MAX if dmax is None else dmax
+        form, dims = rl._parse_size(size)
+        f3 = (rl.mxu_flops_roundtrip_3d(dims, d, radix2=r2, complex_mults=3)
+              if form == "cube" else
+              rl.mxu_flops_batched2d(dims[0], dims[1], d, complex_mults=3,
+                                     radix2=r2))
+        ms = f3 / (util * rl.effective_peak_tflops(V5E, prec) * 1e12) * 1e3
+        lines.append(f"{size},R2C+C2R roundtrip,{label},{ms:.6f},100.0")
+    lines.append("256^3,R2C+C2R roundtrip,xla,5.0,100.0")
+    lines.append("256^3,R2C forward,matmul@high,2.0,100.0")
+    path = tmp_path / "rows.csv"
+    path.write_text("\n".join(lines) + "\n")
+    return str(path)
 
 
 def test_axis_mac_counts_direct():
@@ -46,41 +81,42 @@ def test_roundtrip_flops_closed_form():
 
 
 def test_effective_peak_model():
-    assert rl.effective_peak_tflops("default") == 197.0
-    assert abs(rl.effective_peak_tflops("high") - 197.0 / 3) < 1e-9
-    assert abs(rl.effective_peak_tflops("highest") - 197.0 / 6) < 1e-9
+    assert rl.effective_peak_tflops(V5E, "default") == 197.0
+    assert abs(rl.effective_peak_tflops(V5E, "high") - 197.0 / 3) < 1e-9
+    assert abs(rl.effective_peak_tflops(V5E, "highest") - 197.0 / 6) < 1e-9
+    assert rl.PEAKS[V5E]["hbm_gbps"] == 819.0
 
 
-def test_table_from_committed_csv():
-    rows = rl.roofline_rows(CSV)
-    # Every matmul-family ROUNDTRIP row in the committed CSV translates;
-    # xla / pallas rows (no honest MXU count) are skipped.
-    assert len(rows) >= 6
-    sizes = {r["size"] for r in rows}
-    assert {"128^3", "256^3", "512^3", "2048^2x64"} <= sizes
-    for r in rows:
+def test_unknown_kind_has_no_peak():
+    """A kind missing from the table gets no ideal, never a default."""
+    assert rl.effective_peak_tflops("cpu") is None
+    assert rl.ideal_time_ms(256, "matmul", kind="cpu") is None
+    assert rl.roofline_row(10.0, 256, "xla", kind="cpu") is None
+    with pytest.raises(ValueError, match="no peak"):
+        rl.roofline_rows("unused.csv", "cpu")
+
+
+def test_table_from_csv(csv_path):
+    rows = rl.roofline_rows(csv_path, V5E)
+    # Every matmul-family ROUNDTRIP row translates; the xla row (no
+    # honest MXU count) and the forward row are skipped.
+    assert len(rows) == len(_ROWS)
+    for r, (size, label, _, util) in zip(rows, _ROWS):
+        assert (r["size"], r["backend"]) == (size, label)
         # 3mm (the cheapest known complex-dot lowering) is the physically
-        # binding bound: it may never exceed peak. 4mm is an over-count by
-        # construction whenever XLA uses the 3-mult form, so it may land
-        # above peak — 128^3 at ~106% and the direct(1024) 1024^3 row at
-        # ~118% are the lowering evidence the table documents — but a 4mm
-        # claim far past 4/3 of peak would mean the MAC model itself is
-        # wrong, not the lowering assumption.
-        assert r["util_3mm"] < r["util_4mm"]
-        assert 0 < r["util_3mm"] <= 1.0
-        assert r["util_4mm"] < 4.0 / 3.0
-    md = rl.render_markdown(rows)
-    assert "512^3" in md and "utilization" in md
+        # binding bound; 4mm over-counts by at most 4/3.
+        assert r["util_3mm"] == pytest.approx(util, rel=1e-3)
+        assert r["util_3mm"] < r["util_4mm"] < 4.0 / 3.0
+    md = rl.render_markdown(rows, V5E)
+    assert "512^3" in md and "utilization" in md and V5E in md
 
 
-def test_committed_markdown_is_current():
-    """ROOFLINE.md must match what the generator produces from the CSV —
-    a stale committed table is worse than none."""
-    md_path = os.path.join(REPO, "eval", "benchmarks", "tpu_v5e",
-                           "ROOFLINE.md")
-    with open(md_path) as f:
-        committed = f.read()
-    assert committed == rl.render_markdown(rl.roofline_rows(CSV))
+def test_cli_writes_the_rendered_table(csv_path, tmp_path):
+    out = tmp_path / "ROOFLINE.md"
+    assert rl.main(["--csv", csv_path, "--device-kind", V5E,
+                    "--out", str(out)]) == 0
+    assert out.read_text() == rl.render_markdown(
+        rl.roofline_rows(csv_path, V5E), V5E)
 
 
 def test_parse_backend_plan_suffixes():
@@ -109,10 +145,10 @@ def test_fourstep_suffix_macs_match_measured_plan():
     assert _split_for(4096, 64) == (64, 64)    # four-step(64x64), old CSV
 
 
-def test_metric_size_rows_in_roofline():
+def test_metric_size_rows_in_roofline(csv_path):
     """The BASELINE metric's own size must appear in the rendered table —
     the plan-suffix parsing exists so the 1024^3 row is not dropped."""
-    rows = rl.roofline_rows(CSV)
+    rows = rl.roofline_rows(csv_path, V5E)
     assert any(r["size"] == "1024^3" for r in rows)
     assert any(r["size"] == "4096^2x64" for r in rows)
 
@@ -124,16 +160,16 @@ def test_metric_size_rows_in_roofline():
 def test_ideal_time_and_fraction_cube():
     """fraction = ideal/measured with ideal from the exact MXU model: a
     measurement AT the model's time scores 1.0, half speed scores 0.5."""
-    ideal = rl.ideal_time_ms("256^3", "matmul@high")
+    ideal = rl.ideal_time_ms("256^3", "matmul@high", kind=V5E)
     assert ideal is not None and ideal > 0
-    assert rl.roofline_fraction(ideal, "256^3", "matmul") == 1.0
-    assert abs(rl.roofline_fraction(2 * ideal, 256, "matmul") - 0.5) < 1e-3
+    assert rl.roofline_fraction(ideal, "256^3", "matmul", kind=V5E) == 1.0
+    assert abs(rl.roofline_fraction(2 * ideal, 256, "matmul", kind=V5E) - 0.5) < 1e-3
 
 
 def test_fraction_shape_forms_agree():
     """Every accepted size spelling — '256^3', '256', int, (n,n,n) tuple —
     resolves to the same model."""
-    vals = {rl.ideal_time_ms(f, "matmul")
+    vals = {rl.ideal_time_ms(f, "matmul", kind=V5E)
             for f in ("256^3", "256", 256, (256, 256, 256))}
     assert len(vals) == 1
 
@@ -142,16 +178,17 @@ def test_fraction_modes_and_devices():
     """One-way modes halve the flops; a mesh divides the per-chip share
     (communication deliberately NOT modeled — it shows up as lost
     fraction)."""
-    rt = rl.ideal_time_ms(256, "matmul")
-    assert abs(rl.ideal_time_ms(256, "matmul", mode="forward") - rt / 2) \
-        < 1e-9
-    assert abs(rl.ideal_time_ms(256, "matmul", devices=8) - rt / 8) < 1e-9
+    rt = rl.ideal_time_ms(256, "matmul", kind=V5E)
+    assert abs(rl.ideal_time_ms(256, "matmul", kind=V5E, mode="forward")
+               - rt / 2) < 1e-9
+    assert abs(rl.ideal_time_ms(256, "matmul", kind=V5E, devices=8)
+               - rt / 8) < 1e-9
 
 
 def test_fraction_nominal_model_for_non_matmul():
     """xla/pallas/bluestein rows take the nominal 2.5·N·log2 N model (no
     honest MXU count) and say so in the record."""
-    row = rl.roofline_row(10.0, "256^3", "xla")
+    row = rl.roofline_row(10.0, "256^3", "xla", kind=V5E)
     assert row["model"].startswith("nominal")
     assert row["roofline_fraction"] > 0
 
@@ -159,15 +196,15 @@ def test_fraction_nominal_model_for_non_matmul():
 def test_fraction_direct_plan_override():
     """The direct(N) bench plan note must reach the model: the all-direct
     1024 plan issues more MACs than the four-step default."""
-    d = rl.ideal_time_ms(1024, "matmul", direct_max=1024)
-    f = rl.ideal_time_ms(1024, "matmul")
+    d = rl.ideal_time_ms(1024, "matmul", kind=V5E, direct_max=1024)
+    f = rl.ideal_time_ms(1024, "matmul", kind=V5E)
     assert d > f
 
 
 def test_fraction_unmodelable_returns_none():
-    assert rl.roofline_fraction(1.0, "20x16x7", "matmul") is None
-    assert rl.roofline_fraction(0.0, "256^3", "matmul") is None
-    assert rl.roofline_row(-1.0, "256^3", "matmul") is None
+    assert rl.roofline_fraction(1.0, "20x16x7", "matmul", kind=V5E) is None
+    assert rl.roofline_fraction(0.0, "256^3", "matmul", kind=V5E) is None
+    assert rl.roofline_row(-1.0, "256^3", "matmul", kind=V5E) is None
     assert rl._parse_size((20, 16, 7)) is None
 
 

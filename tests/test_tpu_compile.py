@@ -1,0 +1,93 @@
+"""Compiles of the main path for a described TPU v5e 2x2 (no chip needed).
+
+The TPU compiler is installed with JAX, and it compiles for a topology
+that is described rather than attached. These compiles catch what the
+interpret-mode Pallas tests cannot: tiles the Mosaic compiler refuses,
+scoped VMEM over budget, programs that do not fit HBM, collectives that
+cannot be partitioned. Nothing runs, so they say nothing about results
+or times.
+
+The topology is described inside a module fixture only: libtpu can be
+loaded by one process at a time, and a description at import would make
+xdist workers collect different tests. Pallas is steered off interpret
+mode with ``monkeypatch``; the persistent compile cache is off around
+the compiles (a described-chip entry cannot be read back here).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, SingleDeviceSharding
+
+import distributedfft_tpu as dfft
+from distributedfft_tpu.analysis import hloscan
+from distributedfft_tpu.ops import pallas_fft
+
+HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def compiled_for_tpu(monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(pallas_fft, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _check(compiled, marker):
+    txt = compiled.as_text()
+    assert marker in txt, f"no {marker} in the compiled program"
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 1e9:.2f} GB per device"
+
+
+@pytest.mark.parametrize("n", [256, 512])
+def test_pallas_3d_roundtrip_compiles(one_chip, n):
+    x = jax.ShapeDtypeStruct((n, n, n), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda v: pallas_fft.irfftn_3d(
+        pallas_fft.rfftn_3d(v), (n, n, n))).lower(x).compile()
+    _check(compiled, "tpu_custom_call")
+
+
+def test_fused_wire_decode_fft_compiles_at_1024(one_chip):
+    """The fused wire decode + DFT kernel at its widest block (1024 =
+    ``_N_MAX``: both 1024x1024 f32 DFT planes resident in VMEM)."""
+    y = jax.ShapeDtypeStruct((2, 4096, 1024), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = jax.jit(lambda v: pallas_fft.decode_fft_fused(
+        v, jnp.complex64, axis=1)).lower(y).compile()
+    _check(compiled, "tpu_custom_call")
+
+
+def test_slab4_1024_forward_compiles(topo):
+    mesh = Mesh(np.array(topo.devices), ("p",))
+    plan = dfft.SlabFFTPlan(dfft.GlobalSize(1024, 1024, 1024),
+                            dfft.SlabPartition(4), dfft.Config(), mesh=mesh)
+    aval = hloscan._input_aval(plan, "forward")
+    x = jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                             sharding=plan.input_sharding)
+    compiled = hloscan._builder(plan, "forward").lower(x).compile()
+    _check(compiled, "all-to-all")

@@ -1,6 +1,6 @@
 """Static hazard checker for the revolving-buffer ring schedules.
 
-``SendMethod.RING_OVERLAP`` (``parallel/transpose._ring_transpose_impl``)
+``SendMethod.RING_OVERLAP`` (``parallel/transpose.ring_transpose``)
 pipelines the ``P-1``-step ppermute ring with revolving receive buffers:
 step ``t+1``'s permute is issued before block ``t``'s per-block compute,
 so one wire transfer is in flight under every block's FFT. That schedule
@@ -41,7 +41,7 @@ caught):
 ``revolving_schedule(p, depth)`` generates the depth-D generalization of
 the shipped schedule: pre-issue ``depth-1`` steps, then inside the loop
 issue step ``t+depth-1`` BEFORE computing block ``t`` — at ``depth=2``
-this is op-for-op the order ``_ring_transpose_impl`` traces under
+this is op-for-op the order ``ring_transpose`` traces under
 ``overlap=True`` (issue ``t+1``'s permute, then arrive block ``t``), and
 at ``depth=1`` it degenerates to the plain serial RING. ``describe``
 joins the timeline with ``transpose.ring_schedule``'s byte accounting so
@@ -104,7 +104,7 @@ def revolving_schedule(p: int, depth: int = 2,
     ``subblocks`` > 1 models the block-granularity axis: each peer step
     becomes ``subblocks`` MICRO-steps (sub-block ``(m-1) % S`` of peer
     step ``(m-1) // S + 1`` — the exact linearization
-    ``_ring_transpose_impl`` traces), each riding its own permute into
+    ``ring_transpose`` traces), each riding its own permute into
     its own revolving buffer, so the checker proves the sub-block
     schedule under the same buffer discipline. The effective depth caps
     at ``(p-1) * subblocks``."""

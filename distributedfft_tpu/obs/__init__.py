@@ -4,9 +4,13 @@ Six coordinated pieces (none of which may perturb a compiled program —
 the zero-overhead-when-off contract is pinned by ``tests/test_obs.py``):
 
 * ``obs.span("plan.build") / obs.event / obs.notice`` — host-side span
-  tracing into a per-run JSONL event log under ``$DFFT_OBS_DIR`` (default
-  off), with ``jax.profiler.TraceAnnotation`` mirroring the names into
-  TensorBoard/Perfetto traces (``tracing.py``).
+  tracing into the flight-recorder ring (always) and a per-run JSONL
+  event log under ``$DFFT_OBS_DIR`` (default off). Every span also
+  enters a ``jax.profiler.TraceAnnotation`` (``dfft:<name>``) whenever
+  jax is loaded, log on or off, so a profiled process has the spans on
+  the device trace's clock. Each ``exec_*`` opens ``plan.exec`` with a
+  ``plan.dispatch`` child around the jitted call (``tracing.py``,
+  ``resilience/fallback.py``); the exchange builders open none.
 * ``obs.metrics`` — process-global counters/gauges/latency histograms
   with dual per-plan vs cumulative views; ``bench.py`` folds the per-plan
   ``snapshot()`` into ``BENCH_DETAILS.json``, the Prometheus exposition
@@ -19,7 +23,8 @@ the zero-overhead-when-off contract is pinned by ``tests/test_obs.py``):
   view; ``dfft-serve --http`` serves it at ``GET /metrics``
   (``promexp.py``).
 * ``obs.profile`` — stage-attributed device profiling: ``jax.named_scope``
-  emission per declared plan-graph node (metadata only — every
+  emission per declared plan-graph node and, inside a local-FFT stage,
+  per transformed axis (``<node>.ax<k>``; metadata only — every
   fingerprint pin holds with scopes on), a ``jax.profiler`` xplane/
   trace-events ingester, and the graph join behind
   ``dfft-explain --profile`` (``profile.py``).

@@ -9,14 +9,18 @@ encode. This module closes that loop in three steps:
 
 1. **Scope emission** — the plan families wrap each declared graph
    node's emitted ops in ``jax.named_scope("dfft/<family>/<node-id>")``
-   (``stage_scope``), and the wire layer tags its encode/decode with
-   ``dfft/wire/encode`` / ``dfft/wire/decode`` (``wire_scope``). Scopes
-   are METADATA ONLY: they ride the op ``metadata={op_name=...}``
-   attribute that ``hloscan.strip_metadata`` removes, so every
-   fingerprint pin and the 171-combo verify matrix are byte-identical
-   with scopes on (pinned by the ``scope-zero-overhead`` pins;
-   ``disable_scopes()`` / ``$DFFT_NO_STAGE_SCOPES`` exist exactly so the
-   pin has an off side to compare against).
+   (``stage_scope``); inside a stage, each one-axis local FFT opens the
+   sub-scope ``<node-id>.ax<k>`` (``axis_scope``; ``k`` the transformed
+   array axis, which is x/y/z on the slab and pencil plans, whose
+   arrays keep the global axis order), and the wire layer tags its
+   encode/decode with ``dfft/wire/encode`` / ``dfft/wire/decode``
+   (``wire_scope``). Scopes are METADATA ONLY: they ride the op
+   ``metadata={op_name=...}`` attribute that ``hloscan.strip_metadata``
+   removes, so every fingerprint pin and the 171-combo verify matrix
+   are byte-identical with scopes on (pinned by the
+   ``scope-zero-overhead`` pins; ``disable_scopes()`` /
+   ``$DFFT_NO_STAGE_SCOPES`` exist exactly so the pin has an off side
+   to compare against).
 2. **Trace ingestion** — ``capture_stage_profile`` runs a plan
    direction under ``jax.profiler.trace`` and parses the dumped
    ``*.xplane.pb`` (a minimal hand-rolled protobuf walker — the XSpace
@@ -26,11 +30,11 @@ encode. This module closes that loop in three steps:
    ``call`` wrapping its fusions) are resolved by SELF-TIME attribution
    so nothing is double counted.
 3. **Graph join** — ``stage_profile`` aggregates device time by scope
-   and joins it onto the declared graph: per-node device time, the
-   exchange-vs-compute split, the unattributed remainder (dispatch,
-   h2d, ops outside any scope — honesty line, never hidden), and a
-   per-stage roofline-gap row (measured vs the nominal ideal for that
-   node's axes). GSPMD (``p2p``) exchanges stage no explicit op to
+   (axis sub-scopes folded into their stage) and joins it onto the
+   declared graph: per-node device time, the exchange-vs-compute
+   split, the unattributed remainder (dispatch, h2d, ops outside any
+   scope — honesty line, never hidden), and a per-stage roofline-gap
+   row (measured vs the nominal ideal for that node's axes). GSPMD (``p2p``) exchanges stage no explicit op to
    scope, so their collective lands in the unattributed remainder —
    reported, not guessed.
 
@@ -49,6 +53,7 @@ import math
 import os
 import re
 import tempfile
+import threading
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 SCOPE_PREFIX = "dfft"
@@ -98,9 +103,29 @@ def scope_name(family: str, node_id: str) -> str:
     return f"{SCOPE_PREFIX}/{family}/{node_id}"
 
 
+class _Stages(threading.local):
+    def __init__(self):
+        # open (family, node id) stages, innermost last
+        self.stack: List[Tuple[str, str]] = []
+
+
+_STAGES = _Stages()
+
+
+@contextlib.contextmanager
+def _stage(family: str, node_id: str, jax) -> Iterator[None]:
+    with jax.named_scope(scope_name(family, node_id)):
+        _STAGES.stack.append((family, node_id))
+        try:
+            yield
+        finally:
+            _STAGES.stack.pop()
+
+
 def stage_scope(family: str, node_id: str):
     """``jax.named_scope`` for one declared node's ops (trace-time;
-    metadata only). A no-op context when scopes are disabled, the node id
+    metadata only), which also records the open ``(family, node)`` for
+    ``axis_scope``. A no-op context when scopes are disabled, the node id
     is falsy (an undeclared exchange), or jax is absent."""
     if not node_id or not scopes_enabled():
         return contextlib.nullcontext()
@@ -108,7 +133,31 @@ def stage_scope(family: str, node_id: str):
         import jax
     except Exception:  # noqa: BLE001 — jax-free interpreter
         return contextlib.nullcontext()
-    return jax.named_scope(scope_name(family, node_id))
+    return _stage(family, node_id, jax)
+
+
+def axis_scope(axis: int, ndim: int):
+    """The per-axis sub-scope of the innermost open stage,
+    ``dfft/<family>/<node>.ax<k>`` with ``k`` the non-negative index of
+    the transformed array axis: ``ops/fft.py`` opens it around each
+    one-axis transform, so a trace splits a local-FFT stage by axis.
+    The node id keeps its stage prefix (``slab/local_fft:1.ax2``), so
+    prefix matches on the node still count the axis's ops. A no-op
+    context outside a stage or with scopes disabled."""
+    if not _STAGES.stack or not scopes_enabled():
+        return contextlib.nullcontext()
+    import jax
+    family, node_id = _STAGES.stack[-1]
+    return jax.named_scope(scope_name(family, f"{node_id}.ax{axis % ndim}"))
+
+
+_AXIS_SUFFIX = re.compile(r"\.ax\d+$")
+
+
+def stage_of(scope: str) -> str:
+    """The stage a scope key belongs to: ``slab/local_fft:1.ax2`` ->
+    ``slab/local_fft:1`` (other keys unchanged)."""
+    return _AXIS_SUFFIX.sub("", scope)
 
 
 def wire_scope(kind: str):
@@ -633,7 +682,10 @@ def stage_profile(plan: Any, direction: str = "forward", dims: int = 3,
     graph = plangraph.graph_for(plan, direction, dims)
     agg = capture if capture is not None else capture_stage_profile(
         plan, direction, dims, iters=iters, warmup=warmup)
-    scopes = dict(agg["scopes"])
+    scopes: Dict[str, float] = {}
+    for k, v in agg["scopes"].items():  # axis sub-scopes fold into their stage
+        k = stage_of(k)
+        scopes[k] = scopes.get(k, 0.0) + v
     total = float(agg["total_ms"]) or 1e-12
     ranks = 1
     mesh = getattr(plan, "mesh", None)

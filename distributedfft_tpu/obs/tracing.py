@@ -9,25 +9,33 @@ them: a nestable ``span("plan.build")`` context manager that
 
 * records wall-clock intervals into a per-process JSONL event log under
   ``$DFFT_OBS_DIR`` (``events-<pid>.jsonl``; one JSON object per line, see
-  ``validate_event`` for the schema), and
-* enters a ``jax.profiler.TraceAnnotation`` named ``dfft:<span name>``, so
-  when the process is inside a ``jax.profiler`` trace (``--profile-dir``)
-  the same names appear on the TensorBoard / Perfetto timeline next to the
-  device ops they schedule.
+  ``validate_event`` for the schema) when that log is on, and always into
+  the in-memory flight-recorder ring (``obs/flightrec.py``), and
+* enters a ``jax.profiler.TraceAnnotation`` named ``dfft:<span name>``
+  whenever the process has already imported jax, log on or off, so a
+  process inside a ``jax.profiler`` trace has the program's spans on the
+  device trace's clock, next to the device ops they schedule.
 
-ZERO-OVERHEAD-WHEN-OFF CONTRACT (amended by the flight recorder,
-ISSUE 12): with no ``$DFFT_OBS_DIR`` (and no programmatic ``enable()``)
-there is still **no file I/O, no jax import and no profiler annotation**
-— but spans/events/notices are no longer dropped entirely: every record
-is appended to the always-on in-memory flight-recorder ring
-(``obs/flightrec.py``; a dict build and a bounded deque append), so a
-trigger can dump the last seconds of evidence even from a run that never
-enabled the log. ``$DFFT_FLIGHTREC=off`` restores the full drop. Spans
-never appear *inside* jitted programs as ops: they are host-side
-intervals around plan construction, autotuning, wisdom I/O and trace-time
-program building, which is why neither the ring nor the log can perturb
-the compiled program (``tests/test_obs.py`` pins enabled == disabled HLO
-byte-for-byte).
+The spans on the execution path are ``plan.exec`` (attrs ``direction``,
+``family``), which ``resilience/fallback.execute`` opens around the whole
+envelope every ``exec_*`` passes, and its child ``plan.dispatch``, around
+the call of the jitted program until it returns; the envelope's own host
+time is the parent's less the child's. Build-time spans (``plan.build``,
+``plan.resolve``, wisdom, autotune races) record plan construction. The
+exchange builders open no span: trace-time intervals timed Python tracing
+once per compile, not the exchange.
+
+ZERO-OVERHEAD-WHEN-OFF CONTRACT: with no ``$DFFT_OBS_DIR`` (and no
+programmatic ``enable()``) there is **no file I/O and no jax import**:
+every record goes to the bounded flight-recorder ring only (a dict build
+and a deque append), so a trigger can dump the last seconds of evidence
+even from a run that never enabled the log, and the annotation is
+entered only where jax is already loaded (outside a profiler trace it is
+a no-op). ``$DFFT_FLIGHTREC=off`` with the log off drops every record
+and annotation. Spans never appear *inside* jitted programs as ops: they
+are host-side intervals, which is why neither the ring nor the log can
+perturb the compiled program (``tests/test_obs.py`` pins enabled ==
+disabled HLO byte-for-byte).
 
 Everything here degrades rather than errors: an unwritable log directory
 silently drops events (observability must never fail a run).
@@ -48,6 +56,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterable, Optional
@@ -204,18 +213,17 @@ class _Span:
         self._p0 = time.perf_counter()
         _TLS.stack.append(self.name)
         # Device-trace annotation: inside a jax.profiler trace the span name
-        # shows on the TensorBoard/Perfetto timeline; outside one this is a
-        # cheap no-op, and on a jax-free interpreter it is skipped entirely.
-        # Ring-only spans (log off) skip it — the off path imports no jax.
-        if not enabled():
-            self._ann = None
-            return self
-        try:
-            import jax
-            self._ann = jax.profiler.TraceAnnotation(f"dfft:{self.name}")
-            self._ann.__enter__()
-        except Exception:  # noqa: BLE001 — annotation is best-effort
-            self._ann = None
+        # shows on the timeline, on the device trace's clock; outside one
+        # it is a cheap no-op. Entered whenever the process has imported
+        # jax, log on or off; a jax-free process never imports it here.
+        self._ann = None
+        if "jax" in sys.modules:
+            try:
+                from jax.profiler import TraceAnnotation
+                self._ann = TraceAnnotation(f"dfft:{self.name}")
+                self._ann.__enter__()
+            except Exception:  # noqa: BLE001 — annotation is best-effort
+                self._ann = None
         return self
 
     def __exit__(self, et, ev, tb):

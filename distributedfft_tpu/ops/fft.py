@@ -22,6 +22,11 @@ fusing the twiddle epilogue into the DFT matmul (``ops/pallas_fft.py``);
 non-smooth lengths run the chirp-z identity at O(n log n) instead of
 falling off every fast path (the matmul four-step degrades to a dense
 O(n^2) contraction there). Selected plan-wide via ``Config.fft_backend``.
+
+The one-axis entry points (``rfft``, ``irfft``, ``fft``, ``ifft``) trace
+their work, for every backend, under the per-axis sub-scope of the open
+plan stage (``obs/profile.axis_scope``: ``dfft/<family>/<node>.ax<k>``);
+the multi-axis ones stay at the stage scope.
 """
 
 from __future__ import annotations
@@ -37,6 +42,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..obs.profile import axis_scope
 from ..params import FFTNorm
 
 BACKENDS = ("xla", "matmul", "matmul-r2", "pallas", "bluestein")
@@ -123,11 +129,12 @@ def rfft(x, axis: int, norm: FFTNorm = FFTNorm.NONE, backend: str = "xla",
     ``settings`` (all entry points): optional ``mxu_fft.MXUSettings``
     scoped around the dispatch — the per-plan alternative to the
     deprecated ``set_*`` process globals. Ignored by the "xla" backend."""
-    m = _impl(backend)
-    if m is not None:
-        with _settings_ctx(backend, settings):
-            return m.rfft(x, axis=axis, norm=norm)
-    return _xla_rfft(x, axis, _fwd_norm(norm))
+    with axis_scope(axis, x.ndim):
+        m = _impl(backend)
+        if m is not None:
+            with _settings_ctx(backend, settings):
+                return m.rfft(x, axis=axis, norm=norm)
+        return _xla_rfft(x, axis, _fwd_norm(norm))
 
 
 @functools.partial(jax.custom_jvp, nondiff_argnums=(1, 2))
@@ -155,11 +162,12 @@ def irfft(x, n: int, axis: int, norm: FFTNorm = FFTNorm.NONE,
           backend: str = "xla", settings=None):
     """Inverse C2R along one axis; ``n`` is the real output extent (needed
     because the halved axis length ``n//2+1`` is ambiguous)."""
-    m = _impl(backend)
-    if m is not None:
-        with _settings_ctx(backend, settings):
-            return m.irfft(x, n=n, axis=axis, norm=norm)
-    return xla_irfft(x, n, axis, _inv_norm(norm))
+    with axis_scope(axis, x.ndim):
+        m = _impl(backend)
+        if m is not None:
+            with _settings_ctx(backend, settings):
+                return m.irfft(x, n=n, axis=axis, norm=norm)
+        return xla_irfft(x, n, axis, _inv_norm(norm))
 
 
 def _fit_last(x, k: int):
@@ -213,21 +221,23 @@ def xla_irfft(x, n: int, axis: int = -1, norm: str = "backward"):
 def fft(x, axis: int, norm: FFTNorm = FFTNorm.NONE, backend: str = "xla",
         settings=None):
     """Forward C2C along one axis (cuFFT ``execC2C(..., CUFFT_FORWARD)``)."""
-    m = _impl(backend)
-    if m is not None:
-        with _settings_ctx(backend, settings):
-            return m.fft(x, axis=axis, norm=norm)
-    return jnp.fft.fft(x, axis=axis, norm=_fwd_norm(norm))
+    with axis_scope(axis, x.ndim):
+        m = _impl(backend)
+        if m is not None:
+            with _settings_ctx(backend, settings):
+                return m.fft(x, axis=axis, norm=norm)
+        return jnp.fft.fft(x, axis=axis, norm=_fwd_norm(norm))
 
 
 def ifft(x, axis: int, norm: FFTNorm = FFTNorm.NONE, backend: str = "xla",
          settings=None):
     """Inverse C2C along one axis (cuFFT ``execC2C(..., CUFFT_INVERSE)``)."""
-    m = _impl(backend)
-    if m is not None:
-        with _settings_ctx(backend, settings):
-            return m.ifft(x, axis=axis, norm=norm)
-    return jnp.fft.ifft(x, axis=axis, norm=_inv_norm(norm))
+    with axis_scope(axis, x.ndim):
+        m = _impl(backend)
+        if m is not None:
+            with _settings_ctx(backend, settings):
+                return m.ifft(x, axis=axis, norm=norm)
+        return jnp.fft.ifft(x, axis=axis, norm=_inv_norm(norm))
 
 
 def fftn(x, axes: Sequence[int], norm: FFTNorm = FFTNorm.NONE,

@@ -53,7 +53,13 @@ def use_compile_cache() -> str:
     ``$JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
     left alone; otherwise the cache goes to ``<checkout>/.jax_cache``
     (git-ignored). A fixed path matters: the path is part of the key, so
-    a cache that moves never hits. Call before the first compile."""
+    a cache that moves never hits. Call before the first compile.
+
+    Entries are keyed on the programs' metadata too. The stage scopes
+    (``obs/profile.py``) live there, and JAX's key leaves metadata out
+    by default, so a program whose scopes changed would load an older
+    build's executable and profile under that build's scopes."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -70,8 +70,7 @@ def wire_encode(x, wire: str = WIRE_BF16):
     stage scope (metadata only — ``obs/profile.py`` attribution)."""
     if not _wire_active(x, wire):
         return x
-    with obs.span("exchange.encode", wire=wire), \
-            obs.profile.wire_scope("encode"):
+    with obs.profile.wire_scope("encode"):
         return jnp.stack([jnp.real(x), jnp.imag(x)]).astype(jnp.bfloat16)
 
 
@@ -82,8 +81,7 @@ def wire_decode(y, dtype, wire: str = WIRE_BF16):
     validate_wire(wire)
     if wire == WIRE_NATIVE:
         return y
-    with obs.span("exchange.decode", wire=wire), \
-            obs.profile.wire_scope("decode"):
+    with obs.profile.wire_scope("decode"):
         f = (jnp.float64 if np.dtype(dtype) == np.complex128
              else jnp.float32)
         z = y.astype(f)
@@ -231,11 +229,6 @@ def chunked_reshard(x, target, axis: int, k: int):
     the pieces split the LOCAL sub-axis, so each piece takes the same
     local rows of every shard and the K piece exchanges together move
     exactly the monolithic exchange's bytes."""
-    with obs.span("exchange.chunked_reshard", axis=axis, k=k):
-        return _chunked_reshard_impl(x, target, axis, k)
-
-
-def _chunked_reshard_impl(x, target, axis: int, k: int):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -359,23 +352,6 @@ def ring_transpose(x, axis_name: str, split_axis: int, concat_axis: int, *,
     obs.metrics.inc("wire.exchanges_traced")
     obs.metrics.gauge("wire.bytes_per_transpose",
                       wire_nbytes(x.shape, x.dtype, wire))
-    with obs.span("exchange.ring", axis=axis_name, wire=wire,
-                  overlap=bool(overlap), depth=int(depth),
-                  subblocks=int(subblocks)):
-        return _ring_transpose_impl(x, axis_name, split_axis, concat_axis,
-                                    pipeline_fn=pipeline_fn, wire=wire,
-                                    overlap=overlap, depth=depth,
-                                    subblocks=subblocks, encode_fn=encode_fn,
-                                    arrive_fn=arrive_fn)
-
-
-def _ring_transpose_impl(x, axis_name: str, split_axis: int,
-                         concat_axis: int, *, pipeline_fn, wire: str,
-                         overlap: bool = False, depth: int = 2,
-                         subblocks: int = 1, encode_fn=None,
-                         arrive_fn=None):
-    """``ring_transpose`` proper (split out so the obs span wraps one
-    call site)."""
     if depth < 1:
         raise ValueError(f"overlap depth must be >= 1, got {depth}")
     if overlap and depth < 2:
@@ -612,20 +588,18 @@ def all_to_all_transpose(x, axis_name: str, split_axis: int, concat_axis: int,
     obs.metrics.inc("wire.exchanges_traced")
     obs.metrics.gauge("wire.bytes_per_transpose",
                       wire_nbytes(x.shape, x.dtype, wire))
-    with obs.span("exchange.all_to_all", axis=axis_name,
-                  realigned=bool(realigned), wire=wire):
-        # inject.taint_wire sits exactly at the wire_encode/wire_decode
-        # boundary: the corrupted image is what travels (and what the
-        # guards must catch). Identity without $DFFT_FAULT_SPEC.
-        if _wire_active(x, wire):
-            y = wire_encode(x, wire)
-            y = inject.taint_wire(y, "all_to_all")
-            y = _all_to_all_native(y, axis_name, split_axis + 1,
-                                   concat_axis + 1, realigned)
-            return wire_decode(y, x.dtype, wire)
-        return _all_to_all_native(inject.taint_wire(x, "all_to_all"),
-                                  axis_name, split_axis, concat_axis,
-                                  realigned)
+    # inject.taint_wire sits exactly at the wire_encode/wire_decode
+    # boundary: the corrupted image is what travels (and what the
+    # guards must catch). Identity without $DFFT_FAULT_SPEC.
+    if _wire_active(x, wire):
+        y = wire_encode(x, wire)
+        y = inject.taint_wire(y, "all_to_all")
+        y = _all_to_all_native(y, axis_name, split_axis + 1,
+                               concat_axis + 1, realigned)
+        return wire_decode(y, x.dtype, wire)
+    return _all_to_all_native(inject.taint_wire(x, "all_to_all"),
+                              axis_name, split_axis, concat_axis,
+                              realigned)
 
 
 def pipelined_all_to_all(x, axis_name: str, split_axis: int,
@@ -670,34 +644,32 @@ def pipelined_all_to_all(x, axis_name: str, split_axis: int,
     obs.metrics.inc("wire.exchanges_traced")
     obs.metrics.gauge("wire.bytes_per_transpose",
                       wire_nbytes(x.shape, x.dtype, wire))
-    with obs.span("exchange.a2a_pipe", axis=axis_name, chunks=int(chunks),
-                  depth=int(depth), realigned=bool(realigned), wire=wire):
-        wired = _wire_active(x, wire)
+    wired = _wire_active(x, wire)
 
-        def issue(pc):
-            if wired:
-                y = wire_encode(pc, wire)
-                y = inject.taint_wire(y, "a2a_pipe")
-                return _all_to_all_native(y, axis_name, split_axis + 1,
-                                          concat_axis + 1, realigned)
-            return _all_to_all_native(inject.taint_wire(pc, "a2a_pipe"),
-                                      axis_name, split_axis, concat_axis,
-                                      realigned)
+    def issue(pc):
+        if wired:
+            y = wire_encode(pc, wire)
+            y = inject.taint_wire(y, "a2a_pipe")
+            return _all_to_all_native(y, axis_name, split_axis + 1,
+                                      concat_axis + 1, realigned)
+        return _all_to_all_native(inject.taint_wire(pc, "a2a_pipe"),
+                                  axis_name, split_axis, concat_axis,
+                                  realigned)
 
-        def land(y):
-            return wire_decode(y, x.dtype, wire) if wired else y
+    def land(y):
+        return wire_decode(y, x.dtype, wire) if wired else y
 
-        pieces = split_axis_chunks(x, chunk_axis, chunks)
-        k = len(pieces)
-        w = min(depth - 1, k - 1)
-        queue = [issue(pieces[i]) for i in range(w)]
-        out = []
-        for i in range(k):
-            nxt = i + w
-            if nxt < k:
-                queue.append(issue(pieces[nxt]))
-            out.append(land(queue.pop(0)))
-        return concat_axis_chunks(out, chunk_axis)
+    pieces = split_axis_chunks(x, chunk_axis, chunks)
+    k = len(pieces)
+    w = min(depth - 1, k - 1)
+    queue = [issue(pieces[i]) for i in range(w)]
+    out = []
+    for i in range(k):
+        nxt = i + w
+        if nxt < k:
+            queue.append(issue(pieces[nxt]))
+        out.append(land(queue.pop(0)))
+    return concat_axis_chunks(out, chunk_axis)
 
 
 def _all_to_all_native(x, axis_name: str, split_axis: int, concat_axis: int,

@@ -236,18 +236,27 @@ def execute(plan, direction: str, x, get_runner, dims: int = 3):
     deadline is open (``resilience.deadline.scope``), the ladder walk is
     bounded by the TIGHTER of it and ``DFFT_FALLBACK_DEADLINE_S`` — a
     retry on behalf of a served request must stop when the request's
-    budget is gone, and the original error (not a timeout) propagates."""
+    budget is gone, and the original error (not a timeout) propagates.
+
+    Spans: ``plan.exec`` around the whole envelope, ``plan.dispatch``
+    around each call of the jitted program until it returns (``obs/
+    tracing.py``); the parent less its children is the envelope's own
+    host time."""
+    from ..analysis.contracts import scope_family
     from . import deadline as _dl
-    horizon = time.monotonic() + min(
-        float(os.environ.get("DFFT_FALLBACK_DEADLINE_S", "600")),
-        _dl.remaining_s(float("inf")))
-    while True:
-        try:
-            out = get_runner()(x)
-        except guards.GuardViolation:
-            raise
-        except Exception as err:  # noqa: BLE001 — the ladder's contract
-            if time.monotonic() > horizon or not demote(plan, err):
+    with obs.span("plan.exec", direction=direction,
+                  family=scope_family(plan)):
+        horizon = time.monotonic() + min(
+            float(os.environ.get("DFFT_FALLBACK_DEADLINE_S", "600")),
+            _dl.remaining_s(float("inf")))
+        while True:
+            try:
+                with obs.span("plan.dispatch"):
+                    out = get_runner()(x)
+            except guards.GuardViolation:
                 raise
-            continue
-        return guards.finish(plan, out, direction, dims)
+            except Exception as err:  # noqa: BLE001 — the ladder's contract
+                if time.monotonic() > horizon or not demote(plan, err):
+                    raise
+                continue
+            return guards.finish(plan, out, direction, dims)

@@ -50,3 +50,50 @@ def test_cache_lands_where_configured(tmp_path, from_env):
     finally:
         for f in _entries(CHECKOUT_CACHE, name):
             os.remove(f)
+
+
+_SCOPES = """
+import sys
+from distributedfft_tpu.parallel.mesh import force_cpu_devices
+force_cpu_devices(4)
+import jax
+import distributedfft_tpu as dfft
+from distributedfft_tpu.analysis import hloscan
+from distributedfft_tpu.obs import profile
+from distributedfft_tpu.parallel.mesh import use_compile_cache
+use_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+def text():
+    plan = dfft.SlabFFTPlan(dfft.GlobalSize(16, 16, 16), dfft.SlabPartition(4),
+                            dfft.Config(use_wisdom=False))
+    aval = hloscan._input_aval(plan, "{direction}")
+    x = jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                             sharding=plan.input_sharding
+                             if "{direction}" == "forward"
+                             else plan.output_sharding)
+    return hloscan._builder(plan, "{direction}").lower(x).compile().as_text()
+
+with profile.scopes_off():
+    assert ".ax" not in text()
+jax.clear_caches()
+assert ".ax" in text(), "the cache served the unscoped build"
+print("ok")
+"""
+
+
+@pytest.mark.parametrize("direction", ["forward", "inverse"])
+def test_cache_keeps_each_builds_scopes(tmp_path, direction):
+    """A program that differs from a cached one only in its metadata (an
+    older build without the per-axis scopes) compiles anew instead of
+    loading the cached executable with the old scopes."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    r = subprocess.run(
+        [sys.executable, "-c", _SCOPES.format(direction=direction)],
+        capture_output=True, text=True, timeout=300, cwd=str(tmp_path),
+        env=env)
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr[-1500:]

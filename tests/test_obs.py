@@ -330,3 +330,83 @@ def test_obs_adds_zero_hlo_ops(tmp_path, devices, cfg_kw, sequence):
     assert hloscan.strip_metadata(on) == hloscan.strip_metadata(off)
     # And the enabled run really did trace (the comparison is not vacuous).
     assert obs.validate_events_dir(str(tmp_path / "obs")) > 0
+
+
+# ---------------------------------------------------------------------------
+# exec spans (resilience/fallback.execute) on the device trace's clock
+# ---------------------------------------------------------------------------
+
+def _exec_plan():
+    from distributedfft_tpu.parallel.mesh import make_slab_mesh
+    return dfft.SlabFFTPlan(dfft.GlobalSize(16, 16, 16), pm.SlabPartition(4),
+                            dfft.Config(use_wisdom=False),
+                            mesh=make_slab_mesh(4))
+
+
+@pytest.mark.parametrize("call, direction", [("exec_r2c", "forward"),
+                                             ("exec_c2r", "inverse")])
+def test_exec_leaves_one_exec_span_with_one_dispatch_child(call, direction,
+                                                           devices):
+    plan = _exec_plan()
+    x = np.random.default_rng(0).random(plan.input_shape)
+    if call == "exec_c2r":
+        x = plan.exec_r2c(x)
+    obs.flightrec.clear()
+    jax.block_until_ready(getattr(plan, call)(x))
+    spans = [r for r in obs.flightrec.snapshot() if r["ev"] == "span"]
+    execs = [r for r in spans if r["name"] == "plan.exec"]
+    kids = [r for r in spans if r["name"] == "plan.dispatch"]
+    assert len(execs) == 1 and len(kids) == 1
+    (e,), (d,) = execs, kids
+    assert e["attrs"] == {"direction": direction, "family": "slab"}
+    assert d["parent"] == "plan.exec" and d["depth"] == e["depth"] + 1
+    assert d["seq"] > e["seq"] and 0 <= d["dur_ms"] <= e["dur_ms"]
+
+
+def test_profiler_trace_has_exec_spans_without_obs_dir(tmp_path, devices,
+                                                       monkeypatch):
+    """The spans mirror into a ``jax.profiler`` trace with the JSONL log
+    off: the program's host spans share the device trace's clock."""
+    from jax.profiler import ProfileData
+
+    monkeypatch.delenv(obs.ENV_VAR, raising=False)
+    assert not obs.enabled()
+    plan = _exec_plan()
+    x = np.random.default_rng(0).random(plan.input_shape)
+    jax.block_until_ready(plan.exec_r2c(x))  # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(plan.exec_r2c(x))
+    (pb,) = tmp_path.glob("**/*.xplane.pb")
+    names = {e.name for p in ProfileData.from_file(str(pb)).planes
+             for ln in p.lines for e in ln.events}
+    assert {"dfft:plan.exec", "dfft:plan.dispatch"} <= names
+    assert obs.event_log_path() is None
+
+
+def test_off_path_stays_jax_free():
+    """A process that never imported jax records spans into the ring,
+    opens no profiler annotation and imports no jax (the package's own
+    ``__init__`` imports jax, so the subprocess loads ``obs`` alone)."""
+    import os
+    import subprocess
+    import sys
+
+    pkg = os.path.dirname(os.path.dirname(obs.__file__))
+    code = (
+        "import sys, types\n"
+        "pkg = types.ModuleType('distributedfft_tpu')\n"
+        f"pkg.__path__ = [{pkg!r}]\n"
+        "sys.modules['distributedfft_tpu'] = pkg\n"
+        "from distributedfft_tpu import obs\n"
+        "with obs.span('plan.exec', direction='forward'):\n"
+        "    with obs.span('plan.dispatch'):\n"
+        "        with obs.profile.axis_scope(0, 3):\n"
+        "            pass\n"
+        "assert [r['name'] for r in obs.flightrec.snapshot()] == "
+        "['plan.dispatch', 'plan.exec']\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+        "print('ok')\n")
+    env = {k: v for k, v in os.environ.items() if k != obs.ENV_VAR}
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120, env=env)
+    assert p.returncode == 0 and p.stdout.strip() == "ok", p.stderr[-2000:]

@@ -246,3 +246,95 @@ def test_aggregate_reads_device_op_line_and_inherits_scope():
     assert agg["scopes"] == {"slab/local_fft:1": 6.0}
     assert agg["unattributed_ms"] == 2.0
     assert agg["total_ms"] == 8.0 and agg["window_ms"] == 9.0
+
+
+# ---------------------------------------------------------------------------
+# per-axis sub-scopes of the local FFT (obs/profile.axis_scope)
+# ---------------------------------------------------------------------------
+
+def _four_device_plan(family):
+    from distributedfft_tpu.parallel.mesh import (make_pencil_mesh,
+                                                  make_slab_mesh)
+    g = dfft.GlobalSize(16, 16, 16)
+    cfg = dfft.Config(use_wisdom=False)
+    if family == "slab":
+        return dfft.SlabFFTPlan(g, pm.SlabPartition(4), cfg,
+                                mesh=make_slab_mesh(4))
+    return dfft.PencilFFTPlan(g, pm.PencilPartition(2, 2), cfg,
+                              mesh=make_pencil_mesh(2, 2))
+
+
+def _axis_scopes(txt):
+    import re
+    return set(re.findall(r"dfft/(\w+/[\w:]+\.ax\d+)", txt))
+
+
+@pytest.mark.parametrize("family, direction", [
+    ("slab", "forward"), ("slab", "inverse"),
+    ("pencil", "forward"), ("pencil", "inverse")])
+def test_axis_subscopes_follow_the_declared_axes(family, direction, devices):
+    """Each one-axis FFT of a local-FFT stage leaves
+    ``dfft/<family>/<node>.ax<k>`` in the compiled metadata, with ``k``
+    the global axis the plan graph declares for that node (the slab and
+    pencil arrays keep the global x, y, z order), and no other."""
+    plan = _four_device_plan(family)
+    graph = plangraph.graph_for(plan, direction, 3)
+    want = {f"{family}/{n.id}.ax{a}" for n in graph.nodes
+            if n.kind == "local_fft" for a in n.axes}
+    assert len(want) == 3
+    txt = hloscan.compiled_text(plan, direction, 3)
+    assert _axis_scopes(txt) == want
+
+
+@pytest.mark.parametrize("family, direction", [
+    ("slab", "forward"), ("slab", "inverse"), ("pencil", "forward")])
+def test_axis_subscopes_are_metadata_only(family, direction, devices):
+    """The zero-overhead pin with the sub-scopes on: the compiled program
+    with metadata stripped is byte-identical with every scope off."""
+    on = hloscan.compiled_text(_four_device_plan(family), direction, 3)
+    with profile.scopes_off():
+        off = hloscan.compiled_text(_four_device_plan(family), direction, 3)
+    assert _axis_scopes(on) and not _axis_scopes(off)
+    assert hloscan.strip_metadata(on) == hloscan.strip_metadata(off)
+
+
+def test_axis_scope_noops_outside_a_stage():
+    import contextlib
+    assert isinstance(profile.axis_scope(0, 3), contextlib.nullcontext)
+    with profile.stage_scope("slab", "local_fft:1"):
+        assert not isinstance(profile.axis_scope(-1, 3),
+                              contextlib.nullcontext)
+        with profile.scopes_off():
+            assert isinstance(profile.axis_scope(0, 3),
+                              contextlib.nullcontext)
+    assert isinstance(profile.axis_scope(0, 3), contextlib.nullcontext)
+
+
+@pytest.mark.parametrize("scope, stage", [
+    ("slab/local_fft:1.ax2", "slab/local_fft:1"),
+    ("pencil/local_fft:3.ax0", "pencil/local_fft:3"),
+    ("slab/exchange:1", "slab/exchange:1"),
+    ("wire/encode", "wire/encode")])
+def test_stage_of_folds_the_axis_suffix(scope, stage):
+    assert profile.stage_of(scope) == stage
+
+
+def test_stage_profile_folds_axis_subscopes_into_their_node(devices):
+    """``dfft-explain --profile`` keeps one row per declared node: the
+    time under ``<node>.ax<k>`` adds to the node's own."""
+    plan = _slab(comm_method=dfft.CommMethod.ALL2ALL)
+    capture = {"scopes": {"slab/local_fft:1": 0.5,
+                          "slab/local_fft:1.ax1": 1.0,
+                          "slab/local_fft:1.ax2": 2.0,
+                          "slab/local_fft:2.ax0": 3.0,
+                          "slab/exchange:1": 4.0},
+               "unattributed_ms": 0.5, "total_ms": 11.0, "window_ms": 12.0,
+               "iters": 1, "planes": []}
+    prof = profile.stage_profile(plan, "forward", 3, capture=capture)
+    rows = {r["node"]: r["device_ms"] for r in prof["stages"]}
+    assert rows["local_fft:1"] == pytest.approx(3.5)
+    assert rows["local_fft:2"] == pytest.approx(3.0)
+    assert rows["exchange:1"] == pytest.approx(4.0)
+    assert prof["other_scopes"] == {}
+    assert prof["attributed_ms"] == pytest.approx(10.5)
+    assert prof["unattributed_ms"] == pytest.approx(0.5)

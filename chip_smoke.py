@@ -5,11 +5,12 @@ One process, no JAX subprocess, no emulated devices. The default run
 needs one chip and drives, through the normal entry points:
 
 1. device     — the first device is a TPU (else exit non-zero);
-2. plan       — ``SlabFFTPlan`` 512^3 R2C+C2R, default ``Config``: the
+2. plan       — ``SlabFFTPlan`` 512^3 R2C+C2R, default ``Config`` (the
+                platform default: MXU matmuls at HIGHEST on a TPU): the
                 analytic spectrum and the roundtrip identity at 512^3,
                 and the dense random spectrum against ``numpy.fft.rfftn``
                 at 256^3;
-3. backends   — the same 512^3 plan on the ``matmul`` and ``pallas``
+3. backends   — the same 512^3 plan on the ``xla`` and ``pallas``
                 backends; pallas must compile to a Mosaic kernel;
 4. serve      — an in-process ``serve.Server`` answering 2D images and a
                 3D volume, each checked against numpy;
@@ -120,13 +121,17 @@ def device_random(plan, seed: int = 0):
     return plan.pad_input(gen(jax.random.key(seed)))
 
 
-def phase_plan(n: int, backend: str, tag: str):
+def phase_plan(n: int, backend, tag: str):
+    """``backend`` None: the default Config, the platform's backend."""
     import jax
 
     import distributedfft_tpu as dfft
 
     plan = dfft.SlabFFTPlan(dfft.GlobalSize(n, n, n), dfft.SlabPartition(1),
                             dfft.Config(fft_backend=backend))
+    backend = plan.config.fft_backend
+    say(tag, backend=backend, precision=plan.config.mxu_precision,
+        direct_max=plan.config.mxu_direct_max)
     x = device_random(plan)
     compile_s, steady_ms, y = timed_roundtrip(plan, x)
     say(tag, shape=f"{n}^3", backend=backend, compile_s=f"{compile_s:.3f}",
@@ -145,7 +150,7 @@ def phase_host_truth(n: int, plan_factory, tag: str):
     plan = plan_factory(n)
     r = testcases.testcase1(plan, truth="host", write_csv=False)
     rel = r["residual_max"] / r["ref_max"]
-    bound = BOUNDS["xla"]
+    bound = BOUNDS[plan.config.fft_backend]
     say(tag, check=f"spectrum_vs_numpy_{n}^3", rel_err=rel, bound=bound)
     check(rel <= bound, f"{tag}: numpy spectrum rel err {rel}")
 
@@ -174,7 +179,11 @@ def phase_serve() -> None:
     # One inverse image and one inverse volume: the C2R path.
     reqs += [(np.fft.rfftn(rng.random(s)).astype(np.complex64), "inverse")
              for s in ((2048, 2048), (256, 256, 256))]
-    bound = BOUNDS["xla"]
+    from distributedfft_tpu.utils import wisdom
+
+    fields, _ = wisdom.platform_default(dfft.Config(),
+                                        dfft.GlobalSize(256, 256, 256))
+    bound = BOUNDS[fields["fft_backend"]]
     with serve.Server(dfft.SlabPartition(1), dfft.Config(),
                       latency_budget_ms=600_000.0) as srv:
         for label in ("cold", "warm"):
@@ -223,11 +232,11 @@ def phase_profile(plan) -> None:
 def run_one_chip() -> None:
     import distributedfft_tpu as dfft
 
-    plan = phase_plan(512, "xla", "plan")
+    plan = phase_plan(512, None, "plan")
     phase_host_truth(256, lambda n: dfft.SlabFFTPlan(
         dfft.GlobalSize(n, n, n), dfft.SlabPartition(1), dfft.Config()),
         "plan")
-    phase_plan(512, "matmul", "backends")
+    phase_plan(512, "xla", "backends")
     phase_pallas_kernel(phase_plan(512, "pallas", "backends"))
     phase_serve()
     phase_profile(plan)
@@ -256,8 +265,11 @@ def run_multichip() -> None:
         path = ("create_device_mesh" if pmesh._topology_mesh(shape)
                 is not None else "enumeration")
         plan = make(n)
+        backend = plan.config.fft_backend
         say(tag, mesh_path=path,
-            mesh_devices=[d.id for d in plan.mesh.devices.flat])
+            mesh_devices=[d.id for d in plan.mesh.devices.flat],
+            backend=backend, precision=plan.config.mxu_precision,
+            direct_max=plan.config.mxu_direct_max)
         x = device_random(plan)
         compile_s, steady_ms, y = timed_roundtrip(plan, x)
         say(tag, shape=f"{n}^3", compile_s=f"{compile_s:.3f}",
@@ -267,9 +279,9 @@ def run_multichip() -> None:
             nd = len(arr.sharding.device_set)
             say(tag, output=what, devices=nd)
             check(nd == 4, f"{tag}: {what} lives on {nd} devices")
-        exec_roundtrip_check(tag, "xla", plan, x, y)
+        exec_roundtrip_check(tag, backend, plan, x, y)
         del x, y, c
-        rel_checks(tag, "xla", plan, roundtrip=False)
+        rel_checks(tag, backend, plan, roundtrip=False)
         peaks = [peak_bytes(d) for d in devs]
         quarter = 4 * n ** 3 // 4  # f32 input bytes per device
         say(tag, peak_bytes_in_use=peaks)

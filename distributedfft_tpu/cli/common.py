@@ -46,10 +46,12 @@ def add_common_args(ap: argparse.ArgumentParser, pencil: bool = False,
                          "no native f64)")
     ap.add_argument("--benchmark_dir", "-b", default="benchmarks",
                     help="prefix for the benchmark directory")
-    ap.add_argument("--fft-backend", default="xla",
+    ap.add_argument("--fft-backend", default=None,
                     choices=BACKENDS + ("auto",),
-                    help="local transform implementation: XLA's FFT "
-                         "expansion (default), MXU four-step DFT matmuls "
+                    help="local transform implementation (default: the "
+                         "platform's: MXU matmuls at HIGHEST on a TPU for "
+                         "float32 and smooth extents, else XLA): XLA's FFT "
+                         "expansion, MXU four-step DFT matmuls "
                          "(ops/mxu_fft.py), Pallas fused DFT+twiddle "
                          "kernels (ops/pallas_fft.py), or 'auto' — pick by "
                          "measurement via the wisdom store (race once, "

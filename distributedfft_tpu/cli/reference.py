@@ -139,6 +139,15 @@ def _dispatch(args, shape, dtype, it, wu) -> int:
                     mxu_precision=rec.get("mxu_precision"),
                     mxu_direct_max=rec.get("mxu_direct_max"),
                 ).mxu_settings()
+        elif backend is None:
+            # Bare single-device transform: the platform default a plan of
+            # this shape would resolve to.
+            from .. import params as pm
+            from ..utils import wisdom
+            fields, _ = wisdom.platform_default(
+                pm.Config(double_prec=args.double_prec), pm.GlobalSize(*shape))
+            backend = fields["fft_backend"]
+            settings = pm.Config(**fields).mxu_settings()
         ms = mb.single_device_fft_ms(shape, it, wu, dtype,
                                      backend=backend, settings=settings)
         print(f"Run complete: {ms:.4f} ms (single-device 3D R2C, "

@@ -53,6 +53,19 @@ def notice_axis_smoothness(kind: str, axes_lengths: Iterable[int],
             backend=config.fft_backend)
 
 
+def local_fft_fields(config: Config, settings: Any) -> Dict[str, Any]:
+    """The local-FFT backend with the MXU precision and direct-matmul
+    threshold a plan traces with (None for backends without DFT
+    matmuls), for the ``plan.created`` event."""
+    be = config.fft_backend
+    if be not in ("matmul", "matmul-r2", "pallas"):
+        return {"backend": be, "precision": None, "direct_max": None}
+    from ..ops import mxu_fft
+    st = settings or mxu_fft.default_settings()
+    return {"backend": be, "precision": st.precision.name.lower(),
+            "direct_max": st.direct_max}
+
+
 def _with_pad(pure: Callable[..., Any], logical_shape: Sequence[int],
               padded_shape: Sequence[int]) -> Callable[..., Any]:
     """Wrap a pure pipeline so logical-shaped input is zero-padded to the

@@ -39,7 +39,8 @@ from ..parallel.transpose import (all_to_all_transpose, chunked_reshard,
                                   ring_transpose, slice_axis_to,
                                   split_axis_chunks, wire_gspmd_stages)
 from ..utils import wisdom
-from .base import _with_pad, jit_stages, notice_axis_smoothness
+from .base import (_with_pad, jit_stages, local_fft_fields,
+                   notice_axis_smoothness)
 
 
 class Batched2DFFTPlan:
@@ -141,7 +142,7 @@ class Batched2DFFTPlan:
                   comm=self.config.comm_method.value,
                   send=self.config.send_method.value, opt=self.config.opt,
                   wire=self.config.wire_dtype,
-                  backend=self.config.fft_backend)
+                  **local_fft_fields(self.config, self._mxu_st))
 
     # -- shapes ------------------------------------------------------------
 

@@ -62,7 +62,8 @@ from ..parallel.transpose import (WIRE_NATIVE, all_to_all_transpose,
                                   wire_encode)
 from ..resilience import inject
 from ..utils import wisdom
-from .base import DistFFTPlan, _with_pad, notice_axis_smoothness
+from .base import (DistFFTPlan, _with_pad, local_fft_fields,
+                   notice_axis_smoothness)
 
 P1_AXIS, P2_AXIS = PENCIL_AXES
 
@@ -130,7 +131,7 @@ class PencilFFTPlan(DistFFTPlan):
                   send=self.config.send_method.value,
                   send2=self.config.resolved_snd2().value,
                   opt=self.config.opt, wire=self.config.wire_dtype,
-                  backend=self.config.fft_backend)
+                  **local_fft_fields(self.config, self._mxu_st))
 
     # -- shapes ------------------------------------------------------------
 

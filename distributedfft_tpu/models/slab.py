@@ -66,7 +66,8 @@ from ..parallel.transpose import (all_to_all_transpose, chunked_reshard,
                                   ring_transpose, slice_axis_to,
                                   split_axis_chunks, wire_gspmd_stages)
 from ..utils import wisdom
-from .base import DistFFTPlan, _with_pad, notice_axis_smoothness
+from .base import (DistFFTPlan, _with_pad, local_fft_fields,
+                   notice_axis_smoothness)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,7 +150,7 @@ class SlabFFTPlan(DistFFTPlan):
                   comm=self.config.comm_method.value,
                   send=self.config.send_method.value, opt=self.config.opt,
                   wire=self.config.wire_dtype,
-                  backend=self.config.fft_backend)
+                  **local_fft_fields(self.config, self._mxu_st))
 
     # -- shapes & size tables (reference getInSize/getOutSize family,
     #    include/mpicufft.hpp:66-79) --------------------------------------

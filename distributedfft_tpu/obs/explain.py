@@ -108,7 +108,9 @@ def build_parser() -> argparse.ArgumentParser:
                     metavar="steps:N[,secs:T][,drain:on|off]",
                     help="resolve the checkpoint cadence shown in the "
                          "checkpoint: section (default $DFFT_CKPT_POLICY)")
-    ap.add_argument("--fft-backend", default="xla")
+    ap.add_argument("--fft-backend", default=None,
+                    help="local FFT backend (default: the platform's, "
+                         "utils/wisdom.platform_default)")
     ap.add_argument("--double_prec", "-d", action="store_true")
     ap.add_argument("--c2c", action="store_true",
                     help="explain the C2C transform instead of R2C")
@@ -740,6 +742,8 @@ def main(argv=None) -> int:
                                     subblocks=cfg
                                     .resolved_overlap_subblocks()))
         out.append(f"  local FFT backend: {cfg.fft_backend}"
+                   + (f" ({prov['backend_default']})"
+                      if prov.get("backend_default") else "")
                    + (f" (mxu_precision={cfg.mxu_precision}, "
                       f"mxu_direct_max={cfg.mxu_direct_max})"
                       if cfg.fft_backend.startswith("matmul") else ""))

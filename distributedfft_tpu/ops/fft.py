@@ -9,10 +9,13 @@ maps the cuFFT "unnormalized both ways" convention onto numpy norm strings.
 All functions are shape-polymorphic, jit-safe wrappers; batching comes from
 the untouched axes (cuFFT "batched plan" ≙ XLA treating other axes as batch).
 
-Every entry point takes ``backend``: ``"xla"`` (default) lowers to XLA's FFT
+Every entry point takes ``backend``: ``"xla"`` lowers to XLA's FFT
 expansion; ``"matmul"`` dispatches to the MXU matmul four-step backend
 (``ops/mxu_fft.py``) — the TPU-first alternative that keeps the FLOPs on the
-systolic array; ``"matmul-r2"`` is the same backend with radix-2 DIF
+systolic array, and what a plan whose Config names no backend resolves to on
+a TPU for float32 and 5-smooth extents, at ``Precision.HIGHEST``
+(``utils/wisdom.platform_default``; elsewhere that default is ``"xla"``);
+``"matmul-r2"`` is the same backend with radix-2 DIF
 splitting of the C2C stages down to MXU-depth matmuls (measured slower on
 v5e at 256^3 — see ``mxu_fft.MXUSettings.radix2`` — raced for completeness);
 ``"pallas"`` runs the same four-step with hand-written Pallas kernels

@@ -1,41 +1,43 @@
 """MXU matmul-based FFT backend (four-step Cooley-Tukey).
 
-TPU has no FFT hardware unit: XLA expands ``fft`` HLOs into scalar/vector
-code that runs on the VPU, leaving the 128x128 MXU systolic array — where
-virtually all of the chip's FLOPs live — idle. This backend reformulates the
-DFT as dense matrix multiplication so the transform runs on the MXU:
+TPU has no FFT hardware unit: XLA expands ``fft`` HLOs into a chain of
+small matmuls and relayouts that is bound by HBM traffic, not by the
+128x128 MXU systolic array where virtually all of the chip's FLOPs live.
+This backend writes the DFT as dense matrix products so the transform runs
+on the MXU, each along its axis in place (one ``dot_general``; no swap of
+the axis to the end):
 
-* **Direct**: for ``n <= DIRECT_MAX`` the transform along an axis is one
-  batched matmul with the ``n x n`` DFT matrix ``F[j,k] = w^(jk)``,
-  ``w = exp(-2*pi*i/n)``.
-* **Four-step** (Bailey): for larger ``n = n1*n2``, decompose index
-  ``n = s*n1 + r`` (r in [0,n1)), ``k = k1*n2 + k2``:
+* **Direct**: for ``n <= direct_max`` the transform along an axis is one
+  contraction with the ``n x n`` DFT matrix ``F[j,k] = w^(jk)``,
+  ``w = exp(-2*pi*i/n)``. The real transforms (R2C, C2R) run direct up
+  to ``_REAL_DIRECT * direct_max``: one pass over HBM, where the
+  four-step pays three.
+* **Four-step** (Bailey): for larger ``n = n1*n2`` (``_split_for``: n2 the
+  largest divisor <= ``direct_max``, n1 the short factor), input index
+  ``t = a*n2 + b``, output ``k = c + n1*d``:
 
-      X[k1*n2+k2] = sum_r W_n1^(r*k1) * [ W_n^(r*k2) * sum_s x[s*n1+r] * W_n2^(s*k2) ]
+      X[c + n1*d] = sum_b W_n2^(b*d) * [ W_n^(b*c) * sum_a x[a*n2+b] * W_n1^(a*c) ]
 
-  i.e. reshape -> DFT matmul (n2) -> twiddle multiply -> DFT matmul (n1) ->
-  reshape, recursing when a factor still exceeds ``DIRECT_MAX``. The matmul
-  count is O(n * (n1+n2)) flops — more than O(n log n), but on the MXU's
-  dense-matmul throughput rather than the VPU's. The factor choice is the
-  MXU-deep split (``_split_for``): whenever both factors can stay on the
-  direct path, the dominant factor is the largest divisor <= ``direct_max``
-  (2048 -> 4x512, 4096 -> 8x512) rather than the balanced pair (32x64,
-  64x64), so intermediate lengths keep the systolic array's full
-  contraction depth — the large-axis extension of the direct table, driven
-  by a pre-PR-12 single-chip 1024^3 measurement (652 vs 228 GFLOPS/chip;
-  not measured on the current code).
+  The short DFT over a and the twiddle are elementwise work on the n1
+  contiguous blocks of the axis (``_fourstep``); each long DFT over b is
+  one MXU contraction; interleaving d and c is the one relayout. The R2C
+  computes only the outputs k <= n/2 and the C2R reads only the n/2+1
+  stored ones (``_rfft_axis``, ``_irfft_axis``), half the long-stage work.
 
-The matmul is the hot op of this backend; it lowers to plain XLA
-``dot_general`` so the compiler fuses the twiddle multiplies into the
-surrounding elementwise graph.
+Precision: ``MXUSettings.precision`` applies to float32 operands. The
+platform default (``utils/wisdom.platform_default``) runs ``HIGHEST`` —
+float32 products with float32 accumulation — and picks ``direct_max`` per
+extent from ``DIRECT_MAX_BY_EXTENT``; an explicitly named matmul backend
+keeps the process default, ``HIGH`` (three bf16 passes). float64 always
+runs ``HIGHEST``.
 
-Role in the framework: selected by ``Config.fft_backend = "matmul"`` as a
-drop-in alternative to the XLA-FFT local layer (``ops/fft.py``); this is the
-TPU-first analog of the reference's cuFFT plan choice (the reference's L0
-shim, ``include/cufft.hpp:23-61``, hard-wires cuFFT — on TPU the equivalent
-"vendor transform" is a compiler expansion, so the framework supplies its own
-MXU-shaped implementation and lets benchmarks pick the winner, preserving
-the reference's comparative spirit).
+Role in the framework: ``Config.fft_backend = "matmul"``, and what a
+Config that names no backend resolves to on a TPU for float32 and
+5-smooth extents; the alternative to the XLA-FFT local layer
+(``ops/fft.py``). This is the TPU-first analog of the reference's cuFFT
+plan choice (the reference's L0 shim, ``include/cufft.hpp:23-61``,
+hard-wires cuFFT — on TPU the equivalent "vendor transform" is a compiler
+expansion, so the framework supplies its own MXU-shaped implementation).
 
 Normalization follows the cuFFT "unnormalized both ways" convention mapped
 through ``FFTNorm`` exactly like ``ops/fft.py``.
@@ -62,6 +64,30 @@ from ..params import FFTNorm
 # contraction depth (= n) stays a multiple of the MXU's 128-deep pipeline.
 DIRECT_MAX = 512
 
+# The platform default's direct-matmul threshold, by the largest extent a
+# plan transforms (``utils/wisdom.platform_default``): rows of (largest
+# extent up to, mxu_direct_max), first fit. Past the last row XLA's own
+# FFT was faster and the platform default keeps ``"xla"``. From one v5e
+# at HIGHEST (PERF.md section 6), complex axes: 512 direct (26.4 ms against
+# 31.8 for 2x256); 1024 as 4x256 (34.2 against 36.4 for 2x512, 40.2
+# direct, 40.5 XLA); 2048 as 4x512 (43.9 against 52.0 for 8x256; XLA
+# 42.2, but the R2C's direct contraction wins 41.8 to 51.4); 4096 as
+# 8x512 took 54.9 against XLA's 40.6.
+DIRECT_MAX_BY_EXTENT: Tuple[Tuple[int, int], ...] = (
+    (512, 512),
+    (1024, 256),
+    (2048, 512),
+)
+
+
+def direct_max_for(n: int) -> Optional[int]:
+    """``mxu_direct_max`` for a plan whose largest extent is ``n``, or
+    None past the table (the platform default keeps XLA's FFT)."""
+    for upto, dm in DIRECT_MAX_BY_EXTENT:
+        if n <= upto:
+            return dm
+    return None
+
 
 @dataclasses.dataclass(frozen=True)
 class MXUSettings:
@@ -79,8 +105,10 @@ class MXUSettings:
     * ``precision`` — MXU precision for SINGLE-precision DFT matmuls.
       Raw bf16 (DEFAULT) leaves 5.4e-4 max rel error at 256^3 (v5e,
       f32 vs f64 truth); three-pass bf16 emulation (HIGH) reaches
-      8.2e-7 — O(f32 eps) — at half the MXU passes of HIGHEST (3.0e-8),
-      so HIGH is the default. f64 inputs always use HIGHEST.
+      8.2e-7 at half the MXU passes of HIGHEST (3.0e-8), float32
+      products. HIGH is this class's default; the platform default of a
+      Config that names no backend sets HIGHEST. f64 inputs always use
+      HIGHEST.
     * ``radix2`` — DIF splitting of C2C stages down to depth-128
       matmuls (see the analysis above ``_fft_radix2``).
     * ``karatsuba`` — 3-matmul complex multiply (see ``_matmul_F``).
@@ -341,45 +369,49 @@ def _fft_radix2(x, inverse: bool):
     return jnp.stack([even, odd], axis=-1).reshape(x.shape[:-1] + (n,))
 
 
-def _matmul_F(x, F_np: np.ndarray):
-    """x @ F for complex x and a constant complex DFT matrix."""
-    prec = _prec_for(x.dtype)
+def _tdot(x, axis: int, M):
+    """``y[.., k, ..] = sum_j x[.., j, ..] M[j, k]`` along ``axis``, the
+    output axis left in place (one dot_general; XLA folds the move into
+    its operand layouts)."""
+    y = jnp.tensordot(x, M, axes=[[axis], [0]], precision=_prec_for(x.dtype))
+    return jnp.moveaxis(y, -1, axis)
+
+
+def _matmul_F(x, F_np: np.ndarray, axis: int = -1):
+    """x @ F along ``axis`` for complex x and a constant complex DFT
+    matrix."""
+    axis %= x.ndim
     if not current_settings().karatsuba:
-        return jnp.matmul(x, jnp.asarray(F_np), precision=prec)
+        return _tdot(x, axis, jnp.asarray(F_np))
     rdt = np.float64 if _is_double(x.dtype) else np.float32
     Fr = jnp.asarray(np.ascontiguousarray(F_np.real.astype(rdt)))
     Fi = jnp.asarray(np.ascontiguousarray(F_np.imag.astype(rdt)))
     Fs = jnp.asarray((F_np.real + F_np.imag).astype(rdt))
     ar, ai = jnp.real(x), jnp.imag(x)
-    t1 = jnp.matmul(ar, Fr, precision=prec)
-    t2 = jnp.matmul(ai, Fi, precision=prec)
-    t3 = jnp.matmul(ar + ai, Fs, precision=prec)
+    t1 = _tdot(ar, axis, Fr)
+    t2 = _tdot(ai, axis, Fi)
+    t3 = _tdot(ar + ai, axis, Fs)
     return lax.complex(t1 - t2, t3 - t1 - t2)
 
 
-def _rmatmul_F(x_real, F_np: np.ndarray):
-    """x @ F for REAL x: two real matmuls instead of a complex one (halves
-    the MXU work for the R2C first stage and the four-step first stage)."""
-    prec = _prec_for(x_real.dtype)
-    re = jnp.matmul(x_real, jnp.asarray(np.ascontiguousarray(F_np.real)),
-                    precision=prec)
-    im = jnp.matmul(x_real, jnp.asarray(np.ascontiguousarray(F_np.imag)),
-                    precision=prec)
+def _rmatmul_F(x_real, F_np: np.ndarray, axis: int = -1):
+    """x @ F along ``axis`` for REAL x: two real matmuls instead of a
+    complex one (halves the MXU work of the R2C stages)."""
+    axis %= x_real.ndim
+    re = _tdot(x_real, axis, jnp.asarray(np.ascontiguousarray(F_np.real)))
+    im = _tdot(x_real, axis, jnp.asarray(np.ascontiguousarray(F_np.imag)))
     return lax.complex(re, im)
 
 
-# Four-step layout strategy. The original formulation materializes three
-# jnp.swapaxes relayouts of the full array per four-step level (pack to
-# [r,s], re-pack between the stages, unpack at the end); the einsum
-# formulation contracts the reshaped factor axes directly (dot_general
-# with non-trailing contracting dims), letting XLA pick operand layouts.
-# Measured on v5e (batched-2D 2048^2 x 64 roundtrip, same session):
-# einsum 167.3 ms vs swapaxes 137.2 ms — XLA's layout assignment for the
-# non-trailing contraction is WORSE than the explicit relayout pipeline,
-# so the swapaxes path stays the default and the einsum variant remains a
-# benchmarkable toggle (``MXUSettings.fourstep_einsum``; exact same math,
-# bit-identical in f64 on CPU). Applies when both factors are direct-sized
-# (n <= DIRECT_MAX^2 = 256k — every practical axis).
+# Four-step layout alternative. ``_fourstep`` (the default) keeps the
+# transform axis in place and runs the long DFTs as one contraction; the
+# einsum formulation below takes the axis to the end and contracts both
+# reshaped factor axes with dot_general, the twiddle between them. It was
+# measured slower than the swapaxes pipeline ``_fourstep`` replaced
+# (v5e, batched-2D 2048^2 x 64 roundtrip: einsum 167.3 ms vs swapaxes
+# 137.2 ms, an older row) and stays a benchmarkable toggle
+# (``MXUSettings.fourstep_einsum``; the same math, equal to 1e-14 in f64
+# on CPU). Applies when both factors are direct-sized.
 
 
 def set_fourstep_einsum(on: bool) -> None:
@@ -421,56 +453,175 @@ def _fourstep_einsum(x4, inverse: bool, n1: int, n2: int, dbl: bool):
     return d.reshape(d.shape[:-2] + (n1 * n2,))
 
 
-def _fft_last(x, inverse: bool):
-    """Unnormalized DFT along the last axis of a complex array."""
-    n = x.shape[-1]
+# The short stage of the four-step (``_fourstep``) runs as an unrolled
+# sum of scaled slices on the vector units up to this radix (every output
+# slice reads the n1 input slices; the DFT weights of radix 2 and 4 are
+# +-1 and +-i); a longer short stage recurses through the matmul path.
+_UNROLL_MAX = 8
+
+# The real transforms (R2C, C2R) take the direct contraction up to this
+# multiple of ``direct_max``. A direct real transform of length n does
+# about n real MACs an element and reads and writes the array once; the
+# four-step does less arithmetic but pays three HBM passes. Measured on
+# one v5e at HIGHEST (PERF.md section 6): direct R2C/C2R at 1024 beat the
+# 4x256 four-step by 8 and 21 ms, and direct R2C at 2048 beat 4x512.
+_REAL_DIRECT = 4
+
+
+def _bcast(v: np.ndarray, axis: int, ndim: int):
+    """A constant laid along ``axis`` (and the axes after it, one per
+    dimension of ``v``) of an ``ndim`` array."""
+    return jnp.asarray(v.reshape(v.shape + (1,) * (ndim - axis - v.ndim)))
+
+
+def _short_dft(slices, W: np.ndarray, k: int):
+    """``sum_j W[j, k] * slices[j]`` with the unit weights spelled as
+    adds and subtracts."""
+    acc = None
+    for j, v in enumerate(slices):
+        w = complex(W[j, k])
+        if w == 1:
+            t = v
+        elif w == -1:
+            t = -v
+        else:
+            t = v * w
+        acc = t if acc is None else acc + t
+    return acc
+
+
+def _fft_axis(x, axis: int, inverse: bool):
+    """Unnormalized DFT along ``axis`` of a complex array, left in place."""
+    n = x.shape[axis]
     dbl = _is_double(x.dtype)
     st = current_settings()
+    last = axis == x.ndim - 1
     if st.radix2 and n > _R2_BASE and n % 2 == 0:
-        return _fft_radix2(x, inverse)
+        if last:
+            return _fft_radix2(x, inverse)
+        return jnp.moveaxis(_fft_radix2(jnp.moveaxis(x, axis, -1), inverse),
+                            -1, axis)
     if n <= st.direct_max:
-        return _matmul_F(x, _dft_np(n, inverse, dbl))
+        return _matmul_F(x, _dft_np(n, inverse, dbl), axis)
     n1, n2 = _split_for(n, st.direct_max)
     if n1 == 1:  # prime length: direct full-size matmul
-        return _matmul_F(x, _dft_np(n, inverse, dbl))
+        return _matmul_F(x, _dft_np(n, inverse, dbl), axis)
     if st.fourstep_einsum and n1 <= st.direct_max and n2 <= st.direct_max:
-        return _fourstep_einsum(x.reshape(x.shape[:-1] + (n2, n1)),
-                                inverse, n1, n2, dbl)
-    # x[..., s*n1 + r] -> A[..., r, s]
-    a = jnp.swapaxes(x.reshape(x.shape[:-1] + (n2, n1)), -1, -2)
-    b = _fft_last(a, inverse)                       # DFT over s -> (r, k2)
-    c = b * jnp.asarray(_twiddle_np(n1, n2, inverse, dbl))
-    d = _fft_last(jnp.swapaxes(c, -1, -2), inverse)  # DFT over r -> (k2, k1)
-    return jnp.swapaxes(d, -1, -2).reshape(x.shape[:-1] + (n,))
+        xl = x if last else jnp.moveaxis(x, axis, -1)
+        y = _fourstep_einsum(xl.reshape(xl.shape[:-1] + (n2, n1)),
+                             inverse, n1, n2, dbl)
+        return y if last else jnp.moveaxis(y, -1, axis)
+    return _fourstep(x, axis, n1, n2, inverse)
 
 
-def _rfft_last(x):
-    """Unnormalized R2C DFT along the last axis of a real array; output
-    length n//2+1 (the reference's R2C halving, ``params.hpp:30``)."""
-    n = x.shape[-1]
+def _fourstep(x, axis: int, n1: int, n2: int, inverse: bool):
+    """Four-step DFT of length n = n1*n2 along ``axis`` with the short
+    factor n1 outside: input t = a*n2 + b, output k = c + n1*d.
+
+    The short DFT over a (n1, unrolled up to ``_UNROLL_MAX``) and the
+    twiddle W_n^(b c) are elementwise work on the n1 contiguous blocks of
+    the axis, stacked as [.., b, c, ..]; the n1 long DFTs over b (n2) are
+    then one contraction on the MXU whose output [.., d, c, ..] is the
+    result, reshaped. No swap of the transform axis to the end is needed,
+    and one contraction needs fewer HBM bytes and temporaries than n1
+    (XLA's cost model and buffer assignment for a v5e)."""
+    s, ax = x.shape, axis
+    dbl = _is_double(x.dtype)
+    W1 = _dft_np(n1, inverse, dbl)
+    T = _twiddle_np(n1, n2, inverse, dbl)                  # T[c, b]
+    if n1 <= _UNROLL_MAX:
+        xa = [lax.slice_in_dim(x, a * n2, (a + 1) * n2, axis=ax)
+              for a in range(n1)]
+        y = jnp.stack([_short_dft(xa, W1, c) for c in range(n1)],
+                      axis=ax + 1) * _bcast(T.T, ax, x.ndim + 1)
+        z = _fft_axis(y, ax, inverse)                      # [.., d, c, ..]
+    else:
+        xr = x.reshape(s[:ax] + (n1, n2) + s[ax + 1:])
+        y = _fft_axis(xr, ax, inverse)                     # [.., c, b, ..]
+        y = y * _bcast(T, ax, xr.ndim)
+        z = jnp.swapaxes(_fft_axis(y, ax + 1, inverse), ax, ax + 1)
+    return z.reshape(s)
+
+
+def _fft_last(x, inverse: bool):
+    """Unnormalized DFT along the last axis of a complex array."""
+    return _fft_axis(x, x.ndim - 1, inverse)
+
+
+def _rfft_axis(x, axis: int):
+    """Unnormalized R2C DFT along ``axis`` of a real array; output length
+    n//2+1 there (the reference's R2C halving, ``params.hpp:30``).
+
+    Direct up to ``_REAL_DIRECT * direct_max``; longer axes run
+    ``_fourstep``'s decomposition with each long DFT cut to the outputs
+    k <= n/2 (d <= (n//2)//n1): about half the long-stage work, where
+    transforming the full length and slicing would throw half of it
+    away."""
+    n = x.shape[axis]
     n_out = n // 2 + 1
     dbl = _is_double(x.dtype)
     st = current_settings()
-    if n <= st.direct_max:
-        return _rmatmul_F(x, _dft_np(n, False, dbl)[:, :n_out])
     n1, n2 = _split_for(n, st.direct_max)
-    if n1 == 1:
-        return _rmatmul_F(x, _dft_np(n, False, dbl)[:, :n_out])
-    if st.fourstep_einsum and n1 <= st.direct_max and n2 <= st.direct_max:
-        full = _fourstep_einsum(x.reshape(x.shape[:-1] + (n2, n1)),
-                                False, n1, n2, dbl)
-        return full[..., :n_out]
-    a = jnp.swapaxes(x.reshape(x.shape[:-1] + (n2, n1)), -1, -2)
-    # First stage on real data: real matmul pair.
-    if n2 <= st.direct_max:
-        b = _rmatmul_F(a, _dft_np(n2, False, dbl))
-    else:
+    if n <= _REAL_DIRECT * st.direct_max or n1 == 1:
+        return _rmatmul_F(x, _dft_np(n, False, dbl)[:, :n_out], axis)
+    if n1 > _UNROLL_MAX:
         cdt = np.complex128 if dbl else np.complex64
-        b = _fft_last(a.astype(cdt), False)
-    c = b * jnp.asarray(_twiddle_np(n1, n2, False, dbl))
-    d = _fft_last(jnp.swapaxes(c, -1, -2), False)
-    full = jnp.swapaxes(d, -1, -2).reshape(x.shape[:-1] + (n,))
-    return full[..., :n_out]
+        return lax.slice_in_dim(_fft_axis(x.astype(cdt), axis, False),
+                                0, n_out, axis=axis)
+    W1 = _dft_np(n1, False, dbl)
+    T = _twiddle_np(n1, n2, False, dbl)
+    F2 = _dft_np(n2, False, dbl)[:, :(n // 2) // n1 + 1]
+    xa = [lax.slice_in_dim(x, a * n2, (a + 1) * n2, axis=axis)
+          for a in range(n1)]
+    y = jnp.stack([_short_dft(xa, W1, c) for c in range(n1)],
+                  axis=axis + 1) * _bcast(T.T, axis, x.ndim + 1)
+    z = _matmul_F(y, F2, axis)                             # [.., d, c, ..]
+    z = z.reshape(x.shape[:axis] + (F2.shape[1] * n1,) + x.shape[axis + 1:])
+    return lax.slice_in_dim(z, 0, n_out, axis=axis)
+
+
+def _rfft_last(x):
+    """``_rfft_axis`` along the last axis."""
+    return _rfft_axis(x, x.ndim - 1)
+
+
+def _irfft_axis(c, n: int, axis: int):
+    """Unnormalized C2R along ``axis`` of a half spectrum already fitted
+    to n//2+1 entries: real output of length n.
+
+    Up to ``_REAL_DIRECT * direct_max``: two real contractions with the folded
+    half-spectrum matrices (``_c2r_np``). Longer axes: the real output is
+    Re(IDFT(X~)) with X~[k] = a_k X[k] for k <= n/2 and 0 above (a_k = 2
+    but at DC and Nyquist), so ``_fourstep``'s inverse runs each long
+    DFT over the d <= (n//2)//n1 entries of its residue class c only,
+    keeps the real part of the short stage, and writes the n1 contiguous
+    output blocks: half the work of rebuilding the Hermitian spectrum,
+    and no reversal."""
+    dbl = _is_double(c.dtype)
+    st = current_settings()
+    n1, n2 = _split_for(n, st.direct_max)
+    direct = n <= _REAL_DIRECT * st.direct_max or n1 == 1
+    if direct or n1 > _UNROLL_MAX:
+        if direct:
+            CR, CI = _c2r_np(n, dbl)
+            return (_tdot(jnp.real(c), axis, jnp.asarray(CR))
+                    - _tdot(jnp.imag(c), axis, jnp.asarray(CI)))
+        return jnp.real(_fft_axis(_hermitian_extend(c, n, axis), axis, True))
+    m = n // 2
+    D = m // n1 + 1
+    k = np.arange(m + 1)
+    wk = np.where((k == 0) | ((k == m) & (n % 2 == 0)), 1.0, 2.0)
+    c = c * _bcast(wk.astype(np.float64 if dbl else np.float32), axis,
+                   c.ndim)
+    c = _fit_axis(c, axis, D * n1)
+    cr = c.reshape(c.shape[:axis] + (D, n1) + c.shape[axis + 1:])
+    F2 = _dft_np(n2, True, dbl)[:D]
+    W1 = _dft_np(n1, True, dbl)
+    T = _twiddle_np(n1, n2, True, dbl)
+    v = _matmul_F(cr, F2, axis) * _bcast(T.T, axis, cr.ndim)  # [.., b, j, ..]
+    vs = [lax.index_in_dim(v, j, axis + 1, keepdims=False) for j in range(n1)]
+    return jnp.concatenate([jnp.real(_short_dft(vs, W1, a))
+                            for a in range(n1)], axis=axis)
 
 
 @functools.lru_cache(maxsize=None)
@@ -491,10 +642,14 @@ def _c2r_np(n: int, double: bool) -> Tuple[np.ndarray, np.ndarray]:
     return (a * np.cos(ang)).astype(dt), (a * np.sin(ang)).astype(dt)
 
 
-def _hermitian_extend(c, n: int):
-    """Rebuild the full length-n spectrum from its n//2+1 half (C2R input)."""
-    tail = jnp.conj(c[..., 1:(n + 1) // 2])[..., ::-1]
-    return jnp.concatenate([c, tail], axis=-1)
+def _hermitian_extend(c, n: int, axis: int = -1):
+    """Rebuild the full length-n spectrum from its n//2+1 half (C2R input)
+    along ``axis``. The tail is reversed with a gather: a reverse op
+    reading an FFT's output went wrong on a TPU v5e (``ops/fft.
+    _reversed_last``)."""
+    tail = jnp.conj(lax.slice_in_dim(c, 1, (n + 1) // 2, axis=axis))
+    tail = jnp.take(tail, np.arange(tail.shape[axis] - 1, -1, -1), axis=axis)
+    return jnp.concatenate([c, tail], axis=axis)
 
 
 def _fit_axis(c, axis: int, n: int):
@@ -538,40 +693,30 @@ def _scaled(y, s: float):
 
 def fft(x, axis: int, norm: FFTNorm = FFTNorm.NONE):
     cdt = np.complex128 if _is_double(x.dtype) else np.complex64
-    x = jnp.moveaxis(x.astype(cdt), axis, -1)
-    y = _scaled(_fft_last(x, False), _fwd_scale(x.shape[-1], norm))
-    return jnp.moveaxis(y, -1, axis)
+    axis %= x.ndim
+    y = _fft_axis(x.astype(cdt), axis, False)
+    return _scaled(y, _fwd_scale(x.shape[axis], norm))
 
 
 def ifft(x, axis: int, norm: FFTNorm = FFTNorm.NONE):
     cdt = np.complex128 if _is_double(x.dtype) else np.complex64
-    x = jnp.moveaxis(x.astype(cdt), axis, -1)
-    y = _scaled(_fft_last(x, True), _inv_scale(x.shape[-1], norm))
-    return jnp.moveaxis(y, -1, axis)
+    axis %= x.ndim
+    y = _fft_axis(x.astype(cdt), axis, True)
+    return _scaled(y, _inv_scale(x.shape[axis], norm))
 
 
 def rfft(x, axis: int, norm: FFTNorm = FFTNorm.NONE):
-    x = jnp.moveaxis(x, axis, -1)
-    y = _scaled(_rfft_last(x), _fwd_scale(x.shape[-1], norm))
-    return jnp.moveaxis(y, -1, axis)
+    axis %= x.ndim
+    return _scaled(_rfft_axis(x, axis), _fwd_scale(x.shape[axis], norm))
 
 
 def irfft(x, n: int, axis: int, norm: FFTNorm = FFTNorm.NONE):
     cdt = np.complex128 if _is_double(x.dtype) else np.complex64
-    c = jnp.moveaxis(x.astype(cdt), axis, -1)
+    axis %= x.ndim
     # jnp.fft.irfft contract: the spectral axis is cropped/zero-padded to
     # n//2+1 before inversion.
-    c = _fit_axis(c, -1, n // 2 + 1)
-    if n <= current_settings().direct_max:
-        dbl = _is_double(c.dtype)
-        CR, CI = _c2r_np(n, dbl)
-        prec = _prec_for(c.dtype)
-        y = (jnp.matmul(jnp.real(c), jnp.asarray(CR), precision=prec)
-             - jnp.matmul(jnp.imag(c), jnp.asarray(CI), precision=prec))
-    else:
-        full = _hermitian_extend(c, n)
-        y = jnp.real(_fft_last(full, True))
-    return jnp.moveaxis(_scaled(y, _inv_scale(n, norm)), -1, axis)
+    c = _fit_axis(x.astype(cdt), axis, n // 2 + 1)
+    return _scaled(_irfft_axis(c, n, axis), _inv_scale(n, norm))
 
 
 def fftn(x, axes: Sequence[int], norm: FFTNorm = FFTNorm.NONE):

@@ -401,7 +401,15 @@ class Config:
     opt only changes benchmark labeling and the internal einsum order.
     ``cuda_aware`` is accepted for CLI compatibility; device-resident
     collectives are always on for TPU.
-    ``fft_backend`` selects the local-transform implementation: ``"xla"``
+    ``fft_backend`` selects the local-transform implementation. Left
+    unset (None), plan construction picks it from what the plan can
+    observe (``utils/wisdom.platform_default``): on a TPU, for float32
+    and 5-smooth extents, ``"matmul"`` at ``mxu_precision="highest"``
+    (float32 products, float32 accumulation) with the extent's
+    ``mxu_direct_max`` from ``ops/mxu_fft.DIRECT_MAX_BY_EXTENT``;
+    everywhere else (CPU, GPU, ``double_prec``, a non-smooth axis, an
+    extent past the table) ``"xla"``. A built plan's ``config.fft_backend`` is always concrete.
+    Named explicitly it is one of ``"xla"``
     (XLA's FFT expansion), ``"matmul"`` (MXU four-step DFT matmuls,
     ``ops/mxu_fft.py``), ``"matmul-r2"`` (same with radix-2 DIF splitting
     down to MXU-depth matmuls), ``"pallas"`` (Pallas kernels fusing the
@@ -506,15 +514,14 @@ class Config:
     knob is tri-state: None defers PER KNOB to the deprecated
     ``mxu_fft.set_*`` process defaults; an explicit value wins.
     ``mxu_precision`` is the single-precision DFT-matmul MXU precision:
-    "default" (raw bf16), "high" (the measured accuracy/speed sweet spot
-    on v5e — also the process default), or "highest"; f64 always runs
+    "default" (raw bf16), "high" (three bf16 passes; the process default
+    of an explicitly named matmul backend), or "highest" (float32
+    products; what the platform default resolves to); f64 always runs
     HIGHEST. ``mxu_direct_max`` is the direct-plan threshold: axes up to
     this length are one dense contraction, longer axes take the
-    four-step factorization — on a v5e at 1024^3 the all-direct plan
-    (``mxu_direct_max=1024``) once beat the default four-step 2.9x (a
-    pre-PR-12 row, not measured on the current code;
-    ``autotune_local_fft`` races it automatically past the default
-    threshold).
+    four-step factorization with the largest factor not above it on the
+    MXU (``ops/mxu_fft._split_for``); the platform default sets it per
+    extent, and ``autotune_local_fft`` races it past the default.
     """
 
     comm_method: CommMethod = CommMethod.ALL2ALL
@@ -528,7 +535,7 @@ class Config:
     double_prec: bool = False
     norm: FFTNorm = FFTNorm.NONE
     benchmark_dir: str = "benchmarks"
-    fft_backend: str = "xla"
+    fft_backend: Optional[str] = None
     mxu_precision: Optional[str] = None
     mxu_karatsuba: Optional[bool] = None
     mxu_fourstep_einsum: Optional[bool] = None
@@ -546,7 +553,7 @@ class Config:
 
     def __post_init__(self):
         from .ops.fft import validate_backend  # lazy: ops.fft imports params
-        if self.fft_backend != AUTO:
+        if self.fft_backend not in (None, AUTO):
             validate_backend(self.fft_backend)
         if not (isinstance(self.comm_method, CommMethod)
                 or self.comm_method == AUTO):

@@ -97,7 +97,8 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--comm-method", "-comm", default="All2All",
                     help="comm method of served plans (shard=x)")
     ap.add_argument("--opt", "-o", type=int, default=0, choices=(0, 1))
-    ap.add_argument("--fft-backend", default="xla")
+    ap.add_argument("--fft-backend", default=None,
+                    help="local FFT backend (default: the platform's)")
     ap.add_argument("--wisdom", default=None, metavar="PATH")
     ap.add_argument("--no-wisdom", action="store_true")
     ap.add_argument("--emulate-devices", type=int,

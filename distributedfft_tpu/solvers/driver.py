@@ -58,7 +58,8 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--viscosity", type=float, default=1e-2)
     ap.add_argument("--double", "-d", action="store_true",
                     help="f64 state (enables jax x64)")
-    ap.add_argument("--fft-backend", default="xla")
+    ap.add_argument("--fft-backend", default=None,
+                    help="local FFT backend (default: the platform's)")
     ap.add_argument("--step-interval-ms", type=float, default=0.0,
                     help="pause between steps (chaos drills use this to "
                          "widen the SIGTERM window)")

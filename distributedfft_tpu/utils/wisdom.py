@@ -912,11 +912,59 @@ def resolve_local_backend(shape: Sequence[int], double_prec: bool = False,
 # ---------------------------------------------------------------------------
 
 def unresolved(config: Any) -> bool:
-    """True when the Config still carries an 'auto' the engines should have
-    resolved at plan construction."""
+    """True when the Config still carries an 'auto' (or an unset local-FFT
+    backend) the engines should have resolved at plan construction."""
     from .. import params as pm
-    return pm.AUTO in (config.fft_backend, config.comm_method,
-                       config.comm_method2, config.wire_dtype)
+    return config.fft_backend is None or pm.AUTO in (
+        config.fft_backend, config.comm_method, config.comm_method2,
+        config.wire_dtype)
+
+
+def platform_default(config: Any, global_size: Any, mesh: Any = None,
+                     dims: int = 3) -> Tuple[Dict[str, Any], str]:
+    """``(fields, reason)``: the local-FFT Config fields for a Config that
+    names no backend, read from what the plan can observe — the
+    platform, the dtype and the transformed extents (the last ``dims``
+    slots of ``global_size``; batched2d's batch slot is not one). The
+    platform is the mesh devices' (a described topology counts), else
+    JAX's default backend.
+
+    On a TPU, float32 with every extent 5-smooth and the largest in
+    ``mxu_fft.DIRECT_MAX_BY_EXTENT``: ``"matmul"`` at
+    ``mxu_precision="highest"`` (float32 products and accumulation; the
+    bf16 passes of DEFAULT/HIGH are not float32 arithmetic) and that
+    extent's ``mxu_direct_max``; an explicit precision or threshold is
+    kept. Everywhere else ``"xla"``: XLA's FFT on CPU/GPU, no f64 matmul
+    on the MXU, a non-smooth axis leaves the matmul fast path
+    (``models.base.notice_axis_smoothness``), and past the table XLA's
+    FFT measured faster."""
+    from ..ops import mxu_fft
+    from ..ops.bluestein import is_smooth
+    if mesh is not None:
+        platform = next(iter(mesh.devices.flat)).platform
+    else:
+        import jax
+        platform = jax.default_backend()
+    extents = [int(n) for n in tuple(global_size.shape)[-dims:]]
+    xla = {"fft_backend": "xla"}
+    if platform != "tpu":
+        return xla, f"platform default: {platform}"
+    if config.double_prec:
+        return xla, "platform default: tpu, float64"
+    rough = sorted({n for n in extents if not is_smooth(n)})
+    if rough:
+        return xla, f"platform default: tpu, float32, non-smooth {rough}"
+    direct_max = mxu_fft.direct_max_for(max(extents))
+    if direct_max is None:
+        return xla, (f"platform default: tpu, float32, extent "
+                     f"{max(extents)} past the MXU table")
+    fields = {"fft_backend": "matmul"}
+    if config.mxu_precision is None:
+        fields["mxu_precision"] = "highest"
+    if config.mxu_direct_max is None:
+        fields["mxu_direct_max"] = direct_max
+    return fields, "platform default: tpu, float32, smooth"
+
 
 
 def _race_shape(kind: str, global_size: Any, partition: Any,
@@ -1234,12 +1282,21 @@ def resolve_config(kind: str, global_size: Any, partition: Any,
     hit -> reuse silently; miss -> bounded race (accuracy-gated by the
     underlying autotuners; the wire race additionally by
     ``wire_error_budget``) and record; no usable store -> race without
-    recording. Configs without an 'auto' marker pass through untouched —
+    recording. An unset ``fft_backend`` takes ``platform_default``,
+    counted as ``plan.backend_default[backend=,direct_max=,precision=]``.
+    Configs without an 'auto' marker pass through untouched otherwise —
     the zero-cost common case every plan constructor calls. A wire "auto"
     rides the comm race (one record) when comm is "auto" too, and runs
     the dedicated wire-only race (``wire`` slot) when comm is explicit."""
     from .. import params as pm
     cfg = config if config is not None else pm.Config()
+    if cfg.fft_backend is None:
+        import dataclasses as dc
+        fields, _ = platform_default(cfg, global_size, mesh, dims)
+        cfg = dc.replace(cfg, **fields)
+        obs.metrics.inc(obs.metrics.labeled(
+            "plan.backend_default", backend=cfg.fft_backend,
+            precision=cfg.mxu_precision, direct_max=cfg.mxu_direct_max))
     wants_fft = cfg.fft_backend == pm.AUTO
     wants_comm = pm.AUTO in (cfg.comm_method, cfg.comm_method2)
     wants_wire = cfg.wire_dtype == pm.AUTO
@@ -1286,12 +1343,19 @@ def peek_config(kind: str, global_size: Any, partition: Any,
 
     ``provenance`` = ``{"store_path", "store_version" (on-disk, pre-
     migration, None when absent), "key", "slots": {slot: {"status":
-    "hit"|"miss"|"not consulted (...)", "reason", "record"}}}``. Slots
-    appear only for Config fields that were actually ``"auto"``."""
+    "hit"|"miss"|"not consulted (...)", "reason", "record"}},
+    "backend_default": the ``platform_default`` reason, or None when the
+    Config named its backend}``. Slots appear only for Config fields that
+    were actually ``"auto"``."""
     import dataclasses as dc
 
     from .. import params as pm
     cfg = config if config is not None else pm.Config()
+    default_reason = None
+    if cfg.fft_backend is None:
+        fields, default_reason = platform_default(cfg, global_size, mesh,
+                                                  dims)
+        cfg = dc.replace(cfg, **fields)
     store = store_for_config(cfg)
     key = plan_key(kind, global_size.shape, cfg.double_prec, partition,
                    cfg.norm, transform=transform, sequence=sequence,
@@ -1299,7 +1363,7 @@ def peek_config(kind: str, global_size: Any, partition: Any,
                    mesh_shape=_mesh_shape_of(mesh, partition), dims=dims)
     prov = {"store_path": store.path if store else None,
             "store_version": store.raw_version() if store else None,
-            "key": key, "slots": {}}
+            "key": key, "slots": {}, "backend_default": default_reason}
     wants_fft = cfg.fft_backend == pm.AUTO
     wants_comm = pm.AUTO in (cfg.comm_method, cfg.comm_method2)
     wants_wire = cfg.wire_dtype == pm.AUTO

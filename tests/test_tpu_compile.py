@@ -82,12 +82,47 @@ def test_fused_wire_decode_fft_compiles_at_1024(one_chip):
     _check(compiled, "tpu_custom_call")
 
 
-def test_slab4_1024_forward_compiles(topo):
+def _slab4_1024(topo, direction, config):
+    """The slab 1024^3 program of one direction on the described 2x2:
+    the plan (its config resolved on the mesh's platform) and the
+    compiled program, traced with 64-bit types off as on the chip (the
+    xla C2R's twiddle is computed in complex128 under x64, which the TPU
+    compiler refuses)."""
     mesh = Mesh(np.array(topo.devices), ("p",))
-    plan = dfft.SlabFFTPlan(dfft.GlobalSize(1024, 1024, 1024),
-                            dfft.SlabPartition(4), dfft.Config(), mesh=mesh)
-    aval = hloscan._input_aval(plan, "forward")
-    x = jax.ShapeDtypeStruct(aval.shape, aval.dtype,
-                             sharding=plan.input_sharding)
-    compiled = hloscan._builder(plan, "forward").lower(x).compile()
+    with jax.enable_x64(False):
+        plan = dfft.SlabFFTPlan(dfft.GlobalSize(1024, 1024, 1024),
+                                dfft.SlabPartition(4), config, mesh=mesh)
+        aval = hloscan._input_aval(plan, direction)
+        sharding = (plan.input_sharding if direction == "forward"
+                    else plan.output_sharding)
+        x = jax.ShapeDtypeStruct(aval.shape, aval.dtype, sharding=sharding)
+        return plan, hloscan._builder(plan, direction).lower(x).compile()
+
+
+def _bytes(compiled):
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+
+
+def _default_within_xla(topo, direction):
+    """The default Config on a (described) TPU resolves to the MXU
+    matmuls at HIGHEST, compiles with the all-to-all, and holds no more
+    argument + output + temporary bytes per device than the explicit xla
+    program of the same direction."""
+    plan, compiled = _slab4_1024(topo, direction, dfft.Config())
+    assert plan.config.fft_backend == "matmul"
+    assert plan.config.mxu_precision == "highest"
     _check(compiled, "all-to-all")
+    _, xla = _slab4_1024(topo, direction, dfft.Config(fft_backend="xla"))
+    assert _bytes(compiled) <= _bytes(xla), (
+        f"{direction}: default {_bytes(compiled) / 1e9:.4f} GB > "
+        f"xla {_bytes(xla) / 1e9:.4f} GB per device")
+
+
+def test_slab4_1024_forward_compiles(topo):
+    _default_within_xla(topo, "forward")
+
+
+def test_slab4_1024_inverse_compiles(topo):
+    _default_within_xla(topo, "inverse")

@@ -503,8 +503,12 @@ def test_wire_auto_single_device_resolves_native(tmp_path):
 def test_unresolved_wire_auto_rejected_by_base_plan():
     """A Config still carrying wire 'auto' must never reach a plan body
     (the DistFFTPlan constructor guard extends to the wire axis)."""
-    assert wisdom.unresolved(dfft.Config(wire_dtype="auto"))
-    assert not wisdom.unresolved(dfft.Config(wire_dtype="bf16"))
+    assert wisdom.unresolved(dfft.Config(wire_dtype="auto",
+                                         fft_backend="xla"))
+    # The local-FFT backend named: an unset one is unresolved too (the
+    # platform default is settled at construction).
+    assert not wisdom.unresolved(dfft.Config(wire_dtype="bf16",
+                                             fft_backend="xla"))
 
 
 # ---------------------------------------------------------------------------

@@ -278,7 +278,10 @@ def test_concurrent_fresh_process_writers(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_concrete_config_passes_through_untouched():
-    cfg = dfft.Config()
+    # Concrete: every measured field and the backend named (a Config()
+    # leaves the backend to the platform default, tests/
+    # test_platform_default.py).
+    cfg = dfft.Config(fft_backend="xla")
     out = wisdom.resolve_config("slab", dfft.GlobalSize(8, 8, 8),
                                 pm.SlabPartition(1), cfg)
     assert out is cfg  # the zero-cost common case: no store I/O, no copy

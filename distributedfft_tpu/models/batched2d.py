@@ -56,12 +56,11 @@ class Batched2DFFTPlan:
         chunks of THIS SIZE via ``lax.map`` instead of one fused program
         (``batch_chunk=1`` = per-plane slices, the most chunked form;
         ``None``/0 = whole stack fused). Caps the peak intermediate
-        footprint and the compiled program size — and at large plane
-        sizes the finest slices are also the fastest: the 2026-07-31
-        on-chip sweep at 4096^2 x 64 measured 483 ms roundtrip at
-        ``batch_chunk=1`` vs 542/610/609 ms at 2/4/8 (the whole-stack
-        fused program itself was not measured on-chip; its 2026-07-30
-        attempt failed remote compile). Only meaningful when
+        footprint and the compiled program size. On one v5e at
+        4096^2 x 48 (float32, xla local FFT, input and spectrum live
+        through the C2R) a roundtrip took 424 ms at ``batch_chunk=1``,
+        495 at 2 and 556 at 4; the fused R2C does not fit (PERF.md,
+        cell ``batched2d4096.roundtrip``). Only meaningful when
         the batch axis is a pure batch dimension (``shard='batch'`` or the
         single-process fallback); must divide the (local, padded) batch."""
         if shard not in ("batch", "x"):
@@ -313,7 +312,12 @@ class Batched2DFFTPlan:
 
     def _chunked(self, base):
         """Wrap a whole-(local-)batch transform in a sequential ``lax.map``
-        over ``batch_chunk``-sized slices (see __init__)."""
+        over ``batch_chunk``-sized slices (see __init__). The loop sits
+        under the stage scope ``dfft/batched2d/chunk:1``, which no graph
+        node declares: the transform's ops keep their innermost
+        ``local_fft:1.ax<k>`` scopes, so only the per-chunk slices, the
+        writes of each answer into the stack and the loop itself carry
+        it (metadata only)."""
         ck = self.batch_chunk
         if not ck:
             return base
@@ -321,9 +325,10 @@ class Batched2DFFTPlan:
         def fn(x):
             if x.shape[0] <= ck:
                 return base(x)
-            xs = x.reshape((x.shape[0] // ck, ck) + x.shape[1:])
-            ys = jax.lax.map(base, xs)
-            return ys.reshape((x.shape[0],) + ys.shape[2:])
+            with obs.profile.stage_scope("batched2d", "chunk:1"):
+                xs = x.reshape((x.shape[0] // ck, ck) + x.shape[1:])
+                ys = jax.lax.map(base, xs)
+                return ys.reshape((x.shape[0],) + ys.shape[2:])
 
         return fn
 

@@ -1,5 +1,7 @@
 """Batched distributed 2D FFT plan (BASELINE config #4 workload)."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -68,8 +70,8 @@ def test_validation(devices):
 
 class TestBatchChunk:
     """batch_chunk: sequential lax.map over batch slices — caps peak
-    intermediate memory and compiled-program size (the 4096^2 x 64 stack
-    as one program was never compiled on a chip)."""
+    intermediate memory and compiled-program size (the whole-stack R2C of
+    4096^2 x 64 does not fit one v5e)."""
 
     def test_chunked_matches_unchunked(self, devices, rng):
         x = rng.random((8, 16, 16)).astype(np.float32)
@@ -104,6 +106,72 @@ class TestBatchChunk:
         # error (the CLI/bench '0 disables chunking' convention).
         plan = Batched2DFFTPlan(8, 16, 16, SlabPartition(1), batch_chunk=0)
         assert plan.batch_chunk is None
+
+
+def _rel_l2(got, ref):
+    got, ref = np.asarray(got), np.asarray(ref)
+    return np.linalg.norm(got - ref) / np.linalg.norm(ref)
+
+
+def _chunked_plan(batch_chunk):
+    return Batched2DFFTPlan(8, 64, 64, SlabPartition(1), Config(),
+                            batch_chunk=batch_chunk)
+
+
+@pytest.mark.parametrize("batch_chunk", [1, 2])
+def test_chunked_one_device_matches_float64(rng, batch_chunk):
+    """The one-chip chunked stack (the benchmark's batched-2D cell, at
+    8 x 64 x 64): the float32 R2C against numpy's float64 ``rfft2``, and
+    the C2R of that float64 spectrum against its unnormalised
+    ``irfft2``."""
+    plan = _chunked_plan(batch_chunk)
+    x = rng.uniform(-1.0, 1.0, (8, 64, 64))
+    c = plan.exec_forward(x.astype(np.float32))
+    assert c.dtype == np.complex64
+    ref = np.fft.rfft2(x)
+    assert _rel_l2(c, ref) < 1e-5
+    r = plan.exec_inverse(ref.astype(np.complex64))
+    assert _rel_l2(r, np.fft.irfft2(ref, s=(64, 64)) * 64 * 64) < 1e-5
+
+
+CHUNK_SCOPE = "dfft/batched2d/chunk:1"
+
+
+@pytest.mark.parametrize("direction", ["forward", "inverse"])
+def test_chunk_loop_carries_its_scope(direction):
+    """The chunk loop's ops carry ``dfft/batched2d/chunk:1``; the
+    transform inside it keeps its per-axis ``local_fft:1`` scopes; a
+    fused plan has no chunk scope."""
+    from distributedfft_tpu.analysis import hloscan
+    txt = hloscan.compiled_text(_chunked_plan(2), direction, 2)
+    assert CHUNK_SCOPE in txt
+    assert set(re.findall(r"dfft/batched2d/local_fft:1\.ax\d", txt)) == {
+        "dfft/batched2d/local_fft:1.ax1", "dfft/batched2d/local_fft:1.ax2"}
+    fused = hloscan.compiled_text(_chunked_plan(None), direction, 2)
+    assert CHUNK_SCOPE not in fused
+
+
+@pytest.mark.parametrize("direction", ["forward", "inverse"])
+def test_chunk_scope_is_metadata_only(direction):
+    """Scopes on and off compile the same op graph once the metadata is
+    stripped."""
+    from distributedfft_tpu.analysis import hloscan
+    from distributedfft_tpu.obs import profile
+    on = hloscan.compiled_text(_chunked_plan(1), direction, 2)
+    with profile.scopes_off():
+        off = hloscan.compiled_text(_chunked_plan(1), direction, 2)
+    assert CHUNK_SCOPE in on and CHUNK_SCOPE not in off
+    assert hloscan.strip_metadata(on) == hloscan.strip_metadata(off)
+
+
+@pytest.mark.parametrize("direction", ["forward", "inverse"])
+def test_chunked_plan_passes_graph_verification(direction):
+    """``dfft-verify``'s graph, contract, trace and scope passes and its
+    jaxpr lints accept the chunk scope, which no graph node declares."""
+    from distributedfft_tpu.analysis import jaxprlint, plangraph
+    plan = _chunked_plan(2)
+    assert plangraph.verify_graph(plan, direction, 2) == []
+    assert jaxprlint.lint_plan(plan, direction, 2) == []
 
 
 class TestHarnessWiring:

@@ -27,6 +27,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .. import obs
@@ -42,6 +43,13 @@ from ..utils import wisdom
 from .base import (_with_pad, jit_stages, local_fft_fields,
                    notice_axis_smoothness)
 
+# A TPU's float32 tile is 8 sublanes by 128 lanes. The chunk loop's
+# group buffers (``_map_in_tile_groups``) keep their images outermost, the
+# spectrum's rows in the sublanes and x in the lanes, so that each image
+# fills whole tiles.
+_SUBLANES = 8
+_BATCH_MAJOR = Layout(major_to_minor=(0, 2, 1))
+
 
 class Batched2DFFTPlan:
     """Distributed batched 2D R2C/C2R (or C2C) FFT over a 1D mesh."""
@@ -53,7 +61,7 @@ class Batched2DFFTPlan:
                  shard: str = "batch", transform: str = "r2c",
                  batch_chunk: Optional[int] = None):
         """``batch_chunk``: transform the (per-device) batch in sequential
-        chunks of THIS SIZE via ``lax.map`` instead of one fused program
+        chunks of THIS SIZE in a loop instead of one fused program
         (``batch_chunk=1`` = per-plane slices, the most chunked form;
         ``None``/0 = whole stack fused). Caps the peak intermediate
         footprint and the compiled program size. On one v5e at
@@ -62,7 +70,13 @@ class Batched2DFFTPlan:
         495 at 2 and 556 at 4; the fused R2C does not fit (PERF.md,
         cell ``batched2d4096.roundtrip``). Only meaningful when
         the batch axis is a pure batch dimension (``shard='batch'`` or the
-        single-process fallback); must divide the (local, padded) batch."""
+        single-process fallback); must divide the (local, padded) batch.
+
+        On a TPU the float32 forward with a chunk that divides 8 and a
+        local batch that 8 divides writes its answers into the stack in
+        groups of 8 images (``chunk_group``), so that every write fills
+        whole (8,128) tiles; see ``_chunked``. That took the roundtrip
+        above from 423.4 ms to 331-333 (PERF.md)."""
         if shard not in ("batch", "x"):
             raise ValueError(f"shard must be 'batch' or 'x', got {shard!r}")
         if transform not in ("r2c", "c2c"):
@@ -115,6 +129,7 @@ class Batched2DFFTPlan:
             self._in_spec = PartitionSpec(None, SLAB_AXIS, None)
             self._out_spec = PartitionSpec(None, None, SLAB_AXIS)
         self.batch_chunk = batch_chunk
+        local_b = self._batch_pad if self.fft3d else self._batch_pad // P
         if batch_chunk is not None:
             if batch_chunk <= 0:
                 raise ValueError("batch_chunk must be positive")
@@ -123,7 +138,6 @@ class Batched2DFFTPlan:
                                  "the single-process fallback): with "
                                  "shard='x' the batch axis is not chunkable "
                                  "independently of the collectives")
-            local_b = self._batch_pad if self.fft3d else self._batch_pad // P
             if local_b % batch_chunk:
                 raise ValueError(
                     f"batch_chunk {batch_chunk} must divide the local "
@@ -134,10 +148,16 @@ class Batched2DFFTPlan:
         self._inv_unguarded = None
         self._fwd_pure = None
         self._inv_pure = None
+        self.chunk_group = None
+        if (batch_chunk and _SUBLANES % batch_chunk == 0
+                and batch_chunk < local_b and local_b % _SUBLANES == 0
+                and not self.config.double_prec
+                and wisdom.plan_platform(mesh) == "tpu"):
+            self.chunk_group = _SUBLANES
         notice_axis_smoothness("batched2d", (nx, ny), self.config)
         obs.event("plan.created", kind="batched2d", shard=shard,
                   transform=transform, shape=[batch, nx, ny], ranks=P,
-                  batch_chunk=batch_chunk,
+                  batch_chunk=batch_chunk, chunk_group=self.chunk_group,
                   comm=self.config.comm_method.value,
                   send=self.config.send_method.value, opt=self.config.opt,
                   wire=self.config.wire_dtype,
@@ -310,14 +330,27 @@ class Batched2DFFTPlan:
             return lf.ifft(c, axis=2, norm=norm, backend=be, settings=st)
         return lf.irfft(c, n=self.ny, axis=2, norm=norm, backend=be, settings=st)
 
-    def _chunked(self, base):
+    def _chunked(self, base, group: Optional[int] = None):
         """Wrap a whole-(local-)batch transform in a sequential ``lax.map``
         over ``batch_chunk``-sized slices (see __init__). The loop sits
         under the stage scope ``dfft/batched2d/chunk:1``, which no graph
         node declares: the transform's ops keep their innermost
         ``local_fft:1.ax<k>`` scopes, so only the per-chunk slices, the
         writes of each answer into the stack and the loop itself carry
-        it (metadata only)."""
+        it (metadata only).
+
+        ``group`` (``chunk_group``: the forward on a TPU, ``_build_pure``)
+        maps over groups of that many images instead, with a complex64
+        answer, see ``_map_in_tile_groups``. Why: the TPU compiler lays
+        the stack out as the program's result, with the batch in the
+        sublanes (a 48 x 4096^2 R2C's stack was
+        ``f32[48,1,4096,2049]{2,0,3,1}``: 2049 is no multiple of 128, 48
+        is one of 8), so writing one image touched one sublane of every
+        (8,128) tile, 1.11 ms an image-plane on a v5e where its bytes
+        need 0.082 (PERF.md). A batch-major result would avoid that, but
+        a layout other than the default at a program's boundary does not
+        survive JAX's persistent compile cache: a consumer loaded from it
+        expects the default layout and refuses the buffer."""
         ck = self.batch_chunk
         if not ck:
             return base
@@ -326,6 +359,10 @@ class Batched2DFFTPlan:
             if x.shape[0] <= ck:
                 return base(x)
             with obs.profile.stage_scope("batched2d", "chunk:1"):
+                if group:
+                    ans = jax.eval_shape(base, x[:ck])
+                    if ans.dtype == jnp.complex64:
+                        return _map_in_tile_groups(base, x, ck, group, ans)
                 xs = x.reshape((x.shape[0] // ck, ck) + x.shape[1:])
                 ys = jax.lax.map(base, xs)
                 return ys.reshape((x.shape[0],) + ys.shape[2:])
@@ -337,7 +374,8 @@ class Batched2DFFTPlan:
                       direction="forward" if forward else "inverse"):
             from ..resilience import guards
             direction = "forward" if forward else "inverse"
-            pure, in_spec, out_spec = self._build_pure(forward)
+            pure, in_spec, out_spec = self._build_pure(
+                forward, self.chunk_group if forward else None)
             guarded = False
             if guard:
                 pure, guarded = guards.maybe_wrap(self, pure, direction,
@@ -351,15 +389,17 @@ class Batched2DFFTPlan:
                            in_shardings=NamedSharding(self.mesh, in_spec),
                            out_shardings=outsh)
 
-    def _build_pure(self, forward: bool):
+    def _build_pure(self, forward: bool, group: Optional[int] = None):
         """(pure_fn, in_spec, out_spec) — the specs travel with the
-        composition so the jit wrapper cannot drift from the shard_map."""
+        composition so the jit wrapper cannot drift from the shard_map.
+        ``group``: see ``_chunked``; the jit that ``_build`` makes passes
+        it, the pure composition (``forward_fn``) does not."""
         if self.fft3d or self.shard == "batch":
             # Stage scope (obs/profile.py): the collective-free graph's
             # one local_fft node covers the whole per-plane 2D transform.
             fn = self._chunked(obs.profile.scoped(
                 "batched2d", "local_fft:1",
-                lambda x: self._fft2(x, forward)))
+                lambda x: self._fft2(x, forward)), group)
             if self.mesh is None:
                 return fn, PartitionSpec(), PartitionSpec()
             return (jax.shard_map(fn, mesh=self.mesh, in_specs=self._in_spec,
@@ -616,6 +656,34 @@ class Batched2DFFTPlan:
             [("1D FFT X-Direction", first, self._out_spec, self._out_spec),
              (self._xpose_desc(), xpose, self._out_spec, self._in_spec),
              ("1D FFT Y-Direction", last, self._in_spec, self._in_spec)])
+
+
+def _map_in_tile_groups(base, x, ck, group, ans):
+    """``base`` over chunks of ``ck`` images of ``x``, as ``lax.map``, in
+    groups of ``group`` images: a group's answers (each ``ans``, complex64
+    ``(ck, nx, nys)``) are written into real and imaginary float32
+    buffers laid out ``_BATCH_MAJOR``, and the finished group goes into
+    the stack in one write, which fills whole tiles of the stack's
+    default layout (the group fills the sublanes). The layout constraint
+    is on the float32 planes: the TPU compiler refuses one on complex64."""
+    n = x.shape[0]
+
+    def one_group(g):
+        # Zeros like the input, so that under shard_map they vary as it does.
+        zeros = jax.lax.full_like(x, 0, jnp.float32, (group,) + ans.shape[1:])
+
+        def one_chunk(i, planes):
+            y = base(jax.lax.dynamic_slice_in_dim(x, g * group + i * ck, ck))
+            return tuple(
+                with_layout_constraint(jax.lax.dynamic_update_slice_in_dim(
+                    plane, part, i * ck, 0), _BATCH_MAJOR)
+                for plane, part in zip(planes, (y.real, y.imag)))
+
+        return jax.lax.fori_loop(0, group // ck, one_chunk, (zeros, zeros))
+
+    re, im = jax.lax.map(one_group, jnp.arange(n // group))
+    return jax.lax.complex(re, im).reshape((n,) + ans.shape[1:])
+
 
 # ---------------------------------------------------------------------------
 # contract declaration (analysis/contracts.py) — the exchange this family

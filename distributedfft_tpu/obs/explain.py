@@ -72,6 +72,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="slab sequence")
     ap.add_argument("--shard", default="batch", choices=("batch", "x"),
                     help="batched2d decomposed axis")
+    ap.add_argument("--batch-chunk", type=int, default=0,
+                    help="batched2d batch_chunk (shard=batch only; 0 = "
+                         "whole stack fused)")
     ap.add_argument("--fft-dim", "-f", type=int, default=3,
                     choices=(1, 2, 3), help="pencil partial-transform depth")
     ap.add_argument("--comm-method", "-comm", "-comm1", dest="comm_method",
@@ -620,7 +623,8 @@ def main(argv=None) -> int:
         if kind == "batched":
             from ..models.batched2d import Batched2DFFTPlan
             plan = Batched2DFFTPlan(nz, nx, ny, partition, cfg,
-                                    shard=args.shard, transform=transform)
+                                    shard=args.shard, transform=transform,
+                                    batch_chunk=args.batch_chunk)
         else:
             plan = tc.make_plan(mk_kind, g, partition, cfg,
                                 sequence=args.sequence, transform=transform,
@@ -705,6 +709,8 @@ def main(argv=None) -> int:
             else:
                 out.append("  stage 2: C2C x (per plane; batch sharding "
                            "issues no collectives)")
+            out.append(f"  batch_chunk: {plan.batch_chunk}  chunk_group: "
+                       f"{plan.chunk_group}")
 
         out.append("rendering:")
         if ranks == 1 or (kind == "batched" and args.shard == "batch"):

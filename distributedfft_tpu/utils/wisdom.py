@@ -920,6 +920,15 @@ def unresolved(config: Any) -> bool:
         config.wire_dtype)
 
 
+def plan_platform(mesh: Any = None) -> str:
+    """The platform a plan's programs run on: the mesh devices' (a
+    described topology counts), else JAX's default backend."""
+    if mesh is not None:
+        return next(iter(mesh.devices.flat)).platform
+    import jax
+    return jax.default_backend()
+
+
 def platform_default(config: Any, global_size: Any, mesh: Any = None,
                      dims: int = 3) -> Tuple[Dict[str, Any], str]:
     """``(fields, reason)``: the local-FFT Config fields for a Config that
@@ -940,11 +949,7 @@ def platform_default(config: Any, global_size: Any, mesh: Any = None,
     FFT measured faster."""
     from ..ops import mxu_fft
     from ..ops.bluestein import is_smooth
-    if mesh is not None:
-        platform = next(iter(mesh.devices.flat)).platform
-    else:
-        import jax
-        platform = jax.default_backend()
+    platform = plan_platform(mesh)
     extents = [int(n) for n in tuple(global_size.shape)[-dims:]]
     xla = {"fft_backend": "xla"}
     if platform != "tpu":

@@ -261,6 +261,16 @@ def test_explain_batched_shard_batch_no_collectives(capsys, devices):
     assert "embarrassingly parallel batch sharding" in out
     assert "no exchange -> nothing on the wire" in out
     assert "all_to_all: 0" in out
+    assert "batch_chunk: None  chunk_group: None" in out
+
+
+def test_explain_batched_chunk_shows_chunk_group(capsys, devices):
+    """The chunked plan's line names its chunk and the group of images
+    its forward writes at once (None on the CPU; 8 on a TPU)."""
+    out = _explain(["--kind", "batched", "-nx", "16", "-ny", "16",
+                    "-nz", "8", "--shard", "batch", "-p", "8",
+                    "--batch-chunk", "1"], capsys)
+    assert "batch_chunk: 1  chunk_group: None" in out
 
 
 def test_explain_wisdom_miss_never_races(tmp_path, capsys, devices,

@@ -14,6 +14,8 @@ mode with ``monkeypatch``; the persistent compile cache is off around
 the compiles (a described-chip entry cannot be read back here).
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -126,3 +128,59 @@ def test_slab4_1024_forward_compiles(topo):
 
 def test_slab4_1024_inverse_compiles(topo):
     _default_within_xla(topo, "inverse")
+
+
+def _assert_whole_tile_writes(compiled, local_batch):
+    """Every write of the chunk loop's answers into a stack fills whole
+    (8,128) tiles: where a dynamic-update-slice's layout puts a dimension
+    in the sublanes (second minor-most), the update covers that dimension
+    whole or in multiples of 8. One image written into a stack whose
+    batch sits in the sublanes does not. The result keeps the default
+    layout, which every consumer (and a cached one) expects."""
+    txt = compiled.as_text()
+    dims_of = {name: [int(d) for d in dims.split(",") if d]
+               for name, dims in re.findall(r"%(\S+) = \w+\[([\d,]*)\]",
+                                            txt)}
+    writes = re.findall(r"= f32\[([\d,]+)\]\{([\d,]+):T\([^=]*"
+                        r"dynamic-update-slice\(%[^,]+, %([^,]+),", txt)
+    stacks = 0
+    for dims, m2m, update in writes:
+        dims = [int(d) for d in dims.split(",")]
+        sub = [int(d) for d in m2m.split(",")][1]
+        n = dims_of[update][sub]
+        assert n == dims[sub] or n % 8 == 0, (
+            f"f32{dims}{{{m2m}}} written {n} of {dims[sub]} sublanes")
+        stacks += np.prod(dims[:-2]) == local_batch
+    assert stacks, "no dynamic-update-slice of the stack"
+    default = compiled.output_formats.layout.major_to_minor
+    assert default == (2, 0, 1), default
+
+
+def test_batched2d_chunk_loop_writes_whole_tiles(one_chip, monkeypatch):
+    """The one-chip chunked forward as ``_build`` makes it (the batched
+    benchmark cell's program at 16 x 1024^2): the plan reads its platform
+    as on the chip."""
+    with jax.enable_x64(False):
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            plan = dfft.Batched2DFFTPlan(16, 1024, 1024,
+                                         dfft.SlabPartition(1),
+                                         dfft.Config(), batch_chunk=1)
+        x = jax.ShapeDtypeStruct((16, 1024, 1024), jnp.float32,
+                                 sharding=one_chip)
+        compiled = plan._build(forward=True).lower(x).compile()
+    _assert_whole_tile_writes(compiled, 16)
+
+
+def test_batched2d_sharded_chunk_loop_writes_whole_tiles(topo):
+    """``shard="batch"`` over the described 2x2 (the ``shard_map`` path
+    that ``serve/`` runs): 16 images a chip."""
+    mesh = Mesh(np.array(topo.devices), ("p",))
+    with jax.enable_x64(False):
+        plan = dfft.Batched2DFFTPlan(64, 1024, 1024, dfft.SlabPartition(4),
+                                     dfft.Config(), mesh=mesh,
+                                     batch_chunk=1)
+        x = jax.ShapeDtypeStruct((64, 1024, 1024), jnp.float32,
+                                 sharding=plan.input_sharding)
+        compiled = plan._build(forward=True).lower(x).compile()
+    _assert_whole_tile_writes(compiled, 16)

@@ -44,8 +44,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--autotune-k", type=int, default=257,
                     help="chained roundtrips per timing sample; must be "
                          "large enough that the work dominates the host's "
-                         "per-call constant (257 matches bench.py at "
-                         "256^3)")
+                         "per-call constant (257 is enough at 256^3)")
     return ap
 
 
@@ -93,9 +92,10 @@ def main(argv=None) -> int:
             return 1
         print(f"best: {best.label} ({best.per_iter_ms:.3f} ms/roundtrip, "
               f"rel_err {best.rel_err:.2e})")
-        # Persist the measured winner so later runs (bench.py warm-start,
-        # --fft-backend auto plans of this shape) reuse it instead of
-        # re-racing — the explicit "tune once" entry point.
+        # Persist the measured winner so later runs (testcase 0's
+        # ``wisdom.resolve_local_backend``, --fft-backend auto plans of
+        # this shape) reuse it instead of re-racing — the explicit "tune
+        # once" entry point.
         from ..utils import wisdom
         store = wisdom.open_store(args.wisdom, not args.no_wisdom)
         if store is not None:

@@ -12,9 +12,9 @@ the zero-overhead-when-off contract is pinned by ``tests/test_obs.py``):
   ``plan.dispatch`` child around the jitted call (``tracing.py``,
   ``resilience/fallback.py``); the exchange builders open none.
 * ``obs.metrics`` — process-global counters/gauges/latency histograms
-  with dual per-plan vs cumulative views; ``bench.py`` folds the per-plan
-  ``snapshot()`` into ``BENCH_DETAILS.json``, the Prometheus exposition
-  renders the cumulative one (``metrics.py``).
+  with dual per-plan vs cumulative views; tests read the per-plan
+  ``snapshot()``, the Prometheus exposition renders the cumulative one
+  (``metrics.py``).
 * ``obs.flightrec`` — the ALWAYS-ON bounded in-memory ring of recent
   spans/events/metric deltas, dumped to JSONL on trigger
   (GuardViolation, circuit open, demotion, shed burst, SIGUSR2) — zero

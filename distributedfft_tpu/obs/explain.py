@@ -20,10 +20,11 @@ config + shape:
 * HLO collective census: the forward program is lowered and compiled
   (never executed) and ``microbench.async_collective_counts`` reports the
   collective / async-start / convert instance counts;
-* roofline expectation (``evalkit/roofline.py``): nominal FFT flops, the
-  MXU flops the matmul backend would issue, the v5e-effective-peak ideal
-  time, and the tracked ``roofline_fraction`` for this size from the
-  committed BENCH_DETAILS.json "roofline" block (ISSUE 10's gate);
+* roofline: the least work of one roundtrip per chip — flops, HBM and
+  ICI bytes, counted from the declared plan graph by
+  ``obs/profile.least_work``, the benchmark's model — and its least
+  time on this device (``profile.PEAKS``), with the Bluestein factor of
+  any non-smooth axis;
 * overlap schedule for ring-rendered exchanges (Ring / RingOverlap):
   blocks, revolving buffers, and the wire bytes in flight per device;
 * checkpoint registry (``--checkpoint-dir`` / ``$DFFT_CKPT_DIR``): the
@@ -408,19 +409,21 @@ def _serve_lines(args, kind: str, plan, cfg) -> list:
         config=cfg)
 
 
-def _roofline_lines(args, kind: str, backend: str) -> list:
-    """Roofline expectation for the explained workload (cube / batched-2D
-    only — the shapes the MAC model covers). Non-smooth axes get the
-    HONEST Bluestein accounting (padded chirp length + overhead factor)
-    instead of a silently-wrong smooth-size number."""
-    from ..evalkit import roofline as rl
-    from ..testing.workloads import flops_batched2d, flops_roundtrip_3d
-    nx, ny, nz = args.input_dim_x, args.input_dim_y, args.input_dim_z
+def _roofline_lines(plan, dims: int, tshape, backend: str) -> list:
+    """Least work of one roundtrip (forward + inverse), counted from the
+    declared plan graph by ``profile.least_work`` (the benchmark's
+    model), and its least time on this device. Non-smooth axes get the
+    Bluestein accounting (padded chirp length + flop factor), which the
+    nominal 2.5·N·log2 N count does not see."""
+    import jax
+
+    from ..analysis import plangraph
+    from ..ops import bluestein
+    from . import profile
     lines = []
-    tshape = (nx, ny) if kind == "batched" else (nx, ny, nz)
-    rough = rl.nonsmooth_axes(tshape)
+    rough = bluestein.nonsmooth_axes(tshape)
     for n in rough:
-        m, over = rl.bluestein_axis_report(n)
+        m, over = bluestein.bluestein_axis_report(n)
         lines.append(
             f"  non-smooth axis {n}: no native fast path — bluestein "
             f"chirp length {m} (padded), ~{over:.1f}x the flops of a "
@@ -432,58 +435,29 @@ def _roofline_lines(args, kind: str, backend: str) -> list:
                   else "through XLA's generic expansion")
                + " (fft_backend='bluestein' takes the chirp path)"))
     if rough:
-        lines.append("  (the nominal 2.5·N·log2 N model below assumes "
-                     "smooth axes; scale by the factors above)")
-    if kind == "batched":
-        if nx != ny:
-            return lines + ["  (batched roofline model needs square "
-                            "planes; skipped)"]
-        nominal = flops_batched2d(nz, nx, ny)
-        mxu4 = rl.mxu_flops_batched2d(nz, nx)
-        mxu3 = rl.mxu_flops_batched2d(nz, nx, complex_mults=3)
-        what = f"{nx}^2 x {nz} roundtrip"
-    elif nx == ny == nz:
-        nominal = flops_roundtrip_3d(nx)
-        mxu4 = rl.mxu_flops_roundtrip_3d(nx)
-        mxu3 = rl.mxu_flops_roundtrip_3d(nx, complex_mults=3)
-        what = f"{nx}^3 roundtrip"
-    else:
-        return lines + ["  (MXU MAC model covers cubes and square batched "
-                        "planes only; skipped for this shape)"]
-    lines.append(f"  nominal FFT flops ({what}): {nominal / 1e9:.2f} GF "
-                 "(2.5·N·log2 N per direction)")
-    lines.append(f"  matmul-backend MXU flops: {mxu3 / 1e9:.2f}-"
-                 f"{mxu4 / 1e9:.2f} GF (3mm-4mm complex-dot bracket)")
-    import jax
+        lines.append("  (the nominal flops below assume smooth axes; "
+                     "scale them by the factors above)")
+    chips = plan.mesh.devices.size if plan.mesh is not None else 1
+    shape = tuple(plan.global_size.shape)
+    try:
+        work = sum((profile.least_work(plangraph.graph_for(plan, d, dims),
+                                       shape, chips)
+                    for d in ("forward", "inverse")), profile.Work())
+    except plangraph.MissingGraph as e:
+        return lines + [f"  no declared graph ({e}): no least work"]
+    lines.append(
+        f"  one roundtrip, per chip of {chips}: {work.flops:.4g} flops "
+        f"(2.5·N·log2 N per transformed axis), HBM "
+        f"{_fmt_bytes(int(work.hbm_bytes))} (local FFTs' edges), ICI "
+        f"{_fmt_bytes(int(work.ici_bytes))} sent")
     dev_kind = jax.devices()[0].device_kind
-    peak = rl.effective_peak_tflops(dev_kind, "high")
-    if peak is None:
-        return lines + [f"  no peak for {dev_kind} (evalkit/roofline.py "
-                        "PEAKS): no ideal time"]
-    ideal_ms = mxu4 / (peak * 1e12) * 1e3
-    lines.append(f"  {dev_kind} effective peak @high: {peak:.1f} TFLOPS -> "
-                 f"ideal matmul roundtrip >= {ideal_ms:.2f} ms "
-                 "(100% MXU; backend here: " + backend + ")")
-    # Predicted roofline_fraction (ISSUE 10 gate): the fraction a
-    # measurement of this workload would score is ideal_ms/measured_ms;
-    # quote the TRACKED value from the committed BENCH_DETAILS.json
-    # "roofline" block when a row for this size exists (nothing here
-    # executes — bench.py is the measurement side of the gate).
-    key = f"{nx}^2x{nz}" if kind == "batched" else str(nx)
-    tracked = rl.tracked_fractions()
-    rec = tracked.get(key) or tracked.get(f"{key}^3")
-    if rec:
-        lines.append(
-            f"  roofline_fraction (tracked): {rec['roofline_fraction']} "
-            f"at ideal {rec['ideal_ms']} ms ({rec['model']}, "
-            f"{rec.get('mode', 'roundtrip')}; committed "
-            "BENCH_DETAILS.json — a perf PR must move this, CI fails a "
-            ">10% regression)")
-    else:
-        lines.append(
-            f"  roofline_fraction: predicted ideal/measured — ideal "
-            f"{ideal_ms:.2f} ms at the 4mm bound; no tracked row for "
-            f"{key!r} in BENCH_DETAILS.json (run bench.py to record one)")
+    peaks = profile.PEAKS.get(dev_kind)
+    if peaks is None:
+        return lines + [f"  no peaks for {dev_kind}: no least time"]
+    bounds = work.bounds_s(peaks)
+    bound = max(bounds, key=bounds.get)
+    lines.append(f"  least time on {dev_kind}: {bounds[bound] * 1e3:.4g} ms "
+                 f"(bound by {bound}; backend here: {backend})")
     return lines
 
 
@@ -803,8 +777,10 @@ def main(argv=None) -> int:
             out.append("  contract: unverified (needs the compiled module "
                        "— drop --no-compile or run dfft-verify)")
 
-        out.append("roofline (evalkit/roofline.py):")
-        out.extend(_roofline_lines(args, kind, cfg.fft_backend))
+        out.append("roofline (least work from the plan graph):")
+        out.extend(_roofline_lines(
+            plan, dims, (nx, ny) if kind == "batched" else (nx, ny, nz),
+            cfg.fft_backend))
 
         if args.profile:
             out.append("stage profile (MEASURED — jax.profiler trace of "

@@ -14,7 +14,7 @@ so the default ``snapshot()`` / ``counter_value()`` read the *per-plan*
 window (everything since the last ``reset()``), while
 ``snapshot(view="cumulative")`` / ``counter_total()`` read the raw
 process totals. The split exists because the two consumers want opposite
-things and conflating them corrupted both: tests and ``bench.py`` want a
+things and conflating them corrupted both: tests and the CLIs want a
 clean per-plan window (reset between plans), while the Prometheus
 exposition (``promexp.py``) requires monotone counters — a scrape must
 NEVER see a counter go backwards, so ``/metrics`` always renders the
@@ -248,7 +248,7 @@ def snapshot(view: str = "plan") -> Dict[str, object]:
     JSON diffs): ``{"view", "counters", "gauges", "histograms"}``.
 
     ``view="plan"`` (default) is the since-last-``reset()`` window — what
-    ``bench.py`` folds per child and tests assert on. ``"cumulative"`` is
+    the CLIs' ``--obs`` line prints and tests assert on. ``"cumulative"`` is
     the monotone process totals — what ``/metrics`` scrapes. Zero-valued
     per-plan counters are omitted (a counter untouched this plan is not
     part of this plan's story); cumulative keeps every key ever touched.

@@ -34,18 +34,18 @@ encode. This module closes that loop in three steps:
    declared graph: per-node device time, the exchange-vs-compute
    split, the unattributed remainder (dispatch, h2d, ops outside any
    scope — honesty line, never hidden), and a per-stage roofline-gap
-   row (measured vs the nominal ideal for that node's axes). GSPMD (``p2p``) exchanges stage no explicit op to
-   scope, so their collective lands in the unattributed remainder —
-   reported, not guessed.
-
-Consumers: ``dfft-explain --profile`` (the one explain mode that
-executes), the four CLIs' ``--profile-stages`` epilogue, and the bench
-mesh child's ``"stage_profile"`` block in BENCH_DETAILS.json.
+   row (measured vs the node's least time: ``least_work``, the
+   benchmark's model, over ``PEAKS``). GSPMD (``p2p``) exchanges stage
+   no explicit op to scope, so their collective lands in the
+   unattributed remainder — reported, not guessed. Consumers:
+   ``dfft-explain --profile`` (the one explain mode that executes) and
+   the four CLIs' ``--profile-stages`` epilogue.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import glob
 import gzip
 import json
@@ -637,34 +637,83 @@ def node_scope_key(graph: Any, node: Any) -> Optional[str]:
     return None
 
 
-def _node_ideal_ms(graph: Any, node: Any, ranks: int,
-                   kind: str) -> Optional[float]:
-    """Nominal ideal time of one local-FFT stage: 2.5*N*log2(extent) per
-    transformed axis over the effective peak of a ``kind`` chip, per-chip
-    share on the mesh (the roofline module's convention — communication
-    deliberately unmodeled, so exchange nodes have no ideal; their
-    measured time IS the roofline gap). None for a kind with no
-    published peak."""
-    if node.kind != "local_fft" or not node.axes:
+# Published peaks of one chip, keyed by the ``device_kind`` JAX reports
+# (Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s inter-chip interconnect). The benchmark keeps its own copy
+# (``benchmark/peaks.json``) apart on purpose; a test holds the two equal.
+# A kind not in the table has no least time.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flop_per_s": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_bytes_per_s": 200e9},
+}
+
+
+@dataclasses.dataclass(frozen=True)
+class Work:
+    """Least work of a graph, per chip: nominal FFT flops, the bytes its
+    local FFTs must move through HBM, and the bytes each chip sends over
+    ICI."""
+
+    flops: float = 0.0
+    hbm_bytes: float = 0.0
+    ici_bytes: float = 0.0
+
+    def __add__(self, other: "Work") -> "Work":
+        return Work(self.flops + other.flops,
+                    self.hbm_bytes + other.hbm_bytes,
+                    self.ici_bytes + other.ici_bytes)
+
+    def bounds_s(self, peaks: Dict[str, float]) -> Dict[str, float]:
+        """Each resource's least time: its work over its peak."""
+        return {"flops": self.flops / peaks["bf16_flop_per_s"],
+                "HBM bytes": self.hbm_bytes / peaks["hbm_bytes_per_s"],
+                "ICI bytes": self.ici_bytes / peaks["ici_bytes_per_s"]}
+
+    def least_s(self, peaks: Dict[str, float]) -> float:
+        """No resource beats its own peak, however the work overlaps."""
+        return max(self.bounds_s(peaks).values())
+
+
+def _edge_bytes(edge: Any) -> int:
+    import numpy as np
+    return int(np.prod(tuple(edge.shape), dtype=np.int64)) \
+        * np.dtype(edge.dtype).itemsize
+
+
+def least_work(graph: Any, global_shape: Tuple[int, ...],
+               chips: int) -> Work:
+    """Least work of one declared direction, per chip: per ``local_fft``
+    node 2.5*n*log2(extent) flops per transformed axis (n the logical
+    element count of ``global_shape``) plus its in- and out-edges' bytes
+    once; per ``exchange`` node (P-1)/P of its in-edge bytes over ICI.
+    Backend-blind: xla, matmul and pallas declare the same nodes."""
+    n_total = float(math.prod(global_shape))
+    flops = hbm = ici = 0.0
+    for node in graph.nodes:
+        if node.kind == "local_fft":
+            flops += sum(2.5 * n_total * math.log2(global_shape[a])
+                         for a in node.axes)
+            hbm += sum(_edge_bytes(e) for e in graph.in_edges(node.id))
+            hbm += sum(_edge_bytes(e) for e in graph.out_edges(node.id))
+        elif node.kind == "exchange":
+            p = node.axis_size
+            ici += sum(_edge_bytes(e) * (p - 1) / p
+                       for e in graph.in_edges(node.id))
+    return Work(flops / chips, hbm / chips, ici / chips)
+
+
+def _node_ideal_ms(graph: Any, node: Any, global_shape: Tuple[int, ...],
+                   chips: int, kind: str) -> Optional[float]:
+    """Least time of one declared node on a ``kind`` chip
+    (``least_work`` of the node alone); None for a node with no work
+    (input, output, wire, guard) or a kind with no peaks."""
+    peaks = PEAKS.get(kind)
+    if peaks is None:
         return None
-    from ..evalkit import roofline as rl
-    in_edges = graph.in_edges(node.id)
-    if not in_edges:
-        return None
-    shape = in_edges[0].shape
-    elems = 1
-    for s in shape:
-        elems *= int(s)
-    flops = 0.0
-    for a in node.axes:
-        if 0 <= a < len(shape) and shape[a] > 1:
-            flops += 2.5 * elems * math.log2(shape[a])
-    if flops <= 0:
-        return None
-    peak = rl.effective_peak_tflops(kind, "high")
-    if peak is None:
-        return None
-    return float(f"{flops / (peak * 1e12 * max(1, ranks)) * 1e3:.4g}")
+    work = least_work(dataclasses.replace(graph, nodes=(node,)),
+                      global_shape, chips)
+    ideal = work.least_s(peaks) * 1e3
+    return float(f"{ideal:.4g}") if ideal > 0 else None
 
 
 def stage_profile(plan: Any, direction: str = "forward", dims: int = 3,
@@ -675,8 +724,7 @@ def stage_profile(plan: Any, direction: str = "forward", dims: int = 3,
     ``capture``), resolve the declared graph, and emit one row per
     declared node — device time, fraction of the measured total, and the
     per-stage roofline gap — plus the exchange-vs-compute split and the
-    unattributed remainder. This is the ``"stage_profile"`` block shape
-    bench.py commits and ``dfft-explain --profile`` prints."""
+    unattributed remainder, as ``dfft-explain --profile`` prints it."""
     from ..analysis import plangraph
 
     graph = plangraph.graph_for(plan, direction, dims)
@@ -687,6 +735,7 @@ def stage_profile(plan: Any, direction: str = "forward", dims: int = 3,
         k = stage_of(k)
         scopes[k] = scopes.get(k, 0.0) + v
     total = float(agg["total_ms"]) or 1e-12
+    shape = tuple(plan.global_size.shape)
     ranks = 1
     mesh = getattr(plan, "mesh", None)
     if mesh is not None:
@@ -717,7 +766,7 @@ def stage_profile(plan: Any, direction: str = "forward", dims: int = 3,
             approx = nshare > 1
             consumed[k] = t
         ms = round(share, 6) if share is not None else 0.0
-        ideal = _node_ideal_ms(graph, n, ranks, kind)
+        ideal = _node_ideal_ms(graph, n, shape, ranks, kind)
         row: Dict[str, Any] = {
             "node": n.id, "kind": n.kind,
             "label": n.label or plangraph._node_brief(n),

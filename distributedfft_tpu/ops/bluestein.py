@@ -19,7 +19,7 @@ IFFT(m); the kernel spectrum ``FFT(b)`` is a host-precomputed constant
 matrices of ``ops/mxu_fft.py``), so each chirp-z pass costs exactly two
 smooth power-of-two transforms plus O(m) elementwise work — O(n log n)
 for every n, at a bounded overhead over a natively smooth axis
-(``evalkit/roofline.bluestein_axis_report`` quotes the factor honestly).
+(``bluestein_axis_report`` below quotes the factor).
 
 Registered as ``Config(fft_backend="bluestein")`` (``ops/fft.py``
 dispatch): smooth axes delegate to the XLA expansion untouched
@@ -282,3 +282,23 @@ def irfftn_3d(x, shape_3d: Tuple[int, int, int], norm: FFTNorm = FFTNorm.NONE):
     c = ifft(_fit_axis(x, -3, shape_3d[-3]), axis=-3, norm=norm)
     c = ifft(_fit_axis(c, -2, shape_3d[-2]), axis=-2, norm=norm)
     return irfft(c, n=shape_3d[-1], axis=-1, norm=norm)
+
+
+def bluestein_axis_report(n: int) -> Tuple[int, float]:
+    """(padded chirp length m, flop factor over a natively smooth axis of
+    the same length) — what ``dfft-explain`` quotes for a non-smooth
+    axis, whose least work the nominal 2.5*n*log2(n) model understates.
+    A chirp-z pass runs two length-m smooth FFTs, amortized over n
+    elements (the kernel spectrum is precomputed), plus three complex
+    chirp/pointwise multiplies (6 real flops each). Smooth lengths
+    report (n, 1.0): the backend delegates them."""
+    if is_smooth(n):
+        return n, 1.0
+    m = chirp_length(n)
+    per_elem = 2.0 * 2.5 * m * math.log2(m) / n + 3.0 * 6.0
+    return m, per_elem / (2.5 * math.log2(n))
+
+
+def nonsmooth_axes(shape: Sequence[int]) -> list:
+    """The distinct non-5-smooth axis lengths of a shape (sorted)."""
+    return sorted({int(n) for n in shape if not is_smooth(int(n))})

@@ -743,9 +743,9 @@ def rfftn_3d(x, norm: FFTNorm = FFTNorm.NONE):
 # program contains NO complex dtypes anywhere — input, output, and every
 # intermediate are real. Since XLA lowers complex dots to exactly these
 # real matmuls anyway, this formulation measures the same hardware work.
-# Direct sizes only (every axis <= DIRECT_MAX); reachable as bench.py's
-# ``DFFT_BENCH_BACKEND=matmul-planes`` (ROADMAP C3 decides whether it
-# stays).
+# Direct sizes only (every axis <= DIRECT_MAX); reachable as the chain
+# timer's ``matmul-planes`` backend label (testing/chaintimer.py; ROADMAP
+# C3 decides whether it stays).
 # ---------------------------------------------------------------------------
 
 

@@ -3,8 +3,8 @@
 The reference validates offline (testcases 1/3/4) and benchmarks blind; a
 misconfigured production run — wrong wisdom cell, broken backend on a new
 jax, a lossy wire on data it cannot represent — burns its whole timed loop
-before anyone notices. ``--selftest`` (all four CLIs; ``bench.py``
-forwards it to its children) runs ONE roundtrip of the plan's actual
+before anyone notices. ``--selftest`` (on each of the four
+CLIs) runs ONE roundtrip of the plan's actual
 shape/rendering first and prints a PASS/FAIL line:
 
 * **Parseval** — the forward output's energy against the guard invariant

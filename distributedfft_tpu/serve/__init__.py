@@ -16,7 +16,7 @@ and real faults. Three pieces:
   graceful drain.
 * ``cli``      — the ``dfft-serve`` executable: ``--drive`` runs the
   open-loop load generator (``testing/workloads.serve_load``) against an
-  in-process server (the chaos-CI and saturation-bench surface);
+  in-process server (the chaos-CI and load-drill surface);
   ``--http`` serves ``/healthz`` / ``/readyz`` / ``POST /fft`` over
   stdlib HTTP.
 

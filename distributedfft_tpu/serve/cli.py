@@ -5,7 +5,7 @@ Two complementary surfaces over one in-process :class:`Server`:
 * ``--drive`` runs the open-loop load generator
   (``testing/workloads.serve_load``: Poisson arrivals, mixed
   shape/dtype traffic) against the server and prints ONE final JSON
-  summary line — the surface the chaos CI job and the saturation bench
+  summary line — the surface the chaos CI job and load drills
   drive. ``--health-out`` additionally writes the final health snapshot
   (the readiness document CI asserts ``degraded`` on when a fault opened
   a circuit).
@@ -181,7 +181,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--drive", action="store_true",
                     help="drive the built-in open-loop load generator "
                          "against this server, print a JSON summary, "
-                         "drain and exit (chaos-CI / bench surface)")
+                         "drain and exit (chaos-CI / load-drill surface)")
     ap.add_argument("--rate", type=float, default=20.0,
                     help="offered load, requests/sec (Poisson arrivals)")
     ap.add_argument("--duration", type=float, default=5.0,

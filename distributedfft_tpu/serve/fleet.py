@@ -302,8 +302,8 @@ def _worker_main(conn: Any, spec: Dict[str, Any]) -> None:
     spawn context can pickle it)."""
     os.environ["DFFT_WORKER_INDEX"] = str(spec["index"])
     # Worker-env overrides land BEFORE the jax backend initializes (the
-    # spawn child imported jax but touched no device yet) — the fleet
-    # bench uses this to pin each worker to one intra-op thread so
+    # spawn child imported jax but touched no device yet) — a caller
+    # can pin each worker to one intra-op thread with it, so
     # process-level scaling is real on a shared-core host.
     for k, v in (spec.get("env") or {}).items():
         os.environ[str(k)] = str(v)

@@ -16,11 +16,11 @@ xla wins by a similar margin; the pallas negative-result analysis lives in
 ``ops/pallas_fft.py``, the radix-2 one at ``mxu_fft.MXUSettings.radix2``).
 
 Timing comes from the shared chained-roundtrip harness
-(``testing/chaintimer.py``, also used by bench.py): median of (t_K - t_1)
-pairs of a scalar-fenced jitted fori_loop chain. Use ``k`` large enough
-that the measured work dominates the per-call constant (bench.py uses
-257 at 256^3); a nonpositive median is reported as a degenerate
-measurement, not a timing.
+(``testing/chaintimer.py``): median of (t_K - t_1) pairs of a
+scalar-fenced jitted fori_loop chain. Use ``k`` large enough that the
+measured work dominates the per-call constant (``dfft-reference
+--autotune`` uses 257 at 256^3); a nonpositive median is reported as a
+degenerate measurement, not a timing.
 """
 
 from __future__ import annotations

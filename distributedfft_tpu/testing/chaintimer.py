@@ -1,4 +1,4 @@
-"""Shared chained-roundtrip timing harness (bench.py + testing/autotune.py).
+"""Shared chained-roundtrip timing harness (testing/autotune.py's races).
 
 Methodology: K roundtrips chained through ``lax.fori_loop`` inside ONE
 jitted program reduced to a scalar (a scalar readback is the completion
@@ -234,7 +234,7 @@ def median_pair_diff_ms(fn1, fnK, x, k: int, repeats: int,
     """(per-iteration ms from the median (t_K - t_1) pair, last t_1 seconds).
 
     Callers compile+warm both fns first. The returned t_1 lets a caller
-    build a degenerate fallback (bench.py subtracts a null-readback)."""
+    build a degenerate fallback (such as subtracting a null readback)."""
     if k < 2:
         raise ValueError(f"k must be >= 2 (got {k})")
     pairs = [(timed_best(fnK, x, inner), timed_best(fn1, x, inner))

@@ -102,8 +102,7 @@ def wire_probe(shape, p: int, dtype=np.float32):
     ``(time_window, info)`` where ``time_window(iterations, warmup)`` times
     one window of the compiled program (seconds) and ``info`` carries the
     exchanged bytes and the HLO collective evidence. Lets callers interleave
-    repeated windows with other measurements without recompiling
-    (``bench.py`` mesh child)."""
+    repeated windows with other measurements without recompiling."""
     import jax.lax as lax
 
     mesh = make_slab_mesh(p)
@@ -410,7 +409,7 @@ def transpose_fraction_chain(plan, spec_val, k: int = 8, repeats: int = 5,
     # SELECTION phase: race every variant; pick the winner by median
     # fraction. These samples are NOT published (max-of-noisy-medians is
     # biased high — the publication phase re-measures fresh), so callers
-    # under a deadline (bench.py's mesh child) may rank with fewer
+    # under a deadline may rank with fewer
     # repeats via ``selection_repeats``; default = the full ``repeats``
     # so raising -i on a noisy host fixes selection-phase degeneracy too.
     sel_n = repeats if selection_repeats is None else max(

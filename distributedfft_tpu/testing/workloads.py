@@ -1,144 +1,10 @@
-"""Chained workload timers for the BASELINE application configs.
-
-``chaintimer`` times the raw 3D R2C+C2R roundtrip (BASELINE configs #1-#3);
-this module builds the same scalar-fenced ``lax.fori_loop`` chains for the
-two application-shaped configs, so they are measured with the identical
-methodology (see chaintimer's docstring):
-
-* ``poisson_chain`` — BASELINE config #5 ("3D Poisson solve,
-  FFT-diagonalized Laplacian"): forward R2C -> symbol multiply -> inverse
-  C2R per iteration (``solvers/poisson.py``). The chain iterates
-  ``v <- solve(v + x)``: the extra add keeps a loop-carried dependency (no
-  iteration can be CSE'd away) and the iteration converges to the bounded
-  fixed point ``(I - S)^-1 S x`` of the linear solve operator ``S`` (whose
-  spectral radius is <= 1 in integer mode), so values neither underflow
-  nor blow up over hundreds of iterations.
-* ``batched2d_chain`` — BASELINE config #4 ("Batched 2D FFT, 1D mesh"):
-  per-iteration forward+inverse of a ``(batch, nx, ny)`` stack
-  (``models/batched2d.py``), rescaled by ``1/(nx*ny)`` to stay bounded.
-
-Both run the plans in single-process (``fft3d``) mode when built with
-``SlabPartition(1)`` — the single-chip artifact configuration — but accept
-any partition/mesh the underlying plans accept.
+"""Open-loop load generation against the serving layer: ``serve_load``
+drives a live ``Server`` or ``Fleet`` with Poisson arrivals over a mix of
+shapes, dtypes and transforms (``dfft-serve --drive``).
 """
 
 from __future__ import annotations
 
-from .. import params as pm
-from ..models.batched2d import Batched2DFFTPlan
-from ..models.slab import SlabFFTPlan
-from ..solvers.poisson import PoissonSolver
-
-
-def poisson_chain(k: int, n: int, backend: str = "matmul",
-                  partition: pm.SlabPartition | None = None, mesh=None):
-    """Jitted scalar-fenced chain of ``k`` Poisson solves at ``n^3`` f32.
-
-    Returns ``fn(x)`` where ``x`` is the (padded) real forcing array.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    g = pm.GlobalSize(n, n, n)
-    plan = SlabFFTPlan(g, partition or pm.SlabPartition(1),
-                       pm.Config(fft_backend=backend), mesh=mesh)
-    solver = PoissonSolver(plan, mode="integer")
-
-    def fn(x):
-        v = lax.fori_loop(0, k, lambda i, v: solver.solve(v + x), x)
-        return jnp.sum(jnp.abs(v))
-
-    return jax.jit(fn), plan
-
-
-def batched2d_chain(k: int, batch: int, nx: int, ny: int,
-                    backend: str = "matmul",
-                    partition: pm.SlabPartition | None = None, mesh=None,
-                    shard: str = "batch", batch_chunk=None):
-    """Jitted scalar-fenced chain of ``k`` batched-2D R2C+C2R roundtrips.
-
-    Returns ``fn(x)`` for a (padded) ``(batch, nx, ny)`` f32 stack.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    plan = Batched2DFFTPlan(batch, nx, ny, partition or pm.SlabPartition(1),
-                            pm.Config(fft_backend=backend), mesh=mesh,
-                            shard=shard, batch_chunk=batch_chunk)
-    scale = 1.0 / float(nx * ny)
-
-    def fn(x):
-        def body(i, v):
-            return plan.exec_inverse(plan.exec_forward(v)) * scale
-
-        return jnp.sum(jnp.abs(lax.fori_loop(0, k, body, x)))
-
-    return jax.jit(fn), plan
-
-
-def ns2d_chain(k: int, batch: int, n: int, dt: float = 1e-3,
-               viscosity: float = 1e-3, backend: str = "matmul",
-               partition: pm.SlabPartition | None = None, mesh=None,
-               shard: str = "batch"):
-    """Jitted scalar-fenced chain of ``k`` RK4 Navier-Stokes-2D steps on
-    a ``(batch, n, n)`` vorticity ensemble (solvers/navier_stokes.py) —
-    the solvers bench's step-time workload. Each step is 20 distributed
-    forward/inverse transforms (4 RHS evaluations x 5), the serving
-    layer's steady-state traffic shape in miniature.
-
-    Returns ``(fn, solver)`` with ``fn(w0) -> scalar`` (sum of |ω| after
-    k steps; the scalar readback is the fence, chaintimer convention)."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..models.batched2d import Batched2DFFTPlan
-    from ..solvers.navier_stokes import NavierStokes2D
-
-    plan = Batched2DFFTPlan(batch, n, n, partition or pm.SlabPartition(1),
-                            pm.Config(fft_backend=backend), mesh=mesh,
-                            shard=shard)
-    solver = NavierStokes2D(plan, viscosity)
-    sfn = solver.solve_fn(k, dt)
-
-    def fn(w0):
-        return jnp.sum(jnp.abs(sfn(w0)))
-
-    return jax.jit(fn), solver
-
-
-def flops_ns2d_step(batch: int, n: int) -> float:
-    """Nominal FFT flops of ONE RK4 NS-2D step: 4 RHS evaluations x 5
-    transforms (4 inverse + 1 forward), each a 2D transform of the
-    stack (the elementwise work is O(N) and omitted, the
-    flops_roundtrip_3d convention)."""
-    import math
-    return 4 * 5 * 2.5 * batch * n * n * math.log2(float(n) * n)
-
-
-def flops_roundtrip_3d(n: int) -> float:
-    """R2C + C2R flops for an ``n^3`` volume: 2.5·N^3·log2(N^3) per
-    direction (BASELINE.md §Derived). The single shared FLOP model —
-    ``bench.py`` delegates here from its child processes."""
-    import math
-    return 2 * 2.5 * n**3 * math.log2(float(n) ** 3)
-
-
-def flops_poisson(n: int) -> float:
-    """R2C + C2R per solve (the symbol multiply is O(N^3), negligible)."""
-    return flops_roundtrip_3d(n)
-
-
-def flops_batched2d(batch: int, nx: int, ny: int) -> float:
-    """Forward+inverse 2D FFT flops for the whole stack."""
-    import math
-    return 2 * 2.5 * batch * nx * ny * math.log2(float(nx) * ny)
-
-
-# ---------------------------------------------------------------------------
-# open-loop load generation against the serving layer (ISSUE 8)
-# ---------------------------------------------------------------------------
 
 def serve_load(server, *, rate_hz: float, duration_s: float | None = None,
                n_requests: int | None = None,
@@ -162,9 +28,8 @@ def serve_load(server, *, rate_hz: float, duration_s: float | None = None,
     Every submission outcome is tallied: completed requests contribute
     their end-to-end latency (submit -> result materialized), rejections
     count by class (``shed`` / ``circuit_open`` / ``deadline_expired`` /
-    ``closed`` / ``failed``). Returns the measurement dict the
-    saturation bench folds into BENCH_DETAILS.json: p50/p99/mean latency
-    ms, achieved FFTs/sec vs offered, and the outcome counts.
+    ``closed`` / ``failed``). Returns the measurement dict: p50/p99/mean
+    latency ms, achieved FFTs/sec vs offered, and the outcome counts.
 
     ``warmup`` synchronous requests per (shape, dtype, transform) cell
     pre-build the plans OUTSIDE the measured window (set ``warmup=0`` to

@@ -1,7 +1,7 @@
 """Persistent plan "wisdom": autotune once, reuse everywhere.
 
 FFTW saves its planner measurements as *wisdom*; XLA amortizes compilation
-through its persistent compilation cache (bench.py wires it). This module is
+through its persistent compilation cache. This module is
 the same amortization for THIS framework's two measured plan choices:
 
 * the local-FFT backend race (``testing/autotune.autotune_local_fft`` —
@@ -470,8 +470,7 @@ def plan_key(kind: str, global_shape: Sequence[int], double_prec: bool,
 
 def local_key(shape: Sequence[int], double_prec: bool) -> str:
     """Key for a bare single-device local-FFT race (no plan around it):
-    what ``dfft-reference --autotune`` records and bench.py warm-starts
-    from."""
+    what ``dfft-reference --autotune`` records."""
     parts = dict(_device_fingerprint())
     parts.update({"shape": list(int(s) for s in shape),
                   "dtype": "f64" if double_prec else "f32",
@@ -493,7 +492,7 @@ def _mesh_shape_of(mesh: Any, partition: Any) -> Dict[str, int]:
 
 
 # ---------------------------------------------------------------------------
-# record helpers (shared by resolution, the CLIs and bench.py)
+# record helpers (shared by resolution and the CLIs)
 # ---------------------------------------------------------------------------
 
 def local_fft_record(candidate: Any) -> Dict[str, Any]:
@@ -875,8 +874,8 @@ def resolve_local_backend(shape: Sequence[int], double_prec: bool = False,
                           default: str = _FALLBACK_BACKEND,
                           ) -> Tuple[str, Optional[Dict[str, Any]]]:
     """``(backend, record-or-None)`` for a BARE single-device transform of
-    ``shape`` (no plan around it — the ``dfft-reference`` testcase-0 path
-    and bench.py's warm-start): wisdom hit -> the recorded winner; miss ->
+    ``shape`` (no plan around it — the ``dfft-reference`` testcase-0
+    path): wisdom hit -> the recorded winner; miss ->
     bounded race-and-record when ``race_on_miss`` (else ``default``); any
     failure degrades to ``default``."""
     store = open_store(path, enabled)

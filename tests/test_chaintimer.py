@@ -1,5 +1,5 @@
 """Shared chained-roundtrip timing harness (testing/chaintimer.py), used by
-bench.py and the autotuner."""
+the autotuner."""
 
 import numpy as np
 import pytest
@@ -118,7 +118,7 @@ def test_direct_max_override_changes_factorization(rng):
 
 
 def test_chunked_forward_chain_accumulates():
-    """The chunked-plan forward chain (bench.py's last HBM rung at the
+    """The chunked-plan forward chain (the HBM-bounded plan at the
     north-star cube) follows the same serial-accumulator contract as
     directional_chain: k scales the accumulated scalar and the underlying
     chunked transform matches numpy."""

@@ -170,12 +170,14 @@ class TestEvalKit:
         assert rec8 and rec8[0].endswith("2.000,2.000")
 
     def test_committed_stage_scalability_is_current(self):
-        """The committed cpumesh8 stage-decomposition CSV must match what
-        the reducer produces from the committed raw Timer data."""
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        prefix = os.path.join(repo, "eval", "benchmarks", "cpumesh8")
-        committed = os.path.join(prefix, "eval",
-                                 "scalability_stages_256_256_256.csv")
+        """The committed stage-decomposition CSV must match what the
+        reducer produces from the committed raw Timer data (a 256^3
+        strong-scaling series on the 8-device CPU mesh, every strategy
+        of each series, so the per-P best-strategy pick is exercised)."""
+        data = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "data")
+        prefix = os.path.join(data, "scalability_256")
+        committed = os.path.join(data, "scalability_stages_256_256_256.csv")
         with open(committed) as f:
             want = f.read()
         import tempfile

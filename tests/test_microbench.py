@@ -1,4 +1,4 @@
-"""Communication microbenchmarks (testing/microbench.py) — the reference's
+"""Communication microbenchmarks (``testing/microbench``) — the reference's
 1D/2D/3D bandwidth-probe semantics (``tests_reference.hpp:53-96``), with
 compiled-HLO evidence that each strategy measures a real collective."""
 
@@ -50,7 +50,7 @@ def test_3d_geometry_rejects_degenerate_mesh(devices):
 def test_wire_bandwidth_pure_exchange(devices):
     """The wire probe (all_to_all, split==concat axis) runs a real
     collective with no relayout and reports positive bandwidth + HLO
-    evidence — the ceiling bench.py's alltoall_fraction gates against."""
+    evidence — the ceiling an exchange's bandwidth is read against."""
     r = mb.wire_bandwidth((64, 16, 16), 8, iterations=1, warmup=0)
     assert r["gb_per_s"] > 0
     assert "all-to-all" in r["collective_ops"]

@@ -225,6 +225,61 @@ def test_explain_slab_default(capsys, devices):
     assert "roofline" in out
 
 
+def _roofline_section(out: str) -> str:
+    sec = out.split("roofline (least work from the plan graph):\n", 1)[1]
+    # No record of the retired CPU benchmark, and no tracked fraction.
+    assert "BENCH_" "DETAILS" not in out and "tracked" not in sec
+    return sec
+
+
+@pytest.mark.parametrize("argv,flops,hbm,ici,rough", [
+    # 16^3 slab over 8, per direction and chip: 2.5 * 4096 * 12 / 8
+    # flops; (16 KiB real + 3 x 18 KiB spectral) / 8 through HBM; 7/8 of
+    # the 18 KiB spectrum / 8 sent.
+    (["--kind", "slab", "-nx", "16", "-ny", "16", "-nz", "16", "-p", "8"],
+     "30720", "17.50 KiB", "3.94 KiB", None),
+    # 8 images of 16^2 over 8, batch-sharded: one 2D FFT node each way,
+    # 2.5 * 2048 * 8 / 8 flops and (8 KiB + 9 KiB) / 8; no exchange.
+    (["--kind", "batched", "-nx", "16", "-ny", "16", "-nz", "8",
+      "--shard", "batch", "-p", "8"],
+     "10240", "4.25 KiB", "0 B", None),
+    # A prime extent: the Bluestein factor is quoted before the counts.
+    (["--kind", "slab", "-nx", "17", "-ny", "16", "-nz", "16", "-p", "8"],
+     None, None, None, "non-smooth axis 17: no native fast path — "
+     "bluestein chirp length 64"),
+], ids=["cube", "batched_stack", "nonsmooth"])
+def test_explain_roofline_from_plan_graph(capsys, devices, argv, flops,
+                                          hbm, ici, rough):
+    """The roofline section is the plan graph's least work of one
+    roundtrip per chip, and on the CPU, which has no peaks, no least
+    time."""
+    sec = _roofline_section(_explain(argv + ["--no-compile"], capsys))
+    m = re.search(r"one roundtrip, per chip of (\d+): (\S+) flops .* HBM "
+                  r"(.+) \(local FFTs' edges\), ICI (.+) sent", sec)
+    assert m and m.group(1) == "8"
+    if flops is not None:
+        assert float(m.group(2)) == float(flops)
+        assert (m.group(3), m.group(4)) == (hbm, ici)
+    if rough is not None:
+        assert rough in sec
+        assert "12.8x the flops of a smooth axis" in sec
+    assert "no peaks for cpu: no least time" in sec
+
+
+def test_explain_roofline_least_time_on_a_known_kind(capsys, devices,
+                                                     monkeypatch):
+    """On a kind in ``profile.PEAKS`` the section prints the least time
+    and which resource bounds it: 16^3 over 8 moves bytes, not flops."""
+    from distributedfft_tpu.obs import profile
+    monkeypatch.setitem(profile.PEAKS, "cpu", profile.PEAKS["TPU v5 lite"])
+    sec = _roofline_section(_explain(
+        ["--kind", "slab", "-nx", "16", "-ny", "16", "-nz", "16", "-p", "8",
+         "--no-compile"], capsys))
+    want = 17920 / 819e9 * 1e3
+    m = re.search(r"least time on cpu: (\S+) ms \(bound by HBM bytes", sec)
+    assert m and float(m.group(1)) == pytest.approx(want, rel=1e-3)
+
+
 def test_explain_slab_ring(capsys, devices):
     out = _explain(["--kind", "slab", "-nx", "16", "-ny", "16", "-nz", "16",
                     "-p", "8", "-snd", "Ring", "-s", "Z_Then_YX"], capsys)

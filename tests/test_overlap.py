@@ -439,30 +439,3 @@ def test_send_method_parse_and_encoding():
     assert not pm.SendMethod.SYNC.is_ring
     # The multihost broadcast encoding enumerates every SendMethod.
     assert OVL in wisdom._send_encoding()
-
-
-# ---------------------------------------------------------------------------
-# bench satellite: per-child wall-clock budgets
-# ---------------------------------------------------------------------------
-
-def test_bench_child_budget_env(monkeypatch):
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    monkeypatch.delenv("DFFT_BENCH_CHILD_TIMEOUT_S", raising=False)
-    assert bench._child_budget("mesh", 300) == 300
-    monkeypatch.setenv("DFFT_BENCH_CHILD_TIMEOUT_S", "120")
-    assert bench._child_budget("mesh", 300) == 120
-    assert bench._child_budget("tpu", 450) == 120
-    monkeypatch.setenv("DFFT_BENCH_CHILD_TIMEOUT_S",
-                       "mesh:90, tpu:200, bogus, serve:oops")
-    assert bench._child_budget("mesh", 300) == 90
-    assert bench._child_budget("tpu", 450) == 200
-    assert bench._child_budget("serve", 90) == 90   # malformed -> default
-    assert bench._child_budget("solvers", 75) == 75
-    monkeypatch.setenv("DFFT_BENCH_CHILD_TIMEOUT_S", "60,mesh:10")
-    assert bench._child_budget("mesh", 300) == 10
-    assert bench._child_budget("probe", 180) == 60

@@ -338,3 +338,40 @@ def test_stage_profile_folds_axis_subscopes_into_their_node(devices):
     assert prof["other_scopes"] == {}
     assert prof["attributed_ms"] == pytest.approx(10.5)
     assert prof["unattributed_ms"] == pytest.approx(0.5)
+
+
+def test_stage_profile_ideal_is_the_nodes_least_work(devices, monkeypatch):
+    """Each node's ``ideal_ms`` is ``least_work`` of that node alone over
+    the kind's peaks: the local FFTs bound by HBM bytes, the exchange by
+    ICI bytes; nodes with no work, and kinds with no peaks, get none."""
+    import dataclasses
+
+    from distributedfft_tpu.analysis import plangraph
+    plan = _slab(comm_method=dfft.CommMethod.ALL2ALL)
+    capture = {"scopes": {"slab/local_fft:1": 3.0, "slab/local_fft:2": 3.0,
+                          "slab/exchange:1": 4.0},
+               "unattributed_ms": 0.0, "total_ms": 10.0, "window_ms": 10.0,
+               "iters": 1, "planes": []}
+    prof = profile.stage_profile(plan, "forward", 3, capture=capture)
+    assert not any("ideal_ms" in r for r in prof["stages"])  # cpu: no peaks
+    peaks = profile.PEAKS["TPU v5 lite"]
+    monkeypatch.setitem(profile.PEAKS, "cpu", peaks)
+    prof = profile.stage_profile(plan, "forward", 3, capture=capture)
+    graph = plangraph.graph_for(plan, "forward", 3)
+    shape, chips = tuple(plan.global_size.shape), 8
+    ideal = {r["node"]: r.get("ideal_ms") for r in prof["stages"]}
+    for node in graph.nodes:
+        w = profile.least_work(dataclasses.replace(graph, nodes=(node,)),
+                               shape, chips)
+        if node.kind in ("local_fft", "exchange"):
+            assert ideal[node.id] == pytest.approx(
+                w.least_s(peaks) * 1e3, rel=1e-3)
+        else:
+            assert ideal[node.id] is None
+    fft = profile.least_work(dataclasses.replace(
+        graph, nodes=(graph.node("local_fft:1"),)), shape, chips)
+    assert max(fft.bounds_s(peaks), key=fft.bounds_s(peaks).get) \
+        == "HBM bytes"
+    xch = profile.least_work(dataclasses.replace(
+        graph, nodes=(graph.node("exchange:1"),)), shape, chips)
+    assert xch.ici_bytes > 0 and xch.flops == xch.hbm_bytes == 0

@@ -531,6 +531,32 @@ def test_bluestein_helpers():
     assert good_size(30) == 30
 
 
+@pytest.mark.parametrize("n,m,factor", [
+    (4095, 8192, 4.93453638913481),     # 3^2*5*7*13: chirp at 2^13
+    (17, 64, 12.813931923651086),       # prime: chirp at 2^6
+    (1024, 1024, 1.0),                  # smooth: delegated, no overhead
+    (360, 360, 1.0),
+])
+def test_bluestein_axis_report(n, m, factor):
+    """(chirp length, flop factor over a smooth axis): two length-m FFTs
+    amortized over n elements plus three complex multiplies, over the
+    nominal 2.5*log2(n) per element."""
+    from distributedfft_tpu.ops.bluestein import bluestein_axis_report
+    got_m, got_factor = bluestein_axis_report(n)
+    assert got_m == m
+    assert got_factor == pytest.approx(factor, rel=1e-12)
+    if m != n:
+        assert got_factor == pytest.approx(
+            (5.0 * m * np.log2(m) / n + 18.0) / (2.5 * np.log2(n)))
+
+
+def test_nonsmooth_axes():
+    from distributedfft_tpu.ops.bluestein import nonsmooth_axes
+    assert nonsmooth_axes((4095, 17, 1024, 17)) == [17, 4095]
+    assert nonsmooth_axes((1024, 1024, 1024)) == []
+    assert nonsmooth_axes((4096, 4096)) == []
+
+
 # ---------------------------------------------------------------------------
 # guards + compressed wire composition through a solver path
 # ---------------------------------------------------------------------------

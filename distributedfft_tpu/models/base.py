@@ -66,6 +66,18 @@ def local_fft_fields(config: Config, settings: Any) -> Dict[str, Any]:
             "direct_max": st.direct_max}
 
 
+def r2c_pairing(config: Config, mesh: Optional[Mesh], n: int,
+                rows: Optional[int]) -> bool:
+    """A plan's ``r2c_paired``, decided once at construction: whether
+    its R2C along the last axis, of extent ``n`` over ``rows`` local
+    rows, runs as one complex FFT per pair of rows
+    (``ops/fft.pairs_rows``). ``rows`` is None where the plan's R2C is
+    not ``ops/fft.rfft`` along the last axis."""
+    return rows is not None and local_fft.pairs_rows(
+        wisdom.plan_platform(mesh), config.double_prec, config.fft_backend,
+        n, rows)
+
+
 def _with_pad(pure: Callable[..., Any], logical_shape: Sequence[int],
               padded_shape: Sequence[int]) -> Callable[..., Any]:
     """Wrap a pure pipeline so logical-shaped input is zero-padded to the
@@ -329,7 +341,7 @@ class DistFFTPlan:
 
             def per(xs: Any) -> Any:
                 c = local_fft.rfft(xs, axis=-1, norm=norm, backend=be,
-                                   settings=st)
+                                   settings=st, paired=self.r2c_paired)
                 return local_fft.fft(c, axis=-2, norm=norm, backend=be,
                                      settings=st)
 

@@ -41,7 +41,7 @@ from ..parallel.transpose import (all_to_all_transpose, chunked_reshard,
                                   split_axis_chunks, wire_gspmd_stages)
 from ..utils import wisdom
 from .base import (_with_pad, jit_stages, local_fft_fields,
-                   notice_axis_smoothness)
+                   notice_axis_smoothness, r2c_pairing)
 
 # A TPU's float32 tile is 8 sublanes by 128 lanes. The chunk loop's
 # group buffers (``_map_in_tile_groups``) keep their images outermost, the
@@ -154,10 +154,15 @@ class Batched2DFFTPlan:
                 and not self.config.double_prec
                 and wisdom.plan_platform(mesh) == "tpu"):
             self.chunk_group = _SUBLANES
+        rows = ((batch_chunk or local_b) * nx if self.fft3d or shard == "batch"
+                else batch * (self._nx_pad // P))
+        self.r2c_paired = transform == "r2c" and r2c_pairing(
+            self.config, mesh, ny, rows)
         notice_axis_smoothness("batched2d", (nx, ny), self.config)
         obs.event("plan.created", kind="batched2d", shard=shard,
                   transform=transform, shape=[batch, nx, ny], ranks=P,
                   batch_chunk=batch_chunk, chunk_group=self.chunk_group,
+                  r2c_paired=self.r2c_paired,
                   comm=self.config.comm_method.value,
                   send=self.config.send_method.value, opt=self.config.opt,
                   wire=self.config.wire_dtype,
@@ -323,7 +328,8 @@ class Batched2DFFTPlan:
             if self.transform == "c2c":
                 c = lf.fft(x, axis=2, norm=norm, backend=be, settings=st)
             else:
-                c = lf.rfft(x, axis=2, norm=norm, backend=be, settings=st)
+                c = lf.rfft(x, axis=2, norm=norm, backend=be, settings=st,
+                            paired=self.r2c_paired)
             return lf.fft(c, axis=1, norm=norm, backend=be, settings=st)
         c = lf.ifft(x, axis=1, norm=norm, backend=be, settings=st)
         if self.transform == "c2c":
@@ -442,7 +448,8 @@ class Batched2DFFTPlan:
                 if complex_mode:
                     c = lf.fft(xl, axis=2, norm=norm, backend=be, settings=st)
                 else:
-                    c = lf.rfft(xl, axis=2, norm=norm, backend=be, settings=st)
+                    c = lf.rfft(xl, axis=2, norm=norm, backend=be,
+                                settings=st, paired=self.r2c_paired)
                 return pad_axis_to(c, 2, nys_pad)
 
             def xpose(c):

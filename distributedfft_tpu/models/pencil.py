@@ -63,7 +63,7 @@ from ..parallel.transpose import (WIRE_NATIVE, all_to_all_transpose,
 from ..resilience import inject
 from ..utils import wisdom
 from .base import (DistFFTPlan, _with_pad, local_fft_fields,
-                   notice_axis_smoothness)
+                   notice_axis_smoothness, r2c_pairing)
 
 P1_AXIS, P2_AXIS = PENCIL_AXES
 
@@ -123,6 +123,10 @@ class PencilFFTPlan(DistFFTPlan):
         # The depth the wisdom entry was resolved under (the fallback
         # ladder's demotion stamp must target the exact cell).
         self._wisdom_dims = dims
+        rows = (g.nx * g.ny if self.fft3d else
+                self._nx_p1 // self.p1 * (self._ny_p2 // self.p2))
+        self.r2c_paired = transform == "r2c" and r2c_pairing(
+            self.config, mesh, g.nz, rows)
         notice_axis_smoothness("pencil", g.shape, self.config)
         obs.event("plan.created", kind="pencil", transform=transform,
                   shape=list(g.shape), grid=[self.p1, self.p2],
@@ -131,6 +135,7 @@ class PencilFFTPlan(DistFFTPlan):
                   send=self.config.send_method.value,
                   send2=self.config.resolved_snd2().value,
                   opt=self.config.opt, wire=self.config.wire_dtype,
+                  r2c_paired=self.r2c_paired,
                   **local_fft_fields(self.config, self._mxu_st))
 
     # -- shapes ------------------------------------------------------------
@@ -426,7 +431,8 @@ class PencilFFTPlan(DistFFTPlan):
             if complex_mode:
                 c = lf.fft(xl, axis=2, norm=norm, backend=be, settings=st)
             else:
-                c = lf.rfft(xl, axis=2, norm=norm, backend=be, settings=st)
+                c = lf.rfft(xl, axis=2, norm=norm, backend=be, settings=st,
+                            paired=self.r2c_paired)
             if dims >= 2:
                 c = pad_axis_to(c, 2, nzc_p2)
             return c
@@ -904,7 +910,8 @@ class PencilFFTPlan(DistFFTPlan):
             if complex_mode:
                 c = lf.fft(x, axis=2, norm=norm, backend=be, settings=st)
             else:
-                c = lf.rfft(x, axis=2, norm=norm, backend=be, settings=st)
+                c = lf.rfft(x, axis=2, norm=norm, backend=be, settings=st,
+                            paired=self.r2c_paired)
             if dims >= 2:
                 c = lf.fft(c, axis=1, norm=norm, backend=be, settings=st)
             if dims >= 3:

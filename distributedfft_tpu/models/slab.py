@@ -67,7 +67,7 @@ from ..parallel.transpose import (all_to_all_transpose, chunked_reshard,
                                   split_axis_chunks, wire_gspmd_stages)
 from ..utils import wisdom
 from .base import (DistFFTPlan, _with_pad, local_fft_fields,
-                   notice_axis_smoothness)
+                   notice_axis_smoothness, r2c_pairing)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,12 +144,22 @@ class SlabFFTPlan(DistFFTPlan):
             out = [None, None, None]
             out[self._seq.split_axis] = SLAB_AXIS
             self._out_spec = PartitionSpec(*out)
+        # Local rows of the R2C along z (None: no R2C along the last
+        # axis, or the single-device rfftn over three axes).
+        ck = self.config.fft3d_chunk
+        rows = None
+        if transform == "r2c" and self._seq.r2c_axis == 2:
+            if not self.fft3d:
+                rows = self._nx_pad // P * g.ny
+            elif ck and ck > 1 and g.nx % ck == 0:
+                rows = g.nx // ck * g.ny
+        self.r2c_paired = r2c_pairing(self.config, mesh, g.nz, rows)
         notice_axis_smoothness("slab", g.shape, self.config)
         obs.event("plan.created", kind="slab", sequence=self.sequence.value,
                   transform=transform, shape=list(g.shape), ranks=P,
                   comm=self.config.comm_method.value,
                   send=self.config.send_method.value, opt=self.config.opt,
-                  wire=self.config.wire_dtype,
+                  wire=self.config.wire_dtype, r2c_paired=self.r2c_paired,
                   **local_fft_fields(self.config, self._mxu_st))
 
     # -- shapes & size tables (reference getInSize/getOutSize family,
@@ -407,7 +417,8 @@ class SlabFFTPlan(DistFFTPlan):
             if complex_mode:
                 c = lf.fft(xl, axis=s.r2c_axis, norm=norm, backend=be, settings=st)
             else:
-                c = lf.rfft(xl, axis=s.r2c_axis, norm=norm, backend=be, settings=st)
+                c = lf.rfft(xl, axis=s.r2c_axis, norm=norm, backend=be,
+                            settings=st, paired=self.r2c_paired)
             for a in s.pre_axes:
                 c = lf.fft(c, axis=a, norm=norm, backend=be, settings=st)
             return pad_axis_to(c, s.split_axis, split_pad)

@@ -29,7 +29,9 @@ O(n^2) contraction there). Selected plan-wide via ``Config.fft_backend``.
 The one-axis entry points (``rfft``, ``irfft``, ``fft``, ``ifft``) trace
 their work, for every backend, under the per-axis sub-scope of the open
 plan stage (``obs/profile.axis_scope``: ``dfft/<family>/<node>.ax<k>``);
-the multi-axis ones stay at the stage scope.
+the multi-axis ones stay at the stage scope. On a TPU a plan's float32
+R2C along the last axis on the "xla" backend runs as one complex FFT per
+pair of rows (``pairs_rows``, ``_paired_rfft``).
 """
 
 from __future__ import annotations
@@ -45,6 +47,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..obs import metrics as obs_metrics
 from ..obs.profile import axis_scope
 from ..params import FFTNorm
 
@@ -125,40 +128,100 @@ def _inv_norm(norm: FFTNorm) -> str:
     return "backward"
 
 
+# Where pairing won on a v5e (PERF.md, the pairing sweep): from an
+# extent of 2048 up to 2^25 points a call. Past that both forms ran
+# alike or the pairs lost (16 x 4096^2: 52.8 ms either way; 256 x
+# 1024^2: 59.2 against 52.9), and below 2048 the results were mixed.
+PAIRED_MIN_EXTENT = 2048
+PAIRED_MAX_POINTS = 1 << 25
+
+
+def pairs_rows(platform: str, double_prec: bool, backend: str, n: int,
+               rows: int) -> bool:
+    """Whether a plan's R2C along the last axis, of extent ``n`` over
+    ``rows`` local rows a call, runs as one complex FFT per pair of rows
+    (``rfft(..., paired=True)``, ``_paired_rfft``): on a TPU, in float32,
+    on the xla backend, with ``n`` and ``rows`` even, ``n`` at least
+    ``PAIRED_MIN_EXTENT`` and ``n * rows`` at most ``PAIRED_MAX_POINTS``.
+    XLA's TPU R2C carries a full complex spectrum of every real row from
+    its first stage on: one 4096^2 image took 2.63 ms, and 1.55 paired."""
+    return (platform == "tpu" and not double_prec and backend == "xla"
+            and n % 2 == 0 and rows % 2 == 0 and n >= PAIRED_MIN_EXTENT
+            and n * rows <= PAIRED_MAX_POINTS)
+
+
 def rfft(x, axis: int, norm: FFTNorm = FFTNorm.NONE, backend: str = "xla",
-         settings=None):
+         settings=None, paired: bool = False):
     """Forward R2C along one axis (cuFFT ``execR2C`` analog, 1D case).
 
     ``settings`` (all entry points): optional ``mxu_fft.MXUSettings``
     scoped around the dispatch — the per-plan alternative to the
-    deprecated ``set_*`` process globals. Ignored by the "xla" backend."""
+    deprecated ``set_*`` process globals. Ignored by the "xla" backend.
+
+    ``paired``: the plan's ``r2c_paired`` (``pairs_rows``). On the "xla"
+    backend a float32 ``x`` whose last axis is ``axis``, with an even
+    extent and an even number of rows, then takes ``_paired_rfft``,
+    counted as ``local_fft.r2c_paired``; any other ``x`` keeps
+    ``jnp.fft.rfft``."""
     with axis_scope(axis, x.ndim):
         m = _impl(backend)
         if m is not None:
             with _settings_ctx(backend, settings):
                 return m.rfft(x, axis=axis, norm=norm)
-        return _xla_rfft(x, axis, _fwd_norm(norm))
+        paired = (paired and x.dtype == jnp.float32
+                  and axis % x.ndim == x.ndim - 1 and x.shape[-1] % 2 == 0
+                  and math.prod(x.shape[:-1]) % 2 == 0)
+        if paired:
+            obs_metrics.inc("local_fft.r2c_paired")
+        return _xla_rfft(x, axis, _fwd_norm(norm), paired)
 
 
-@functools.partial(jax.custom_jvp, nondiff_argnums=(1, 2))
-def _xla_rfft(x, axis: int, norm: str):
-    """``jnp.fft.rfft`` whose tangent map is spelled as slice(fft(x)).
-    JAX's own transpose of rfft traces a naive rfft against an unsharded
-    dummy, so inside ``shard_map`` its output type lacks the cotangent's
-    varying mesh axes and the backward pass is refused; slice, fft and
-    the real-to-complex cast transpose with the types intact."""
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1, 2, 3))
+def _xla_rfft(x, axis: int, norm: str, paired: bool = False):
+    """``jnp.fft.rfft`` (or ``_paired_rfft``) whose tangent map is
+    spelled as slice(fft(x)). JAX's own transpose of rfft traces a naive
+    rfft against an unsharded dummy, so inside ``shard_map`` its output
+    type lacks the cotangent's varying mesh axes and the backward pass
+    is refused; slice, fft and the real-to-complex cast transpose with
+    the types intact."""
+    if paired:
+        return _paired_rfft(x, norm)
     return jnp.fft.rfft(x, axis=axis, norm=norm)
 
 
 @_xla_rfft.defjvp
-def _xla_rfft_jvp(axis, norm, primals, tangents):
+def _xla_rfft_jvp(axis, norm, paired, primals, tangents):
     x, = primals
     t, = tangents
     m = x.shape[axis] // 2 + 1
     full = jnp.fft.fft(t.astype(jnp.result_type(t, 1j)), axis=axis,
                        norm=norm)
-    return _xla_rfft(x, axis, norm), jax.lax.slice_in_dim(full, 0, m,
-                                                          axis=axis)
+    return _xla_rfft(x, axis, norm, paired), jax.lax.slice_in_dim(
+        full, 0, m, axis=axis)
+
+
+def _paired_rfft(x, norm: str):
+    """``jnp.fft.rfft`` along the last axis as one complex FFT per pair
+    of rows. With the leading axes flattened to R rows of even length n,
+    row j and row j + R/2 make z = a + i b; Z = fft(z) and
+    Zr[k] = Z[(n - k) mod n] give, for k = 0..n/2,
+    A[k] = (Z[k] + conj(Zr[k]))/2 and B[k] = (Z[k] - conj(Zr[k]))/(2i),
+    the spectra of a and b. The split is spelled on the real and
+    imaginary planes, so the DC and Nyquist bins come out exactly real;
+    Zr is a constant-index gather, not a reverse op (see
+    ``_reversed_last``)."""
+    lead, n = x.shape[:-1], x.shape[-1]
+    h = math.prod(lead) // 2
+    x = x.reshape(2, h, n)
+    z = jnp.fft.fft(jax.lax.complex(x[0], x[1]), axis=-1, norm=norm)
+    m = n // 2 + 1
+    zr = jnp.take(z, (n - np.arange(m)) % n, axis=-1)
+    z = z[:, :m]
+    p, q = jnp.real(z), jnp.imag(z)
+    s, t = jnp.real(zr), jnp.imag(zr)
+    a = jax.lax.complex((p + s) * 0.5, (q - t) * 0.5)
+    b = jax.lax.complex((q + t) * 0.5, (s - p) * 0.5)
+    return jnp.stack([a, b]).reshape(lead + (m,))
 
 
 def irfft(x, n: int, axis: int, norm: FFTNorm = FFTNorm.NONE,

@@ -293,6 +293,31 @@ class TestChunkGroups:
         assert not _constrains_layout(cases[1][0].forward_fn(),
                                       (8, 16, 16), np.float32)
 
+    @pytest.mark.parametrize("platform,want", [("tpu", True),
+                                               ("cpu", False)])
+    def test_plan_created_and_explain_name_r2c_paired(
+            self, monkeypatch, capsys, platform, want):
+        """The benchmark cell's plan (48 images of 4096^2, chunk 1,
+        float32, xla) pairs the rows of its R2C on a TPU
+        (``ops/fft.pairs_rows``), and ``plan.created`` and
+        ``dfft-explain`` say so; on the CPU it does not. Nothing runs."""
+        from distributedfft_tpu import obs
+        from distributedfft_tpu.obs import explain
+        from distributedfft_tpu.utils import wisdom
+        obs.flightrec.clear()
+        monkeypatch.setattr(wisdom, "plan_platform",
+                            lambda mesh=None: platform)
+        plan = Batched2DFFTPlan(48, 4096, 4096, SlabPartition(1), XLA,
+                                batch_chunk=1)
+        assert plan.r2c_paired is want
+        assert [r["attrs"]["r2c_paired"] for r in obs.flightrec.snapshot()
+                if r["name"] == "plan.created"] == [want]
+        assert explain.main(["--kind", "batched", "-nx", "4096", "-ny",
+                             "4096", "-nz", "48", "--shard", "batch", "-p",
+                             "1", "--batch-chunk", "1", "--fft-backend",
+                             "xla"]) == 0
+        assert f"r2c_paired: {want}" in capsys.readouterr().out
+
 
 class TestHarnessWiring:
     """VERDICT r2 item 7: the batched plan flows through the same

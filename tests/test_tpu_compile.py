@@ -184,3 +184,21 @@ def test_batched2d_sharded_chunk_loop_writes_whole_tiles(topo):
                                  sharding=plan.input_sharding)
         compiled = plan._build(forward=True).lower(x).compile()
     _assert_whole_tile_writes(compiled, 16)
+
+
+def test_batched2d_xla_pairs_rows_on_a_described_v5e(topo):
+    """On the described 2x2 (its devices' platform is "tpu") the float32
+    xla plan pairs the rows of its R2C (``plan.r2c_paired``), and the
+    compiled forward keeps whole-tile writes and reads the FFT's output
+    through no reverse op (``ops/fft._paired_rfft``)."""
+    mesh = Mesh(np.array(topo.devices), ("p",))
+    with jax.enable_x64(False):
+        plan = dfft.Batched2DFFTPlan(64, 2048, 2048, dfft.SlabPartition(4),
+                                     dfft.Config(fft_backend="xla"),
+                                     mesh=mesh, batch_chunk=1)
+        assert plan.r2c_paired
+        x = jax.ShapeDtypeStruct((64, 2048, 2048), jnp.float32,
+                                 sharding=plan.input_sharding)
+        compiled = plan._build(forward=True).lower(x).compile()
+    assert " reverse(" not in compiled.as_text()
+    _assert_whole_tile_writes(compiled, 16)

@@ -17,10 +17,10 @@ maps to a mesh-less plan that calls ``jnp.fft.rfftn`` directly.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .. import obs
@@ -76,6 +76,85 @@ def r2c_pairing(config: Config, mesh: Optional[Mesh], n: int,
     return rows is not None and local_fft.pairs_rows(
         wisdom.plan_platform(mesh), config.double_prec, config.fft_backend,
         n, rows)
+
+
+# The chunk rule (``fft3d_chunk_rule``). The counts it tries, fastest
+# first: the R2C of 1024^3 f32 on one v5e at the platform default took
+# 199.6 ms in 128 chunks, 221.9 in 64, 249.5 in 32, 272.8 in 16, 283.4 in
+# 8 and 313.6 in 4 (the C2R: 209.7 in 128, 217.7 in 64; median of 9).
+# 256 and 512 chunks, under 8 x-planes each, took 221.8 and 225.2, and
+# the C2R in 256 held 17.3 GB. Then the share of the device's limit a
+# plan's programs may fill, and the model of their temporaries by kind
+# of local FFT, in full-length complex cubes (fused) and complex chunks
+# (chunked), from described-v5e compiles at 1024^3 f32: fused, 8.72 GB
+# for the matmul four-step, 10.75 for xla's C2R (its R2C does not
+# compile), 6.48 for one direct contraction per axis; in 16 chunks, 1.2
+# GB for the four-step's C2R, 2.7 for xla's R2C, 3.1 for the direct C2R
+# (eval/benchmarks/tpu_v5e/MEMORY_1024.md).
+FFT3D_CHUNKS = (128, 64, 32, 16, 8, 4, 2)
+FFT3D_FILL = 0.85
+FFT3D_TEMPS = {"fourstep": (1.0, 2.5), "direct": (0.75, 6.0),
+               "xla": (1.25, 5.0)}
+
+# HBM a program may use, by ``device_kind``, for a device that reports
+# no ``memory_stats`` (a described topology): the v5e's compiler refuses
+# a program past 15.75 GiB ("Used 16.00G of 15.75G hbm").
+HBM_LIMIT_BY_KIND = {"TPU v5 lite": int(15.75 * 2**30)}
+
+
+def device_bytes_limit(mesh: Optional[Mesh] = None) -> Optional[int]:
+    """The bytes a single-device plan's programs may hold on its device
+    (the mesh's first, else JAX's default): ``memory_stats()
+    ["bytes_limit"]`` where the device reports one, else
+    ``HBM_LIMIT_BY_KIND`` for its kind, else None (the CPU: no limit
+    known)."""
+    dev = next(iter(mesh.devices.flat)) if mesh is not None \
+        else jax.devices()[0]
+    try:
+        stats = dev.memory_stats() or {}
+    except Exception:  # noqa: BLE001 — a described device reports none
+        stats = {}
+    if stats.get("bytes_limit"):
+        return int(stats["bytes_limit"])
+    return HBM_LIMIT_BY_KIND.get(dev.device_kind)
+
+
+def fft3d_chunk_rule(shape: Sequence[int], double_prec: bool, backend: str,
+                     direct_max: Optional[int], limit: Optional[int]
+                     ) -> Optional[int]:
+    """The chunk count of a single-device 3D R2C/C2R plan whose Config
+    names none: None (the fused program) where ``limit`` is None or the
+    fused programs fit in ``FFT3D_FILL`` of it; else the first count of
+    ``FFT3D_CHUNKS`` (fastest first) that divides the x extent and whose
+    chunked programs fit, or else the largest that divides it (None where
+    none does).
+
+    What a program holds is modelled as argument + output + temporaries:
+    the real cube and the half spectrum, plus, fused, a share of one
+    full-length complex cube, and chunked, the intermediate half spectrum
+    between the two loops and a multiple of one chunk's full-length
+    complex block (``FFT3D_TEMPS``, by the local FFT's kind: the matmul
+    backends' ``"direct"`` where every extent is at most ``direct_max``,
+    else ``"fourstep"``; ``"xla"`` for the others)."""
+    if limit is None:
+        return None
+    if backend in ("matmul", "matmul-r2", "pallas"):
+        kind = ("direct" if direct_max and max(shape) <= direct_max
+                else "fourstep")
+    else:
+        kind = "xla"
+    fused_temps, chunk_temps = FFT3D_TEMPS[kind]
+    r = 8 if double_prec else 4
+    full = 2 * r * math.prod(shape)
+    half = 2 * r * math.prod(shape[:-1]) * (shape[-1] // 2 + 1)
+    budget = FFT3D_FILL * limit
+    if full / 2 + half + fused_temps * full <= budget:
+        return None
+    counts = [c for c in FFT3D_CHUNKS if shape[0] % c == 0]
+    for c in counts:
+        if full / 2 + 2 * half + chunk_temps * full / c <= budget:
+            return c
+    return max(counts, default=None)
 
 
 def _with_pad(pure: Callable[..., Any], logical_shape: Sequence[int],
@@ -307,16 +386,37 @@ class DistFFTPlan:
 
     # -- single-device fallback ------------------------------------------
 
-    def _chunk_for(self, nx: int) -> Optional[int]:
-        """Validated ``Config.fft3d_chunk`` for a leading extent of
-        ``nx`` (None = fused path)."""
+    def _resolve_fft3d_chunk(self, transform: str) -> None:
+        """Set ``fft3d_chunk`` (None: fused) and ``fft3d_chunk_from`` once,
+        when the plan is built. Only a single-device R2C plan chunks. An
+        explicit ``Config.fft3d_chunk`` is kept as given (``"config"``; 1
+        is the fused program). An unset one takes ``fft3d_chunk_rule``
+        against the device's memory (``device_bytes_limit``): a count
+        where the fused program does not fit (``"memory"``, counted as
+        ``plan.fft3d_chunk_auto``), else None."""
+        self.fft3d_chunk, self.fft3d_chunk_from = None, None
+        if not (self.fft3d and transform == "r2c"):
+            return
+        nx = self.input_shape[0]
         ck = self.config.fft3d_chunk
-        if not ck or ck <= 1:
-            return None
-        if nx % ck:
-            raise ValueError(f"fft3d_chunk {ck} must divide the x extent "
-                             f"{nx}")
-        return ck
+        if ck is not None:
+            if ck > 1 and nx % ck:
+                raise ValueError(f"fft3d_chunk {ck} must divide the x "
+                                 f"extent {nx}")
+            self.fft3d_chunk = ck if ck > 1 else None
+            self.fft3d_chunk_from = "config"
+            return
+        dm = None
+        if self.config.fft_backend in ("matmul", "matmul-r2", "pallas"):
+            from ..ops import mxu_fft
+            dm = (self._mxu_st or mxu_fft.default_settings()).direct_max
+        ck = fft3d_chunk_rule(tuple(self.input_shape),
+                              self.config.double_prec,
+                              self.config.fft_backend, dm,
+                              device_bytes_limit(self.mesh))
+        if ck is not None:
+            self.fft3d_chunk, self.fft3d_chunk_from = ck, "memory"
+            obs.metrics.inc("plan.fft3d_chunk_auto")
 
     def _scope_family(self) -> str:
         """The plan-graph family key stage scopes are named under
@@ -327,58 +427,53 @@ class DistFFTPlan:
     def _fft3d_r2c(self, jit: bool = True) -> Any:
         norm, be = self.config.norm, self.config.fft_backend
         st = self._mxu_st
-        ck = self._chunk_for(self.input_shape[0])
+        ck = self.fft3d_chunk
+        fam = self._scope_family()
 
-        def run(x: Any) -> Any:
-            if ck is None:
-                return local_fft.rfftn_3d(x, norm=norm, backend=be,
-                                          settings=st)
-            # Memory-bounded large-cube path: z+y stages per leading-axis
-            # chunk (lax.map serializes them, capping the four-step
-            # relayout temporaries at chunk size); the x stage needs the
-            # full axis and runs on the already-halved spectrum.
-            nx = x.shape[0]
+        def fused(x: Any) -> Any:
+            return local_fft.rfftn_3d(x, norm=norm, backend=be, settings=st)
 
-            def per(xs: Any) -> Any:
-                c = local_fft.rfft(xs, axis=-1, norm=norm, backend=be,
-                                   settings=st, paired=self.r2c_paired)
-                return local_fft.fft(c, axis=-2, norm=norm, backend=be,
-                                     settings=st)
-
-            cs = jnp.reshape(x, (ck, nx // ck) + x.shape[1:])
-            c = jnp.reshape(jax.lax.map(per, cs),
-                            (nx,) + x.shape[1:-1] + (x.shape[-1] // 2 + 1,))
-            return local_fft.fft(c, axis=-3, norm=norm, backend=be,
+        def zy(xs: Any) -> Any:
+            c = local_fft.rfft(xs, axis=-1, norm=norm, backend=be,
+                               settings=st, paired=self.r2c_paired)
+            return local_fft.fft(c, axis=-2, norm=norm, backend=be,
                                  settings=st)
 
-        run = obs.profile.scoped(self._scope_family(), "local_fft:1", run)
+        def x_stage(cs: Any) -> Any:
+            return local_fft.fft(cs, axis=-3, norm=norm, backend=be,
+                                 settings=st)
+
+        if ck is None:
+            run = obs.profile.scoped(fam, "local_fft:1", fused)
+        else:
+            run = _chunked_3d(fam, ck, zy, x_stage, forward=True)
         return self._jit_guarded(run, "forward") if jit else run
 
     def _fft3d_c2r(self, jit: bool = True) -> Any:
         norm, be = self.config.norm, self.config.fft_backend
         st = self._mxu_st
         shape = self.input_shape
-        ck = self._chunk_for(shape[0])
+        ck = self.fft3d_chunk
+        fam = self._scope_family()
 
-        def run(c: Any) -> Any:
-            if ck is None:
-                return local_fft.irfftn_3d(c, shape, norm=norm, backend=be,
-                                           settings=st)
-            nz = shape[-1]
-            c = local_fft.ifft(c, axis=-3, norm=norm, backend=be,
+        def fused(c: Any) -> Any:
+            return local_fft.irfftn_3d(c, shape, norm=norm, backend=be,
+                                       settings=st)
+
+        def x_stage(cs: Any) -> Any:
+            return local_fft.ifft(cs, axis=-3, norm=norm, backend=be,
+                                  settings=st)
+
+        def yz(cs: Any) -> Any:
+            y = local_fft.ifft(cs, axis=-2, norm=norm, backend=be,
                                settings=st)
+            return local_fft.irfft(y, n=shape[-1], axis=-1, norm=norm,
+                                   backend=be, settings=st)
 
-            def per(cs: Any) -> Any:
-                y = local_fft.ifft(cs, axis=-2, norm=norm, backend=be,
-                                   settings=st)
-                return local_fft.irfft(y, n=nz, axis=-1, norm=norm,
-                                       backend=be, settings=st)
-
-            nx = c.shape[0]
-            ys = jnp.reshape(c, (ck, nx // ck) + c.shape[1:])
-            return jnp.reshape(jax.lax.map(per, ys), (nx,) + shape[1:])
-
-        run = obs.profile.scoped(self._scope_family(), "local_fft:1", run)
+        if ck is None:
+            run = obs.profile.scoped(fam, "local_fft:1", fused)
+        else:
+            run = _chunked_3d(fam, ck, yz, x_stage, forward=False)
         return self._jit_guarded(run, "inverse") if jit else run
 
     def _fft3d_c2c(self, forward: bool, jit: bool = True) -> Any:
@@ -429,3 +524,49 @@ def jit_stages(mesh: Mesh,
             sm, in_shardings=NamedSharding(mesh, ispec),
             out_shardings=NamedSharding(mesh, ospec))))
     return out
+
+
+def _chunked_3d(family: str, ck: int, planes: Callable[..., Any],
+                x_stage: Callable[..., Any], forward: bool
+                ) -> Callable[..., Any]:
+    """The single-device 3D R2C (``forward``: ``planes`` then
+    ``x_stage``) or C2R (``x_stage`` then ``planes``) as two loops of
+    ``ck`` steps, so that a step's temporaries are a chunk's and not the
+    cube's. ``planes`` transforms the two trailing axes of ``ck`` chunks
+    of x-planes (``lax.map``, under the stage scope ``chunk:1``).
+    ``x_stage`` transforms axis 0 of ``ck`` blocks of z-planes of the
+    half spectrum, each written back in place (``fori_loop``, under
+    ``chunk:2``), then of the planes left over (the Nyquist plane). The
+    half spectrum's z axis is the major one of its TPU layout, so a block
+    is one contiguous slice; blocks of y columns made the compiler lay the
+    whole spectrum out again before and after the loop (17.3 GB for the
+    1024^3 C2R on a v5e). Neither scope is a graph node: the transforms
+    keep their innermost ``local_fft:1.ax<k>``, so only the slices, the
+    writes and the loops carry the chunk scopes (metadata only)."""
+    planes = obs.profile.scoped(family, "local_fft:1", planes)
+    x_stage = obs.profile.scoped(family, "local_fft:1", x_stage)
+
+    def plane_loop(x: Any) -> Any:
+        with obs.profile.stage_scope(family, "chunk:1"):
+            ys = jax.lax.map(planes, x.reshape((ck, -1) + x.shape[1:]))
+            return ys.reshape((x.shape[0],) + ys.shape[2:])
+
+    def block_loop(c: Any) -> Any:
+        b = c.shape[2] // ck
+
+        def one(j: Any, c: Any) -> Any:
+            blk = jax.lax.dynamic_slice_in_dim(c, j * b, b, axis=2)
+            return jax.lax.dynamic_update_slice_in_dim(c, x_stage(blk),
+                                                       j * b, axis=2)
+
+        with obs.profile.stage_scope(family, "chunk:2"):
+            if b:
+                c = jax.lax.fori_loop(0, ck, one, c)
+            if ck * b == c.shape[2]:
+                return c
+            return jax.lax.dynamic_update_slice_in_dim(
+                c, x_stage(c[:, :, ck * b:]), ck * b, axis=2)
+
+    if forward:
+        return lambda x: block_loop(plane_loop(x))
+    return lambda c: plane_loop(block_loop(c))

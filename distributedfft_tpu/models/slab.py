@@ -144,14 +144,15 @@ class SlabFFTPlan(DistFFTPlan):
             out = [None, None, None]
             out[self._seq.split_axis] = SLAB_AXIS
             self._out_spec = PartitionSpec(*out)
+        self._resolve_fft3d_chunk(transform)
         # Local rows of the R2C along z (None: no R2C along the last
         # axis, or the single-device rfftn over three axes).
-        ck = self.config.fft3d_chunk
+        ck = self.fft3d_chunk
         rows = None
         if transform == "r2c" and self._seq.r2c_axis == 2:
             if not self.fft3d:
                 rows = self._nx_pad // P * g.ny
-            elif ck and ck > 1 and g.nx % ck == 0:
+            elif ck:
                 rows = g.nx // ck * g.ny
         self.r2c_paired = r2c_pairing(self.config, mesh, g.nz, rows)
         notice_axis_smoothness("slab", g.shape, self.config)
@@ -160,6 +161,7 @@ class SlabFFTPlan(DistFFTPlan):
                   comm=self.config.comm_method.value,
                   send=self.config.send_method.value, opt=self.config.opt,
                   wire=self.config.wire_dtype, r2c_paired=self.r2c_paired,
+                  fft3d_chunk=ck, fft3d_chunk_from=self.fft3d_chunk_from,
                   **local_fft_fields(self.config, self._mxu_st))
 
     # -- shapes & size tables (reference getInSize/getOutSize family,

@@ -145,7 +145,7 @@ def directional_chain(k: int, shape, backend: str, direction: str,
 def chunked_forward_chain(k: int, n: int, chunk: int = 8,
                           backend: str = "matmul"):
     """Forward chain over a CHUNKED single-device plan pipeline
-    (``Config.fft3d_chunk``): the z and y stages run in ``chunk``
+    (``Config.fft3d_chunk``): the z+y and x stages run in ``chunk``
     sequential slices so the program's live intermediates fit a 16 GB
     chip at 1024^3 (``eval/benchmarks/tpu_v5e/MEMORY_1024.md`` — the
     all-at-once forward's intermediates do not). Same on-device-input +

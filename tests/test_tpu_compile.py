@@ -202,3 +202,25 @@ def test_batched2d_xla_pairs_rows_on_a_described_v5e(topo):
         compiled = plan._build(forward=True).lower(x).compile()
     assert " reverse(" not in compiled.as_text()
     _assert_whole_tile_writes(compiled, 16)
+
+
+@pytest.mark.parametrize("direction", ["forward", "inverse"])
+def test_one_chip_1024_default_chunks_and_fits(topo, direction):
+    """The default Config's one-chip 1024^3 plan on a described v5e (a
+    mesh of one of its devices, so the plan reads the TPU's platform and
+    memory) chooses its chunk count by itself, and each direction holds
+    at most 14.5 GB of argument + output + temporaries: the fused
+    program needs 17.3, more than the chip's 15.75 GiB."""
+    mesh = Mesh(np.array(topo.devices[:1]), ("p",))
+    with jax.enable_x64(False):
+        plan = dfft.SlabFFTPlan(dfft.GlobalSize(1024, 1024, 1024),
+                                dfft.SlabPartition(1), dfft.Config(),
+                                mesh=mesh)
+        assert plan.fft3d and plan.config.fft_backend == "matmul"
+        assert plan.fft3d_chunk_from == "memory" and plan.fft3d_chunk
+        aval = hloscan._input_aval(plan, direction)
+        x = jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                 sharding=SingleDeviceSharding(
+                                     topo.devices[0]))
+        compiled = hloscan._builder(plan, direction).lower(x).compile()
+    assert _bytes(compiled) <= 14.5e9, f"{_bytes(compiled) / 1e9:.2f} GB"

@@ -78,6 +78,17 @@ def r2c_pairing(config: Config, mesh: Optional[Mesh], n: int,
         n, rows)
 
 
+def c2r_pairing(config: Config, mesh: Optional[Mesh], n: int,
+                rows: Optional[int]) -> bool:
+    """A plan's ``c2r_paired``, decided once at construction like
+    ``r2c_pairing``: whether its C2R along the last axis, to extent
+    ``n`` over ``rows`` local rows, runs as one complex inverse FFT per
+    pair of rows (``ops/fft.pairs_rows``, ``_paired_irfft``)."""
+    return rows is not None and local_fft.pairs_rows(
+        wisdom.plan_platform(mesh), config.double_prec, config.fft_backend,
+        n, rows, inverse=True)
+
+
 # The chunk rule (``fft3d_chunk_rule``). The counts it tries, fastest
 # first: the R2C of 1024^3 f32 on one v5e at the platform default took
 # 199.6 ms in 128 chunks, 221.9 in 64, 249.5 in 32, 272.8 in 16, 283.4 in
@@ -468,7 +479,8 @@ class DistFFTPlan:
             y = local_fft.ifft(cs, axis=-2, norm=norm, backend=be,
                                settings=st)
             return local_fft.irfft(y, n=shape[-1], axis=-1, norm=norm,
-                                   backend=be, settings=st)
+                                   backend=be, settings=st,
+                                   paired=self.c2r_paired)
 
         if ck is None:
             run = obs.profile.scoped(fam, "local_fft:1", fused)

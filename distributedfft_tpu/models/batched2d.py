@@ -41,7 +41,7 @@ from ..parallel.transpose import (all_to_all_transpose, chunked_reshard,
                                   split_axis_chunks, wire_gspmd_stages)
 from ..utils import wisdom
 from .base import (_with_pad, jit_stages, local_fft_fields,
-                   notice_axis_smoothness, r2c_pairing)
+                   notice_axis_smoothness, r2c_pairing, c2r_pairing)
 
 # A TPU's float32 tile is 8 sublanes by 128 lanes. The chunk loop's
 # group buffers (``_map_in_tile_groups``) keep their images outermost, the
@@ -158,11 +158,13 @@ class Batched2DFFTPlan:
                 else batch * (self._nx_pad // P))
         self.r2c_paired = transform == "r2c" and r2c_pairing(
             self.config, mesh, ny, rows)
+        self.c2r_paired = transform == "r2c" and c2r_pairing(
+            self.config, mesh, ny, rows)
         notice_axis_smoothness("batched2d", (nx, ny), self.config)
         obs.event("plan.created", kind="batched2d", shard=shard,
                   transform=transform, shape=[batch, nx, ny], ranks=P,
                   batch_chunk=batch_chunk, chunk_group=self.chunk_group,
-                  r2c_paired=self.r2c_paired,
+                  r2c_paired=self.r2c_paired, c2r_paired=self.c2r_paired,
                   comm=self.config.comm_method.value,
                   send=self.config.send_method.value, opt=self.config.opt,
                   wire=self.config.wire_dtype,
@@ -334,7 +336,8 @@ class Batched2DFFTPlan:
         c = lf.ifft(x, axis=1, norm=norm, backend=be, settings=st)
         if self.transform == "c2c":
             return lf.ifft(c, axis=2, norm=norm, backend=be, settings=st)
-        return lf.irfft(c, n=self.ny, axis=2, norm=norm, backend=be, settings=st)
+        return lf.irfft(c, n=self.ny, axis=2, norm=norm, backend=be,
+                        settings=st, paired=self.c2r_paired)
 
     def _chunked(self, base, group: Optional[int] = None):
         """Wrap a whole-(local-)batch transform in a sequential ``lax.map``
@@ -474,7 +477,7 @@ class Batched2DFFTPlan:
                     return lf.ifft(c, axis=2, norm=norm, backend=be,
                                    settings=st)
                 return lf.irfft(c, n=ny, axis=2, norm=norm, backend=be,
-                                settings=st)
+                                settings=st, paired=self.c2r_paired)
         # Stage scopes (obs/profile.py): the shard='x' graph's nodes.
         sc = obs.profile.scoped
         return (sc("batched2d", "local_fft:1", first),

@@ -63,7 +63,7 @@ from ..parallel.transpose import (WIRE_NATIVE, all_to_all_transpose,
 from ..resilience import inject
 from ..utils import wisdom
 from .base import (DistFFTPlan, _with_pad, local_fft_fields,
-                   notice_axis_smoothness, r2c_pairing)
+                   notice_axis_smoothness, r2c_pairing, c2r_pairing)
 
 P1_AXIS, P2_AXIS = PENCIL_AXES
 
@@ -127,6 +127,8 @@ class PencilFFTPlan(DistFFTPlan):
                 self._nx_p1 // self.p1 * (self._ny_p2 // self.p2))
         self.r2c_paired = transform == "r2c" and r2c_pairing(
             self.config, mesh, g.nz, rows)
+        self.c2r_paired = transform == "r2c" and c2r_pairing(
+            self.config, mesh, g.nz, rows)
         notice_axis_smoothness("pencil", g.shape, self.config)
         obs.event("plan.created", kind="pencil", transform=transform,
                   shape=list(g.shape), grid=[self.p1, self.p2],
@@ -135,7 +137,7 @@ class PencilFFTPlan(DistFFTPlan):
                   send=self.config.send_method.value,
                   send2=self.config.resolved_snd2().value,
                   opt=self.config.opt, wire=self.config.wire_dtype,
-                  r2c_paired=self.r2c_paired,
+                  r2c_paired=self.r2c_paired, c2r_paired=self.c2r_paired,
                   **local_fft_fields(self.config, self._mxu_st))
 
     # -- shapes ------------------------------------------------------------
@@ -497,7 +499,8 @@ class PencilFFTPlan(DistFFTPlan):
             c = slice_axis_to(cl, 2, nzc)
             if complex_mode:
                 return lf.ifft(c, axis=2, norm=norm, backend=be, settings=st)
-            return lf.irfft(c, n=nz, axis=2, norm=norm, backend=be, settings=st)
+            return lf.irfft(c, n=nz, axis=2, norm=norm, backend=be,
+                            settings=st, paired=self.c2r_paired)
 
         # Stage scopes (obs/profile.py): inverse graph node numbering.
         ids = self._scope_ids("inverse", dims)
@@ -938,7 +941,8 @@ class PencilFFTPlan(DistFFTPlan):
                 c = lf.ifft(c, axis=1, norm=norm, backend=be, settings=st)
             if complex_mode:
                 return lf.ifft(c, axis=2, norm=norm, backend=be, settings=st)
-            return lf.irfft(c, n=nz, axis=2, norm=norm, backend=be, settings=st)
+            return lf.irfft(c, n=nz, axis=2, norm=norm, backend=be,
+                            settings=st, paired=self.c2r_paired)
 
         run = obs.profile.scoped("pencil", "local_fft:1", run)
         if not jit:

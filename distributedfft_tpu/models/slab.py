@@ -67,7 +67,7 @@ from ..parallel.transpose import (all_to_all_transpose, chunked_reshard,
                                   split_axis_chunks, wire_gspmd_stages)
 from ..utils import wisdom
 from .base import (DistFFTPlan, _with_pad, local_fft_fields,
-                   notice_axis_smoothness, r2c_pairing)
+                   notice_axis_smoothness, r2c_pairing, c2r_pairing)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,8 +145,8 @@ class SlabFFTPlan(DistFFTPlan):
             out[self._seq.split_axis] = SLAB_AXIS
             self._out_spec = PartitionSpec(*out)
         self._resolve_fft3d_chunk(transform)
-        # Local rows of the R2C along z (None: no R2C along the last
-        # axis, or the single-device rfftn over three axes).
+        # Local rows of the R2C and the C2R along z (None: neither runs
+        # along the last axis, or the single-device rfftn over three axes).
         ck = self.fft3d_chunk
         rows = None
         if transform == "r2c" and self._seq.r2c_axis == 2:
@@ -155,12 +155,14 @@ class SlabFFTPlan(DistFFTPlan):
             elif ck:
                 rows = g.nx // ck * g.ny
         self.r2c_paired = r2c_pairing(self.config, mesh, g.nz, rows)
+        self.c2r_paired = c2r_pairing(self.config, mesh, g.nz, rows)
         notice_axis_smoothness("slab", g.shape, self.config)
         obs.event("plan.created", kind="slab", sequence=self.sequence.value,
                   transform=transform, shape=list(g.shape), ranks=P,
                   comm=self.config.comm_method.value,
                   send=self.config.send_method.value, opt=self.config.opt,
                   wire=self.config.wire_dtype, r2c_paired=self.r2c_paired,
+                  c2r_paired=self.c2r_paired,
                   fft3d_chunk=ck, fft3d_chunk_from=self.fft3d_chunk_from,
                   **local_fft_fields(self.config, self._mxu_st))
 
@@ -465,7 +467,7 @@ class SlabFFTPlan(DistFFTPlan):
             if complex_mode:
                 return lf.ifft(c, axis=s.r2c_axis, norm=norm, backend=be, settings=st)
             return lf.irfft(c, n=real_n, axis=s.r2c_axis, norm=norm,
-                            backend=be, settings=st)
+                            backend=be, settings=st, paired=self.c2r_paired)
 
         # Inverse graph numbering: its stage 1 (post-axis inverses) is
         # local_fft:1, its stage 2 (pre-axis + r2c inverses) local_fft:2.
@@ -706,7 +708,8 @@ class SlabFFTPlan(DistFFTPlan):
                                     backend=be, settings=st)
                     return y
                 return lf.irfft(y, n=real_n, axis=s.r2c_axis, norm=norm,
-                                backend=be, settings=st)
+                                backend=be, settings=st,
+                                paired=self.c2r_paired)
 
         return body
 

@@ -728,6 +728,7 @@ def main(argv=None) -> int:
                       f"mxu_direct_max={cfg.mxu_direct_max})"
                       if cfg.fft_backend.startswith("matmul") else ""))
         out.append(f"  r2c_paired: {plan.r2c_paired}")
+        out.append(f"  c2r_paired: {plan.c2r_paired}")
 
         out.append("graph (declared stage graph, plangraph registry):")
         out.extend(_graph_lines(plan, dims))

@@ -31,7 +31,8 @@ their work, for every backend, under the per-axis sub-scope of the open
 plan stage (``obs/profile.axis_scope``: ``dfft/<family>/<node>.ax<k>``);
 the multi-axis ones stay at the stage scope. On a TPU a plan's float32
 R2C along the last axis on the "xla" backend runs as one complex FFT per
-pair of rows (``pairs_rows``, ``_paired_rfft``).
+pair of rows (``pairs_rows``, ``_paired_rfft``), and so does its C2R
+(``_paired_irfft``).
 """
 
 from __future__ import annotations
@@ -128,26 +129,46 @@ def _inv_norm(norm: FFTNorm) -> str:
     return "backward"
 
 
-# Where pairing won on a v5e (PERF.md, the pairing sweep): from an
-# extent of 2048 up to 2^25 points a call. Past that both forms ran
-# alike or the pairs lost (16 x 4096^2: 52.8 ms either way; 256 x
+# Where pairing won on a v5e (PERF.md, the pairing sweeps). The R2C:
+# from an extent of 2048 up to 2^25 points a call. Past that both forms
+# ran alike or the pairs lost (16 x 4096^2: 52.8 ms either way; 256 x
 # 1024^2: 59.2 against 52.9), and below 2048 the results were mixed.
 PAIRED_MIN_EXTENT = 2048
 PAIRED_MAX_POINTS = 1 << 25
+# The C2R: from an extent of 1024 and from 2^22 up to 2^26 points a call,
+# where every point of the sweep won (0.53 to 0.82 of the unpaired
+# time); at 2^20 and 2^21 points both forms ran alike.
+PAIRED_C2R_MIN_EXTENT = 1024
+PAIRED_C2R_MIN_POINTS = 1 << 22
+PAIRED_C2R_MAX_POINTS = 1 << 26
 
 
 def pairs_rows(platform: str, double_prec: bool, backend: str, n: int,
-               rows: int) -> bool:
-    """Whether a plan's R2C along the last axis, of extent ``n`` over
-    ``rows`` local rows a call, runs as one complex FFT per pair of rows
-    (``rfft(..., paired=True)``, ``_paired_rfft``): on a TPU, in float32,
-    on the xla backend, with ``n`` and ``rows`` even, ``n`` at least
-    ``PAIRED_MIN_EXTENT`` and ``n * rows`` at most ``PAIRED_MAX_POINTS``.
-    XLA's TPU R2C carries a full complex spectrum of every real row from
-    its first stage on: one 4096^2 image took 2.63 ms, and 1.55 paired."""
+               rows: int, inverse: bool = False) -> bool:
+    """Whether a plan's R2C (``inverse``: its C2R) along the last axis,
+    of extent ``n`` over ``rows`` local rows a call, runs as one complex
+    FFT per pair of rows (``rfft(..., paired=True)``, ``_paired_rfft``;
+    ``irfft(..., paired=True)``, ``_paired_irfft``): on a TPU, in
+    float32, on the xla backend, with ``n`` and ``rows`` even, and ``n``
+    and ``n * rows`` inside the direction's window above. XLA's TPU R2C
+    carries a full complex spectrum of every real row from its first
+    stage on: one 4096^2 image took 2.63 ms, and 1.55 paired. Its C2R
+    interleaves the halves of each real row along the lanes: 1.88 ms,
+    and 1.53 paired."""
+    if inverse:
+        fits = (n >= PAIRED_C2R_MIN_EXTENT
+                and PAIRED_C2R_MIN_POINTS <= n * rows <= PAIRED_C2R_MAX_POINTS)
+    else:
+        fits = n >= PAIRED_MIN_EXTENT and n * rows <= PAIRED_MAX_POINTS
     return (platform == "tpu" and not double_prec and backend == "xla"
-            and n % 2 == 0 and rows % 2 == 0 and n >= PAIRED_MIN_EXTENT
-            and n * rows <= PAIRED_MAX_POINTS)
+            and n % 2 == 0 and rows % 2 == 0 and fits)
+
+
+def _pairable(x, axis: int, n: int, dtype) -> bool:
+    """Whether ``x`` of ``dtype`` can pair its rows along ``axis``, the
+    last, to an even extent ``n``: the leading axes hold an even count."""
+    return (x.dtype == dtype and axis % x.ndim == x.ndim - 1 and n % 2 == 0
+            and math.prod(x.shape[:-1]) % 2 == 0)
 
 
 def rfft(x, axis: int, norm: FFTNorm = FFTNorm.NONE, backend: str = "xla",
@@ -168,9 +189,7 @@ def rfft(x, axis: int, norm: FFTNorm = FFTNorm.NONE, backend: str = "xla",
         if m is not None:
             with _settings_ctx(backend, settings):
                 return m.rfft(x, axis=axis, norm=norm)
-        paired = (paired and x.dtype == jnp.float32
-                  and axis % x.ndim == x.ndim - 1 and x.shape[-1] % 2 == 0
-                  and math.prod(x.shape[:-1]) % 2 == 0)
+        paired = paired and _pairable(x, axis, x.shape[-1], jnp.float32)
         if paired:
             obs_metrics.inc("local_fft.r2c_paired")
         return _xla_rfft(x, axis, _fwd_norm(norm), paired)
@@ -225,15 +244,54 @@ def _paired_rfft(x, norm: str):
 
 
 def irfft(x, n: int, axis: int, norm: FFTNorm = FFTNorm.NONE,
-          backend: str = "xla", settings=None):
+          backend: str = "xla", settings=None, paired: bool = False):
     """Inverse C2R along one axis; ``n`` is the real output extent (needed
-    because the halved axis length ``n//2+1`` is ambiguous)."""
+    because the halved axis length ``n//2+1`` is ambiguous).
+
+    ``paired``: the plan's ``c2r_paired`` (``pairs_rows``). On the "xla"
+    backend a complex64 ``x`` whose last axis is ``axis``, with an even
+    ``n`` and an even number of rows, then takes ``_paired_irfft``,
+    counted as ``local_fft.c2r_paired``; any other ``x`` keeps
+    ``xla_irfft``."""
     with axis_scope(axis, x.ndim):
         m = _impl(backend)
         if m is not None:
             with _settings_ctx(backend, settings):
                 return m.irfft(x, n=n, axis=axis, norm=norm)
+        if paired and _pairable(x, axis, n, jnp.complex64):
+            obs_metrics.inc("local_fft.c2r_paired")
+            return _paired_irfft(x, n, _inv_norm(norm))
         return xla_irfft(x, n, axis, _inv_norm(norm))
+
+
+def _paired_irfft(x, n: int, norm: str):
+    """``jnp.fft.irfft`` along the last axis, of even length ``n``, as
+    one complex inverse FFT per pair of rows. With the leading axes
+    flattened to R rows of half spectra, row j (A) and row j + R/2 (B)
+    make Z[k] = A[k] + i B[k] for k <= n/2 and
+    Z[k] = conj(A[n - k]) + i conj(B[n - k]) above, so ifft(Z) = a + i b
+    holds both real rows whole: no lane interleave. Z is spelled on the
+    real and imaginary planes of A and B apart, each (R/2, n/2 + 1), and
+    not on one (2, R/2, n/2 + 1) array: a TPU's layouts put such a pair
+    axis of 2 in the sublanes, at a quarter of their width. Its tail
+    reads the planes through a constant-index gather, not a reverse op
+    (see ``_reversed_last``). Like numpy, the spectral axis is cropped or
+    zero-padded to n/2 + 1, and the imaginary parts of the DC and
+    Nyquist bins are ignored."""
+    lead, m = x.shape[:-1], n // 2
+    h = math.prod(lead) // 2
+    x = _fit_last(x, m + 1).reshape((2 * h, m + 1))
+    ar, ai = jnp.real(x[:h]), jnp.imag(x[:h])
+    br, bi = jnp.real(x[h:]), jnp.imag(x[h:])
+    inner = np.ones(m + 1, np.float32)
+    inner[[0, m]] = 0.0
+    tail = np.arange(m - 1, 0, -1)
+    re = jnp.concatenate([ar - bi * inner, jnp.take(ar + bi, tail, axis=-1)],
+                         axis=-1)
+    im = jnp.concatenate([ai * inner + br, jnp.take(br - ai, tail, axis=-1)],
+                         axis=-1)
+    z = jnp.fft.ifft(jax.lax.complex(re, im), axis=-1, norm=norm)
+    return jnp.concatenate([jnp.real(z), jnp.imag(z)]).reshape(lead + (n,))
 
 
 def _fit_last(x, k: int):

@@ -318,6 +318,31 @@ class TestChunkGroups:
                              "xla"]) == 0
         assert f"r2c_paired: {want}" in capsys.readouterr().out
 
+    @pytest.mark.parametrize("platform,want", [("tpu", True),
+                                               ("cpu", False)])
+    def test_plan_created_and_explain_name_c2r_paired(
+            self, monkeypatch, capsys, platform, want):
+        """The same cell's plan pairs the rows of its C2R on a TPU
+        (``plan.c2r_paired``, ``ops/fft._paired_irfft``), and
+        ``plan.created`` and ``dfft-explain`` say so; on the CPU it does
+        not. Nothing runs."""
+        from distributedfft_tpu import obs
+        from distributedfft_tpu.obs import explain
+        from distributedfft_tpu.utils import wisdom
+        obs.flightrec.clear()
+        monkeypatch.setattr(wisdom, "plan_platform",
+                            lambda mesh=None: platform)
+        plan = Batched2DFFTPlan(48, 4096, 4096, SlabPartition(1), XLA,
+                                batch_chunk=1)
+        assert plan.c2r_paired is want
+        assert [r["attrs"]["c2r_paired"] for r in obs.flightrec.snapshot()
+                if r["name"] == "plan.created"] == [want]
+        assert explain.main(["--kind", "batched", "-nx", "4096", "-ny",
+                             "4096", "-nz", "48", "--shard", "batch", "-p",
+                             "1", "--batch-chunk", "1", "--fft-backend",
+                             "xla"]) == 0
+        assert f"c2r_paired: {want}" in capsys.readouterr().out
+
 
 class TestHarnessWiring:
     """VERDICT r2 item 7: the batched plan flows through the same

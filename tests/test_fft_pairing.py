@@ -1,9 +1,11 @@
 """The R2C along the last axis as one complex FFT per pair of rows
-(``ops/fft.rfft(..., paired=True)``, ``_paired_rfft``).
+(``ops/fft.rfft(..., paired=True)``, ``_paired_rfft``), and the C2R as
+one complex inverse per pair of rows (``ops/fft.irfft(..., paired=True)``,
+``_paired_irfft``).
 
-A plan selects it on a TPU only (``plan.created``'s ``r2c_paired``), so
-here the paired function is called directly, and plans are built with the
-platform they read steered to "tpu".
+A plan selects them on a TPU only (``plan.created``'s ``r2c_paired`` and
+``c2r_paired``), so here the paired functions are called directly, and
+plans are built with the platform they read steered to "tpu".
 """
 
 import numpy as np
@@ -114,6 +116,8 @@ def test_unpaired_inputs_lower_as_before(case):
 def small_cut(monkeypatch):
     """Let plans of a few points pair, so that their programs run here."""
     monkeypatch.setattr(lf, "PAIRED_MIN_EXTENT", 2)
+    monkeypatch.setattr(lf, "PAIRED_C2R_MIN_EXTENT", 2)
+    monkeypatch.setattr(lf, "PAIRED_C2R_MIN_POINTS", 0)
 
 
 def _tpu(monkeypatch, cls, *args, **kw):
@@ -128,48 +132,82 @@ def _batched(monkeypatch, batch, nx, ny, config=XLA, **kw):
                 SlabPartition(1), config, **kw)
 
 
-@pytest.mark.parametrize("case,want", [
-    ("tpu", True),
-    ("tpu_chunked", True),
-    ("whole_stack", False),
-    ("small_extent", False),
-    ("odd_extent", False),
-    ("odd_rows", False),
-    ("float64", False),
-    ("cpu", False),
-    ("matmul", False),
-    ("c2c", False),
-])
-def test_batched_plan_decides_once(monkeypatch, case, want):
+def _decision_plan(monkeypatch, case):
+    """A full-size batched plan (nothing traced) for one decision case."""
+    if case == "tpu":
+        return _batched(monkeypatch, 2, 2048, 2048)
+    if case == "tpu_chunked":
+        return _batched(monkeypatch, 48, 4096, 4096, batch_chunk=1)
+    if case == "whole_stack":
+        return _batched(monkeypatch, 48, 4096, 4096)
+    if case == "small_extent":
+        return _batched(monkeypatch, 8, 16, 16)
+    if case == "odd_extent":
+        return _batched(monkeypatch, 2, 2048, 2049)
+    if case == "odd_rows":
+        return _batched(monkeypatch, 1, 2049, 2048)
+    if case == "float64":
+        return _batched(monkeypatch, 2, 2048, 2048,
+                        Config(fft_backend="xla", double_prec=True))
+    if case == "cpu":
+        return Batched2DFFTPlan(2, 2048, 2048, SlabPartition(1), XLA)
+    if case == "matmul":
+        return _batched(monkeypatch, 2, 2048, 2048,
+                        Config(fft_backend="matmul"))
+    if case == "extent_1024":
+        return _batched(monkeypatch, 8, 1024, 1024)
+    if case == "four_images":
+        return _batched(monkeypatch, 4, 4096, 4096)
+    if case == "small_call":
+        return _batched(monkeypatch, 2, 256, 2048)
+    return _batched(monkeypatch, 2, 2048, 2048, transform="c2c")
+
+
+# (case, r2c_paired, c2r_paired): the two directions' windows differ in
+# their least extent (2048, 1024) and in the points a call (at most
+# 2^25; 2^22 to 2^26).
+_DECISIONS = [
+    ("tpu", True, True),
+    ("tpu_chunked", True, True),
+    ("whole_stack", False, False),
+    ("small_extent", False, False),
+    ("odd_extent", False, False),
+    ("odd_rows", False, False),
+    ("float64", False, False),
+    ("cpu", False, False),
+    ("matmul", False, False),
+    ("c2c", False, False),
+    ("extent_1024", False, True),
+    ("four_images", False, True),
+    ("small_call", True, False),
+]
+
+
+@pytest.mark.parametrize("case,want,_", _DECISIONS)
+def test_batched_plan_decides_once(monkeypatch, case, want, _):
     """The batched plan pairs on a TPU, float32, xla, an even extent of
     at least ``PAIRED_MIN_EXTENT`` and an even local row count, with at
     most ``PAIRED_MAX_POINTS`` a call, and says so on ``plan.created``.
     Nothing is traced: the plans are built at full size."""
     obs.flightrec.clear()
-    if case == "tpu":
-        plan = _batched(monkeypatch, 2, 2048, 2048)
-    elif case == "tpu_chunked":
-        plan = _batched(monkeypatch, 48, 4096, 4096, batch_chunk=1)
-    elif case == "whole_stack":
-        plan = _batched(monkeypatch, 48, 4096, 4096)
-    elif case == "small_extent":
-        plan = _batched(monkeypatch, 8, 16, 16)
-    elif case == "odd_extent":
-        plan = _batched(monkeypatch, 2, 2048, 2049)
-    elif case == "odd_rows":
-        plan = _batched(monkeypatch, 1, 2049, 2048)
-    elif case == "float64":
-        plan = _batched(monkeypatch, 2, 2048, 2048,
-                        Config(fft_backend="xla", double_prec=True))
-    elif case == "cpu":
-        plan = Batched2DFFTPlan(2, 2048, 2048, SlabPartition(1), XLA)
-    elif case == "matmul":
-        plan = _batched(monkeypatch, 2, 2048, 2048,
-                        Config(fft_backend="matmul"))
-    else:
-        plan = _batched(monkeypatch, 2, 2048, 2048, transform="c2c")
+    plan = _decision_plan(monkeypatch, case)
     assert plan.r2c_paired is want
     got = [r["attrs"]["r2c_paired"] for r in obs.flightrec.snapshot()
+           if r["name"] == "plan.created"]
+    assert got == [want]
+
+
+@pytest.mark.parametrize("case,_,want", _DECISIONS)
+def test_batched_plan_decides_c2r_once(monkeypatch, case, _, want):
+    """``pairs_rows(..., inverse=True)`` decides the plan's
+    ``c2r_paired`` on the same observed inputs, in the C2R's window: an
+    even extent of at least ``PAIRED_C2R_MIN_EXTENT`` and from
+    ``PAIRED_C2R_MIN_POINTS`` to ``PAIRED_C2R_MAX_POINTS`` a call; and
+    ``plan.created`` carries it."""
+    obs.flightrec.clear()
+    plan = _decision_plan(monkeypatch, case)
+    assert plan.c2r_paired is want
+    got = [r["attrs"]["c2r_paired"] for r in obs.flightrec.snapshot()
            if r["name"] == "plan.created"]
     assert got == [want]
 
@@ -260,3 +298,172 @@ def test_autodiff_inside_shard_map(devices, rng, mode):
     for a, b in zip(out[True], out[False]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                    atol=1e-5)
+
+
+# -- the C2R ---------------------------------------------------------------
+
+
+def _spectrum(rng, shape):
+    return (rng.uniform(-1.0, 1.0, shape)
+            + 1j * rng.uniform(-1.0, 1.0, shape)).astype(np.complex64)
+
+
+def _paired_inv(c, n, norm=FFTNorm.NONE):
+    return np.asarray(lf.irfft(jnp.asarray(c), n=n, axis=-1, norm=norm,
+                               paired=True))
+
+
+@pytest.mark.parametrize("norm", list(FFTNorm), ids=lambda n: n.name)
+@pytest.mark.parametrize("rows", [2, 6, 4096])
+@pytest.mark.parametrize("n", [8, 64, 4096])
+def test_c2r_matches_numpy(rng, n, rows, norm):
+    """Agreement with float64 NumPy's ``irfft`` to float32 rounding."""
+    c = _spectrum(rng, (rows, n // 2 + 1))
+    ref = np.fft.irfft(c.astype(np.complex128), n=n, axis=-1,
+                       norm=lf._inv_norm(norm))
+    got = _paired_inv(c, n, norm)
+    assert got.dtype == np.float32 and got.shape == ref.shape
+    assert _rel_l2(got, ref) < 1e-6
+
+
+@pytest.mark.parametrize("width", [3, 5, 9, 12, 20])
+def test_c2r_crops_or_pads_the_spectral_axis(rng, width):
+    """A spectral axis longer than n/2 + 1 is cropped, a shorter one
+    zero-padded, as NumPy does (n = 16, so 9 is exact)."""
+    c = _spectrum(rng, (4, width))
+    ref = np.fft.irfft(c.astype(np.complex128), n=16, axis=-1)
+    got = _paired_inv(c, 16, FFTNorm.BACKWARD)
+    assert got.shape == (4, 16)
+    assert _rel_l2(got, ref) < 1e-6
+
+
+@pytest.mark.parametrize("n", [8, 64, 4096])
+def test_c2r_ignores_dc_and_nyquist_imaginary_parts(rng, n):
+    c = _spectrum(rng, (6, n // 2 + 1))
+    real_ends = c.copy()
+    real_ends[:, 0] = real_ends[:, 0].real
+    real_ends[:, -1] = real_ends[:, -1].real
+    got = _paired_inv(c, n)
+    np.testing.assert_array_equal(got, _paired_inv(real_ends, n))
+    ref = np.fft.irfft(c.astype(np.complex128), n=n, axis=-1, norm="forward")
+    assert _rel_l2(got, ref) < 1e-6
+
+
+@pytest.mark.parametrize("lead,n", [((2, 8), 16), ((1, 64), 64),
+                                    ((4, 6), 4096)])
+def test_paired_roundtrip(rng, lead, n):
+    """Paired R2C then paired C2R returns n times the input (FFTNorm.NONE
+    is unnormalised both ways), in the input's leading shape."""
+    x = rng.uniform(-1.0, 1.0, lead + (n,)).astype(np.float32)
+    c = lf.rfft(jnp.asarray(x), axis=-1, paired=True)
+    got = np.asarray(lf.irfft(c, n=n, axis=-1, paired=True))
+    assert got.shape == x.shape
+    assert _rel_l2(got, x * n) < 1e-6
+
+
+def test_c2r_counts_each_traced_pairing(rng):
+    obs.metrics.reset()
+    c = jnp.asarray(_spectrum(rng, (4, 5)))
+    lf.irfft(c, n=8, axis=-1, paired=True)
+    lf.irfft(c, n=8, axis=-1)
+    lf.irfft(c[:3], n=8, axis=-1, paired=True)  # odd row count
+    lf.irfft(c, n=9, axis=-1, paired=True)      # odd extent
+    assert obs.snapshot()["counters"].get("local_fft.c2r_paired") == 1
+
+
+def test_c2r_lowered_program_has_no_reverse():
+    """Z's tail reads the spectrum through a gather, not a reverse op
+    (``_reversed_last``), and the rows come back stacked along a leading
+    axis: no lane interleave of real and imaginary parts."""
+    txt = _text(lambda v: lf.irfft(v, n=64, axis=2, paired=True),
+                (2, 8, 33), jnp.complex64)
+    assert "stablehlo.gather" in txt
+    assert "reverse" not in txt
+    assert "tensor<2x8x32x2xf32>" not in txt
+
+
+@pytest.mark.parametrize("case", ["c128", "odd_extent", "odd_rows",
+                                  "not_last", "matmul"])
+def test_c2r_unpaired_inputs_lower_as_before(case):
+    """Where pairing does not apply, ``paired=True`` lowers to the same
+    program as ``paired=False``, which is ``xla_irfft`` for xla."""
+    shape, n, dtype, axis, be = (4, 8, 9), 16, jnp.complex64, 2, "xla"
+    if case == "c128":
+        dtype = jnp.complex128
+    elif case == "odd_extent":
+        n = 17
+    elif case == "odd_rows":
+        shape = (3, 5, 9)
+    elif case == "not_last":
+        shape, n, axis = (4, 9, 8), 16, 1
+    else:
+        be = "matmul"
+    paired = _text(lambda v: lf.irfft(v, n=n, axis=axis, backend=be,
+                                      paired=True), shape, dtype)
+    assert paired == _text(lambda v: lf.irfft(v, n=n, axis=axis, backend=be),
+                           shape, dtype)
+    if be == "xla":
+        assert paired == _text(lambda v: lf.xla_irfft(
+            v, n, axis, "forward"), shape, dtype)
+
+
+@pytest.mark.parametrize("batch_chunk", [None, 1])
+def test_batched_plan_paired_c2r_answers(monkeypatch, rng, small_cut,
+                                         batch_chunk):
+    """The paired plan's inverse matches float64 NumPy, chunked or
+    whole, and counts its paired C2R."""
+    plan = _batched(monkeypatch, 8, 16, 16, batch_chunk=batch_chunk)
+    assert plan.c2r_paired
+    c = _spectrum(rng, (8, 16, 9))
+    obs.metrics.reset()
+    got = plan.crop_real(plan.exec_inverse(plan.pad_spectral(c)))
+    assert obs.snapshot()["counters"].get("local_fft.c2r_paired", 0) >= 1
+    ref = np.fft.irfft2(c.astype(np.complex128), s=(16, 16), norm="forward")
+    assert _rel_l2(got, ref) < 1e-6
+
+
+@pytest.mark.parametrize("family", ["slab", "slab_fft3d_chunked", "pencil"])
+def test_3d_plans_pair_c2r_and_match(devices, monkeypatch, rng, small_cut,
+                                     family):
+    """Slab and pencil plans pair their C2R along z too, with the same
+    answers as the CPU's plan and NumPy's."""
+    g = GlobalSize(16, 16, 16)
+    if family == "slab":
+        mk = lambda: SlabFFTPlan(g, SlabPartition(8), XLA)  # noqa: E731
+    elif family == "slab_fft3d_chunked":
+        cfg = Config(fft_backend="xla", fft3d_chunk=2)
+        mk = lambda: SlabFFTPlan(g, SlabPartition(1), cfg)  # noqa: E731
+    else:
+        mk = lambda: PencilFFTPlan(g, PencilPartition(2, 4), XLA)  # noqa: E731
+    with monkeypatch.context() as m:
+        m.setattr(wisdom, "plan_platform", lambda mesh=None: "tpu")
+        paired = mk()
+    base = mk()
+    assert paired.c2r_paired and not base.c2r_paired
+    x = rng.uniform(-1.0, 1.0, g.shape).astype(np.float32)
+    c = base.exec_r2c(x)
+    obs.metrics.reset()
+    got = paired.crop_real(paired.exec_c2r(c))
+    assert obs.snapshot()["counters"].get("local_fft.c2r_paired", 0) >= 1
+    np.testing.assert_allclose(got, base.crop_real(base.exec_c2r(c)),
+                               rtol=1e-5, atol=2e-3)
+    assert _rel_l2(got, x * g.nx * g.ny * g.nz) < 1e-6
+
+
+@pytest.mark.parametrize("mode", ["jvp", "grad"])
+def test_c2r_autodiff_inside_shard_map(devices, rng, mode):
+    """``jax.jvp`` and ``jax.grad`` through the paired C2R inside
+    ``shard_map`` over 2 devices match the unpaired path."""
+    c = jnp.asarray(_spectrum(rng, (4, 6, 9)))
+    t = jnp.asarray(_spectrum(rng, (4, 6, 9)))
+    w = jnp.asarray(rng.uniform(-1.0, 1.0, (4, 6, 16)).astype(np.float32))
+    out = {}
+    for paired in (False, True):
+        f = _shard2(lambda v, p=paired: lf.irfft(v, n=16, axis=2, paired=p))
+        if mode == "jvp":
+            out[paired] = jax.jvp(f, (c,), (t,))
+        else:
+            out[paired] = (jax.grad(lambda v: jnp.sum(f(v) * w))(c),)
+    for a, b in zip(out[True], out[False]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-4)

@@ -25,6 +25,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 import distributedfft_tpu as dfft
 from distributedfft_tpu.analysis import hloscan
+from distributedfft_tpu.ops import fft as lf
 from distributedfft_tpu.ops import pallas_fft
 
 HBM_BYTES = 16e9
@@ -202,6 +203,39 @@ def test_batched2d_xla_pairs_rows_on_a_described_v5e(topo):
         compiled = plan._build(forward=True).lower(x).compile()
     assert " reverse(" not in compiled.as_text()
     _assert_whole_tile_writes(compiled, 16)
+
+
+def _batched_cell_c2r(one_chip, monkeypatch, paired):
+    """The batched benchmark cell's C2R (48 x 4096^2, chunk 1, float32,
+    xla) compiled for one described v5e chip, its rows paired or not
+    (``ops/fft.PAIRED_C2R_MIN_EXTENT`` out of reach)."""
+    if not paired:
+        monkeypatch.setattr(lf, "PAIRED_C2R_MIN_EXTENT", 1 << 30)
+    with jax.enable_x64(False):
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            plan = dfft.Batched2DFFTPlan(48, 4096, 4096,
+                                         dfft.SlabPartition(1),
+                                         dfft.Config(fft_backend="xla"),
+                                         batch_chunk=1)
+        assert plan.c2r_paired is paired
+        c = jax.ShapeDtypeStruct((48, 4096, 2049), jnp.complex64,
+                                 sharding=one_chip)
+        return plan._build(forward=False).lower(c).compile()
+
+
+def test_batched2d_cell_c2r_pairs_rows_on_a_described_v5e(one_chip,
+                                                           monkeypatch):
+    """The cell's paired C2R reads its spectrum through no reverse op
+    (``ops/fft._paired_irfft``), and reserves no more temporaries than
+    the unpaired program: the chip is nearly full while it runs."""
+    compiled = _batched_cell_c2r(one_chip, monkeypatch, True)
+    assert " reverse(" not in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    unpaired = _batched_cell_c2r(one_chip, monkeypatch, False)
+    assert temp <= unpaired.memory_analysis().temp_size_in_bytes + 1e6, (
+        f"{temp / 1e9:.4f} GB against "
+        f"{unpaired.memory_analysis().temp_size_in_bytes / 1e9:.4f}")
 
 
 @pytest.mark.parametrize("direction", ["forward", "inverse"])
